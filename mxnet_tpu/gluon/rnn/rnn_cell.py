@@ -21,7 +21,8 @@ class RecurrentCell(HybridBlock):
     def begin_state(self, batch_size=0, func=None, **kwargs):
         from ...ndarray import ndarray as _nd
 
-        return [_nd.zeros(info["shape"])
+        func = func or _nd.zeros
+        return [func(info["shape"], **kwargs)
                 for info in self.state_info(batch_size)]
 
     def unroll(self, length, inputs, begin_state=None, layout="NTC",

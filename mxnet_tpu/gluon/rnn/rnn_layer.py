@@ -62,12 +62,13 @@ class _RNNLayer(HybridBlock):
         raise NotImplementedError
 
     def begin_state(self, batch_size=0, func=None, **kwargs):
+        """Ref: _RNNLayer.begin_state — ``kwargs`` (``ctx=``) go to the
+        creation function, as in the reference."""
         from ...ndarray import ndarray as _nd
 
-        states = []
-        for info in self.state_info(batch_size):
-            states.append(_nd.zeros(info["shape"]))
-        return states
+        func = func or _nd.zeros
+        return [func(info["shape"], **kwargs)
+                for info in self.state_info(batch_size)]
 
     def _flat_params(self, F, params):
         """Pack per-layer params into the fused op's flat layout."""
@@ -112,7 +113,15 @@ class _RNNLayer(HybridBlock):
         if skip_states:
             if isinstance(x, NDArray):
                 bs = x.shape[0] if self._layout == "NTC" else x.shape[1]
-                states = self.begin_state(bs)
+                # the implicit state lives where the input lives (ref:
+                # begin_state(batch_size, ctx=inputs.context)), not on
+                # the default context — the host, even beside a chip;
+                # under a trace there is no device and a constant is
+                # placed by the compiler
+                from ..block import is_tracing
+
+                ctx = None if is_tracing() else x.context
+                states = self.begin_state(bs, ctx=ctx)
             else:
                 states = []
         if isinstance(states, (list, tuple)) and states and \

@@ -94,6 +94,7 @@ class WholeStepCompiler:
         # axis (chunk pos -> [garr per slot] / [{rank: raw} per slot])
         self._zgstates = {}
         self._zgstate_views = {}
+        self._zero_rank_devs = {}  # rank -> jax device of its shards
 
     # -- public entry -------------------------------------------------------
 
@@ -394,9 +395,16 @@ class WholeStepCompiler:
                 # cotangent loss.backward() uses on the unreduced loss
                 return jnp.sum(loss_nd._data), aux
 
-            loss, vjp_fn, aux = jax.vjp(_loss, list(train_ws),
-                                        has_aux=True)
-            (grads,) = vjp_fn(jnp.asarray(1.0, loss.dtype))
+            ws_in = list(train_ws)
+            if axis_name is not None:
+                # the weights arrive replicated; typed varying over the
+                # replica axis, their gradients stay per-replica, so the
+                # explicit bucketed collectives below are THE reduction
+                # (left replicated, autodiff would psum each gradient
+                # on its own and the buckets would sum a second time)
+                ws_in = jax.lax.pcast(ws_in, axis_name, to="varying")
+            (loss, aux), grads = jax.value_and_grad(
+                _loss, has_aux=True)(ws_in)
             if zero_world is not None:
                 # ZeRO-1: no full allreduce — the per-chunk reduce-
                 # scatter inside apply_zero_step_plan IS the gradient
@@ -428,7 +436,7 @@ class WholeStepCompiler:
         if mesh_info is not None:
             meta["buckets"] = (len(plan) if zero_world is not None
                                else self._count_buckets(plan))
-            from ..parallel import mesh as _mesh_mod
+            import jax
             from jax.sharding import PartitionSpec as P
 
             mesh, axis = mesh_info
@@ -437,7 +445,7 @@ class WholeStepCompiler:
             # replica axis (in and out), so each device allocates only
             # its 1/world slice — the ZeRO-1 memory contract
             sts_spec = P(axis) if zero_world is not None else P()
-            fn = _mesh_mod.shard_map()(
+            fn = jax.shard_map(
                 _whole_step_fn, mesh=mesh,
                 in_specs=(P(), P(), sts_spec, P(), data,
                           data if has_y else P(), P()),
@@ -701,6 +709,11 @@ class WholeStepCompiler:
         t = self.trainer
         mesh, axis = mesh_info
         sh = NamedSharding(mesh, P(axis))
+        # rank -> device for the rebind after the step, which must not
+        # ask the (by then donated) shard holders where they live
+        self._zero_rank_devs = {
+            r: c.jax_device()
+            for r, c in self._zero_rank_ctx(mesh_info).items()}
         out = []
         for c, (_k, _s, n_states, _dt, _idxs, _total, padded) in \
                 enumerate(plan):
@@ -739,8 +752,7 @@ class WholeStepCompiler:
                            for s in garr.addressable_shards}
                 vmap = {}
                 for r in sorted(entry):
-                    dev = entry[r][slot].context.jax_device()
-                    data = per_dev.get(dev)
+                    data = per_dev.get(self._zero_rank_devs[r])
                     if data is not None:
                         entry[r][slot]._data = data
                         vmap[r] = data
@@ -780,10 +792,14 @@ class WholeStepCompiler:
     def _bind_state_views(self, i):
         st_nds = self._state_nds(i)
         views = []
+        # the holder's device comes from the parameter's context, never
+        # from the holder's array: after a donating step that array IS
+        # the donated buffer, and a real chip has deleted it (the CPU
+        # backend ignores donation, so only a chip shows this)
+        dev = self.trainer._params[i].list_ctx()[0].jax_device()
         for nd_, garr in zip(st_nds, self._gstates[i]):
             view = {s.device: s.data
-                    for s in garr.addressable_shards}.get(
-                        nd_.context.jax_device())
+                    for s in garr.addressable_shards}.get(dev)
             if view is None:  # ctx0 device not in mesh: keep ctx0 copy
                 view = nd_._data
             else:

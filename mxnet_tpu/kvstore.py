@@ -19,6 +19,7 @@ reference's KVStoreDistServer sync-mode update.
 from __future__ import annotations
 
 import jax
+from jax._src.lax.parallel import all_gather_invariant
 
 from .base import MXNetError
 from .ndarray.ndarray import NDArray, _wrap
@@ -729,7 +730,10 @@ def traced_allgather_flat(shard, shapes, axis_name):
     ``shapes`` (the zero-pad tail is never read)."""
     from . import engine
 
-    full = jax.lax.all_gather(shard, axis_name, axis=0, tiled=True)
+    # the gathered bucket is the same on every rank, and must be TYPED
+    # so for a replicated out_spec to accept it; jax 0.9.0 keeps the
+    # varying -> invariant form of all_gather out of jax.lax
+    full = all_gather_invariant(shard, axis_name, axis=0, tiled=True)
     return list(engine._k_unflatten(
         full, shapes=tuple(tuple(int(d) for d in s) for s in shapes)))
 

@@ -107,13 +107,16 @@ class BERTModel(HybridBlock):
         from .. import ndarray as F
 
         seq_len = inputs.shape[1]
-        positions = F.arange(0, seq_len, dtype="int32")
+        # arange_like, not arange: a creation op lands on the DEFAULT
+        # context (mx.cpu() — the host, even beside a chip), while this
+        # one is computed from `inputs` and so lives where they live
+        positions = F.contrib.arange_like(inputs, axis=1)
         x = self.word_embed(inputs) + self.token_type_embed(token_types)
         x = x + self.position_embed(positions)
         x = self.embed_dropout(self.embed_ln(x))
         mask = None
         if valid_length is not None:
-            steps = F.arange(0, seq_len, dtype="float32")
+            steps = positions.astype("float32")
             m = F.broadcast_lesser(
                 steps.reshape(1, -1), valid_length.reshape(-1, 1))
             mask = (m.reshape(m.shape[0], 1, 1, seq_len) - 1.0) * 1e9
@@ -148,7 +151,8 @@ class BERTModel(HybridBlock):
             b, S = inputs.shape[0], inputs.shape[1]
             K = masked_positions.shape[1]
             flat = seq.reshape(b * S, self._units)
-            offsets = F.arange(0, b, dtype="int32").reshape(b, 1) * S
+            offsets = F.contrib.arange_like(inputs, axis=0) \
+                .astype("int32").reshape(b, 1) * S
             fidx = (masked_positions.astype("int32") + offsets) \
                 .reshape(b * K)
             mlm_in = F.take(flat, fidx).reshape(b, K, self._units)

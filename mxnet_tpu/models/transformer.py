@@ -97,8 +97,13 @@ class TransformerModel(HybridBlock):
         self.out_proj = nn.Dense(tgt_vocab, flatten=False)
         self.dropout = nn.Dropout(dropout)
 
-    def _mask_from_len(self, F, valid_length, q_len, k_len):
-        steps = F.arange(0, k_len, dtype="float32")
+    def _mask_from_len(self, F, valid_length, keys):
+        """Additive (b, 1, 1, k_len) key-padding mask for the (b, k_len)
+        token array `keys`.  arange_like, not arange: a creation op
+        lands on the default context (the host, even beside a chip);
+        this one is computed from `keys` and lives where they live."""
+        k_len = keys.shape[1]
+        steps = F.contrib.arange_like(keys, axis=1).astype("float32")
         m = F.broadcast_lesser(steps.reshape(1, -1),
                                valid_length.reshape(-1, 1))
         return (m.reshape(m.shape[0], 1, 1, k_len) - 1.0) * 1e9
@@ -111,7 +116,7 @@ class TransformerModel(HybridBlock):
         x = self.dropout(x)
         mask = None
         if src_valid_len is not None:
-            mask = self._mask_from_len(F, src_valid_len, s, s)
+            mask = self._mask_from_len(F, src_valid_len, src)
         for layer in self.enc_layers:
             x = layer(x, None, mask, None)
         return x, mask

@@ -419,6 +419,14 @@ class NDArray:
     def __setitem__(self, idx, value):
         if isinstance(value, NDArray):
             v = value._data
+            # ref: a[:] = b is CopyFromTo — it crosses contexts (a
+            # constant made on the default context, the host, assigned
+            # into a parameter that lives on the chip)
+            if not isinstance(v, jax.core.Tracer) and \
+                    not isinstance(self._data, jax.core.Tracer):
+                dst = self._data.devices()
+                if len(dst) == 1 and v.devices() != dst:
+                    v = jax.device_put(v, next(iter(dst)))
         else:
             v = jnp.asarray(value, self._data.dtype)
         tree, arrays = _encode_index(idx)

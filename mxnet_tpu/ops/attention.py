@@ -7,9 +7,11 @@ fused scaled-dot-product attention op (capability upgrade per SURVEY
 API-compatible').
 
 Two paths: a Pallas flash-attention kernel on TPU (ops/pallas/
-flash_attention.py) and this XLA fallback; the fallback is the oracle.
-Selection is automatic by platform; MXTPU_DISABLE_PALLAS=1 forces the
-fallback.
+flash_attention.py) and this XLA form, which is also the oracle.
+Selection is by shape and by the platform the computation is LOWERED
+for (MXTPU_DISABLE_PALLAS=1 forces the XLA form); a kernel that fails
+to trace or compile on a shape its gates admit is an error, never a
+quiet switch of path.
 """
 from __future__ import annotations
 
@@ -20,15 +22,6 @@ import jax.numpy as jnp
 
 from ..base import getenv
 from .registry import register
-
-
-def _use_pallas():
-    if getenv("DISABLE_PALLAS", False, bool):
-        return False
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
 
 
 def sdpa_reference(q, k, v, mask=None, *, scale=None, causal=False):
@@ -56,15 +49,31 @@ def sdpa_reference(q, k, v, mask=None, *, scale=None, causal=False):
 
 def _k_sdpa(q, k, v, mask=None, *, scale=None, causal=False,
             dropout_p=0.0):
-    if _use_pallas():
-        try:
-            from .pallas.flash_attention import flash_attention
+    if getenv("DISABLE_PALLAS", False, bool):
+        return sdpa_reference(q, k, v, mask, scale=scale, causal=causal)
+    from .pallas.flash_attention import flash_attention
 
-            return flash_attention(q, k, v, mask=mask, scale=scale,
-                                   causal=causal)
-        except Exception:  # pragma: no cover - pallas fallback safety
-            pass
-    return sdpa_reference(q, k, v, mask, scale=scale, causal=causal)
+    # the branch is picked when the computation is lowered, by the
+    # platform it is lowered FOR — not by the process's default
+    # backend: on a TPU host a block living on mx.cpu() takes the XLA
+    # form, and a step compiled ahead of time for a described chip
+    # takes the kernel
+    def _xla(q, k, v, mask):
+        # both branches return the query's dtype (an additive f32 mask
+        # would otherwise promote the XLA form of a bf16 model to f32)
+        return sdpa_reference(q, k, v, mask, scale=scale,
+                              causal=causal).astype(q.dtype)
+
+    def _tpu(q, k, v, mask):
+        from ..parallel.mesh import per_batch_shard
+
+        return per_batch_shard(
+            functools.partial(flash_attention, scale=scale,
+                              causal=causal), q, k, v, mask
+        ).astype(q.dtype)
+
+    return jax.lax.platform_dependent(q, k, v, mask, tpu=_tpu,
+                                      default=_xla)
 
 
 register("scaled_dot_product_attention", _k_sdpa,

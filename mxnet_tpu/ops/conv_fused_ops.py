@@ -2,11 +2,10 @@
 
 Ref: src/operator/nn/batch_norm.cu + cudnn
 CUDNN_FUSED_SCALE_BIAS_ACTIVATION_CONV_BNSTATS — the reference's fused
-scale-bias-act-conv-bnstats kernels.  Capability upgrade per the r2
-roofline analysis (docs/BENCHMARKS.md): XLA keeps BN's stats and
-normalize passes as separate HBM round trips, bounding ResNet-50 near
-20% MFU on v5e; these ops fuse them into the 1x1 convolutions'
-matmuls via the Pallas kernels in ops/pallas/conv_fused.py.
+scale-bias-act-conv-bnstats kernels.  XLA keeps BN's stats and
+normalize passes as separate HBM round trips; these ops fuse them into
+the 1x1 convolutions' matmuls via the Pallas kernels in
+ops/pallas/conv_fused.py (never timed on a chip: ROADMAP S3).
 
 Two ops, chained by the model block (gluon model_zoo BottleneckV1 under
 ``MXTPU_CONV_EPILOGUE=pallas``, NHWC only):
@@ -27,8 +26,6 @@ reference forms, so these ops are correct everywhere and fast where it
 matters.
 """
 from __future__ import annotations
-
-import warnings
 
 import jax.numpy as jnp
 from jax import lax
@@ -131,18 +128,10 @@ def _k_bn_fold(data, gamma, beta, moving_mean, moving_var, *, eps=1e-5,
 
         # same gate as the sibling kernels: off-TPU the pallas stats
         # kernel fails at XLA lowering, so only dispatch it when the
-        # backend gate and shape support both say yes; the except
-        # covers ONLY the pallas call itself, so a real kernel defect
-        # is not silently hidden behind the jnp fallback
-        ss = qq = None
+        # backend gate and shape support both say yes
         if _use_pallas() and _pbn.stats_supported(n, C):
-            try:
-                ss, qq = _pbn.bn_stats(x2d)
-            except Exception as e:  # pragma: no cover - TPU-only path
-                warnings.warn(
-                    f"pallas bn_stats failed ({type(e).__name__}: {e}); "
-                    "falling back to the XLA reduction")
-        if ss is None:
+            ss, qq = _pbn.bn_stats(x2d)
+        else:
             xf = x2d.astype(jnp.float32)
             ss = jnp.sum(xf, axis=0, keepdims=True)
             qq = jnp.sum(xf * xf, axis=0, keepdims=True)

@@ -373,19 +373,16 @@ def _k_batch_norm(data, gamma, beta, moving_mean, moving_var, *,
             n *= data.shape[i]
         mean = sumsq_mean = None
         if axis == data.ndim - 1 and _bn_stats_use_pallas():
-            try:
-                from .pallas import batch_norm as _pbn
+            from .pallas import batch_norm as _pbn
 
-                M = int(n)
-                C = data.shape[-1]
-                if _pbn.stats_supported(M, C):
-                    # one-pass fused stats kernel: XLA's two separate
-                    # reduce fusions for mean / mean(x^2) were ~half the
-                    # ResNet-50 step (see ops/pallas/batch_norm.py)
-                    s, q = _pbn.bn_stats(data.reshape(M, C))
-                    mean, sumsq_mean = s / n, q / n
-            except Exception:  # pragma: no cover - pallas fallback safety
-                mean = sumsq_mean = None
+            M = int(n)
+            C = data.shape[-1]
+            if _pbn.stats_supported(M, C):
+                # one-pass fused stats kernel: XLA's two separate
+                # reduce fusions for mean / mean(x^2) were ~half the
+                # ResNet-50 step (see ops/pallas/batch_norm.py)
+                s, q = _pbn.bn_stats(data.reshape(M, C))
+                mean, sumsq_mean = s / n, q / n
         if mean is None:
             mean = jnp.mean(data, axis=red, dtype=jnp.float32)
             sumsq_mean = jnp.mean(jnp.square(data), axis=red,

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_call
+
 
 def _block_rows(M, C):
     """Largest row block that divides M, keeps sublane alignment, and
@@ -46,7 +48,7 @@ def _stats_kernel(x_ref, sum_ref, sq_ref):
 def _stats_pallas(x2d):
     M, C = x2d.shape
     bm = _block_rows(M, C)
-    s, q = pl.pallas_call(
+    s, q = pallas_call(
         _stats_kernel,
         grid=(M // bm,),
         in_specs=[pl.BlockSpec((bm, C), lambda i: (i, 0),

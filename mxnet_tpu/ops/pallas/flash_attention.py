@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_call
+
 _NEG_INF = -1e9
 
 
@@ -180,7 +182,7 @@ def _flash_forward_stream(q, k, v, *, causal, scale, kmask=None,
             memory_space=pltpu.VMEM))
         args.append(kmask.astype(jnp.float32).reshape(b, 1, sk))
 
-    out, lse = pl.pallas_call(
+    out, lse = pallas_call(
         functools.partial(_flash_fwd_stream_kernel, causal=causal,
                           scale=scale, has_mask=kmask is not None,
                           num_kb=num_kb),
@@ -229,7 +231,7 @@ def _flash_forward(q, k, v, *, causal, scale, kmask=None,
         args.append(kmask.astype(jnp.float32).reshape(b, 1, sk))
 
     grid = (bh, sq // block_q)
-    out, lse = pl.pallas_call(
+    out, lse = pallas_call(
         functools.partial(_flash_fwd_kernel, block_k=block_k,
                           causal=causal, scale=scale, seq_k=sk,
                           has_mask=kmask is not None),
@@ -379,7 +381,7 @@ def _flash_backward_stream(q, k, v, o, lse, do, *, causal, scale,
         dq_specs.append(pl.BlockSpec((1, 1, block_k), _km_blk,
                                      memory_space=pltpu.VMEM))
         dq_args.append(km3)
-    dq = pl.pallas_call(
+    dq = pallas_call(
         functools.partial(_flash_dq_stream_kernel, causal=causal,
                           scale=scale, has_mask=has_mask,
                           num_kb=num_kb),
@@ -404,7 +406,7 @@ def _flash_backward_stream(q, k, v, o, lse, do, *, causal, scale,
             (1, 1, block_k), lambda i, j, kk: (i // h, 0, j),
             memory_space=pltpu.VMEM))
         dkv_args.append(km3)
-    dk, dv = pl.pallas_call(
+    dk, dv = pallas_call(
         functools.partial(_flash_dkv_stream_kernel, causal=causal,
                           scale=scale, has_mask=has_mask,
                           num_qb=num_qb),
@@ -557,7 +559,7 @@ def _flash_backward(q, k, v, o, lse, do, *, causal, scale, kmask=None,
         dq_specs.append(km_spec)
         dq_args.append(km3)
 
-    dq = pl.pallas_call(
+    dq = pallas_call(
         functools.partial(_flash_dq_kernel, block_k=block_k,
                           causal=causal, scale=scale, seq_k=sk,
                           has_mask=has_mask),
@@ -583,7 +585,7 @@ def _flash_backward(q, k, v, o, lse, do, *, causal, scale, kmask=None,
         dkv_specs.append(km_spec)
         dkv_args.append(km3)
 
-    dk, dv = pl.pallas_call(
+    dk, dv = pallas_call(
         functools.partial(_flash_dkv_kernel, block_q=block_q,
                           causal=causal, scale=scale, seq_q=sq,
                           has_mask=has_mask),
@@ -609,12 +611,11 @@ def _tiles_ok(q, k, block_q=128, block_k=128):
     b, h, sq, d = q.shape
     sk = k.shape[2]
     # head_dim 64 is the common transformer case (BERT/GPT heads) and
-    # tiles onto the MXU fine (lane dim padded to 128); requiring
-    # d % 128 == 0 silently pushed every 64-dim model onto the XLA
-    # fallback path
-    if d % 128 != 0:
-        if d % 64 != 0 or not _headdim64_allowed():
-            return False
+    # tiles onto the MXU fine (lane dim padded to 128);
+    # tests/test_aot_tpu.py compiles it for the chip at BERT-base's
+    # shape, so the rule is static — no compile probe at dispatch
+    if d % 64 != 0:
+        return False
     return (sq % block_q == 0 and sk % block_k == 0
             and sq >= block_q and sk >= block_k)
 
@@ -633,44 +634,6 @@ def _kv_resident(q, k):
     itemsize = 2 if q.dtype in (jnp.bfloat16, jnp.float16) else 4
     kv_mb = 2 * sk * d * itemsize / 1e6
     return kv_mb <= getenv("FLASH_MAX_KV_VMEM_MB", 8.0, float)
-
-
-def _headdim64_allowed():
-    """Whether the d%64 (non-128-multiple) tiling may hit the kernel.
-
-    A Mosaic lowering failure for this tiling would surface at
-    jit-compile time — after trace time, so past the try/except in
-    ops/attention._k_sdpa — leaving no runtime fallback.  On real TPU we
-    therefore compile-probe a tiny d=64 instance ONCE per process via
-    the shared pallas probe (ops/pallas/probe.py latching rules); off
-    TPU (interpret mode) the kernel is interpreter-checked and always
-    allowed.  MXTPU_FLASH_HEADDIM64=1/0 forces the answer either way.
-    """
-    from ...base import getenv
-
-    forced = getenv("FLASH_HEADDIM64", None, bool)
-    if forced is not None:
-        return forced
-    try:
-        on_tpu = jax.default_backend() == "tpu"
-    except RuntimeError:
-        on_tpu = False
-    if not on_tpu:
-        return True
-    from .probe import probe_ok
-
-    return probe_ok("flash_headdim64", _d64_compile_probe)
-
-
-def _d64_compile_probe():
-    """Compile value-and-grad in both training dtypes so a Mosaic
-    rejection of the BACKWARD d=64 tiling (or the bf16 variant) is
-    caught here, not at the user's jit compile."""
-    for dt in (jnp.float32, jnp.bfloat16):
-        q = jnp.zeros((1, 1, 128, 64), dt)
-        jax.jit(jax.grad(
-            lambda a: _flash_sdpa(a, a, a, None, False, 0.125)
-            .astype(jnp.float32).sum())).lower(q).compile()
 
 
 def _fwd_dispatch(q, k):

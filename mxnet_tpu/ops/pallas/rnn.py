@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_call
+
 
 # ---------------------------------------------------------------------------
 # forward
@@ -85,7 +87,7 @@ def _lstm_forward(x_proj, wh, h0, c0):
     xp4 = x_proj.reshape(T, N, 4, H)
     # pre-transpose per-gate so the kernel's dots need no in-kernel .T
     wh4 = wh.reshape(4, H, H).transpose(0, 2, 1)
-    outs = pl.pallas_call(
+    outs = pallas_call(
         _lstm_fwd_kernel,
         grid=(T,),
         in_specs=[
@@ -193,7 +195,7 @@ def _lstm_backward(wh, h0, c0, ys, gates, cs, dys, dhn, dcn):
 
     rev3 = lambda t: (T - 1 - t, 0, 0)     # noqa: E731
     rev4 = lambda t: (T - 1 - t, 0, 0, 0)  # noqa: E731
-    outs = pl.pallas_call(
+    outs = pallas_call(
         _lstm_bwd_kernel,
         grid=(T,),
         in_specs=[
@@ -330,7 +332,7 @@ def _gru_forward(x_proj, wh, bh, h0):
     xp3 = x_proj.reshape(T, N, 3, H)
     wh3 = wh.reshape(3, H, H).transpose(0, 2, 1)
     bh3 = bh.reshape(3, 1, H)
-    return pl.pallas_call(
+    return pallas_call(
         _gru_fwd_kernel,
         grid=(T,),
         in_specs=[
@@ -418,7 +420,7 @@ def _gru_backward(wh, h0, ys, gates, hn_lin, dys, dhn):
                              0)
     rev3 = lambda t: (T - 1 - t, 0, 0)     # noqa: E731
     rev4 = lambda t: (T - 1 - t, 0, 0, 0)  # noqa: E731
-    return pl.pallas_call(
+    return pallas_call(
         _gru_bwd_kernel,
         grid=(T,),
         in_specs=[

@@ -104,8 +104,9 @@ def _scan_dir(step, xs, init, wi, wh, bi, bh, reverse):
     return carry, ys
 
 
-def _use_pallas_lstm():
-    """Pallas recurrence kernel on TPU (MXTPU_RNN_IMPL=auto|pallas|scan)."""
+def _use_pallas_rnn():
+    """Pallas LSTM/GRU recurrence kernels on TPU
+    (MXTPU_RNN_IMPL=auto|pallas|scan)."""
     from ..base import getenv
 
     impl = getenv("RNN_IMPL", "auto").lower()
@@ -113,35 +114,9 @@ def _use_pallas_lstm():
         return False
     if impl == "pallas":
         return True
-    try:
-        on_tpu = jax.default_backend() == "tpu"
-    except Exception:
-        return False
-    if not on_tpu:
-        return False
-    # auto on TPU: one-time Mosaic compile probe so an un-lowerable
-    # recurrence kernel degrades to the lax.scan path instead of
-    # erroring mid-train (VERDICT r3 #2; MXTPU_PALLAS_RNN_OK overrides)
-    from .pallas.probe import probe_ok
-
-    return probe_ok("rnn", _lstm_compile_probe)
-
-
-def _lstm_compile_probe():
-    """Compile tiny value-and-grad LSTM recurrences, f32 and bf16."""
-    from .pallas.rnn import lstm_layer
-
-    T, N, H = 2, 8, 128
-    for dt in (jnp.float32, jnp.bfloat16):
-        xp = jnp.zeros((T, N, 4 * H), dt)
-        wh = jnp.zeros((4 * H, H), dt)
-        h0 = jnp.zeros((N, H), dt)
-        c0 = jnp.zeros((N, H), dt)
-
-        def _loss(a, b, c, d):
-            return lstm_layer(a, b, c, d)[0].astype(jnp.float32).sum()
-
-        jax.jit(jax.grad(_loss)).lower(xp, wh, h0, c0).compile()
+    # auto: the kernel on TPU wherever _pallas_lstm_fits admits the
+    # shape; a Mosaic rejection there is a loud compile error
+    return jax.default_backend() == "tpu"
 
 
 def _pallas_lstm_fits(N, H, G=4):
@@ -153,43 +128,6 @@ def _pallas_lstm_fits(N, H, G=4):
                + 3 * N * G * H    # x_proj block + gates out + dgates
                + 6 * N * H)       # h/c scratch + ys/cs blocks
     return 2 * est < 12 * 1024 * 1024
-
-
-def _use_pallas_gru():
-    """Pallas GRU recurrence on TPU (same gating scheme as the LSTM)."""
-    from ..base import getenv
-
-    impl = getenv("RNN_IMPL", "auto").lower()
-    if impl == "scan":
-        return False
-    if impl == "pallas":
-        return True
-    try:
-        on_tpu = jax.default_backend() == "tpu"
-    except Exception:
-        return False
-    if not on_tpu:
-        return False
-    from .pallas.probe import probe_ok
-
-    return probe_ok("gru", _gru_compile_probe)
-
-
-def _gru_compile_probe():
-    """Compile tiny value-and-grad GRU recurrences, f32 and bf16."""
-    from .pallas.rnn import gru_layer
-
-    T, N, H = 2, 8, 128
-    for dt in (jnp.float32, jnp.bfloat16):
-        xp = jnp.zeros((T, N, 3 * H), dt)
-        wh = jnp.zeros((3 * H, H), dt)
-        bh = jnp.zeros((3 * H,), dt)
-        h0 = jnp.zeros((N, H), dt)
-
-        def _loss(a, b, c, d):
-            return gru_layer(a, b, c, d)[0].astype(jnp.float32).sum()
-
-        jax.jit(jax.grad(_loss)).lower(xp, wh, bh, h0).compile()
 
 
 def _pallas_gru_dir(xs, init, wi, wh, bi, bh, reverse):
@@ -235,8 +173,8 @@ def _k_rnn(data, parameters, state, state_cell=None, key=None, *,
     step = _step_fn(mode)
     is_lstm = mode == "lstm"
 
-    pallas_lstm = is_lstm and _use_pallas_lstm()
-    pallas_gru = mode == "gru" and _use_pallas_gru()
+    pallas_lstm = is_lstm and _use_pallas_rnn()
+    pallas_gru = mode == "gru" and _use_pallas_rnn()
     x = data
     h_states, c_states = [], []
     for layer in range(num_layers):

@@ -8,6 +8,8 @@ XLA inserting ICI collectives from sharding annotations.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 
 import numpy as np
@@ -82,6 +84,68 @@ def batch_sharding(mesh, axis=None):
     return NamedSharding(mesh, PartitionSpec(axis))
 
 
+# the mesh of the jit-with-shardings step being traced, if any
+_partitioned_mesh = contextvars.ContextVar("mxtpu_partitioned_mesh",
+                                           default=None)
+
+
+@contextlib.contextmanager
+def auto_partitioned(mesh):
+    """Declare that the code traced inside runs under ``jax.jit`` with
+    shardings on ``mesh`` — i.e. the compiler partitions it
+    automatically.  Ops the compiler cannot partition (Mosaic kernels)
+    read it through :func:`per_batch_shard`."""
+    token = _partitioned_mesh.set(mesh)
+    try:
+        yield
+    finally:
+        _partitioned_mesh.reset(token)
+
+
+def per_batch_shard(fn, *arrays):
+    """``fn(*arrays)``, computed shard by shard over the leading (batch)
+    dim when the surrounding step is automatically partitioned over
+    more than one data shard.  The compiler refuses to partition a
+    Mosaic kernel ("cannot be automatically partitioned"), so a kernel
+    whose math is independent per example is wrapped in a ``shard_map``
+    over the batch axes here, where it is called.  ``None`` entries of
+    ``arrays`` are passed through; every other entry carries the batch
+    on dim 0 (or 1 there, to broadcast over it), and so does the
+    result.  Mesh axes that do not shard the batch see the operands
+    replicated."""
+    import jax
+    from jax.sharding import PartitionSpec
+
+    mesh = _partitioned_mesh.get()
+    axes = data_axes(mesh) if mesh is not None else ()
+    n = int(np.prod([mesh.shape[a] for a in axes])) if axes else 1
+    if n == 1:
+        return fn(*arrays)
+    spec = PartitionSpec(axes)
+    live = [i for i, a in enumerate(arrays) if a is not None]
+    in_specs = []
+    for i in live:
+        b = arrays[i].shape[0]
+        if b != 1 and b % n:
+            raise MXNetError(
+                f"batch {b} does not divide over the {n} data shards "
+                f"of mesh {dict(mesh.shape)}")
+        in_specs.append(PartitionSpec() if b == 1 else spec)
+
+    def body(*present):
+        full = [None] * len(arrays)
+        for i, a in zip(live, present):
+            full[i] = a
+        return fn(*full)
+
+    # check_vma=False: the body is one kernel per shard with no
+    # collective in it — there is nothing for the replication check to
+    # find, and Pallas's interpreter (the CPU tests) trips over it
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=tuple(in_specs), out_specs=spec,
+        check_vma=False)(*(arrays[i] for i in live))
+
+
 def global_put(value, sharding):
     """device_put that also works on multi-process meshes.
 
@@ -126,48 +190,11 @@ def spmd_jit(sharded_fn, mesh, in_specs, out_specs, **kwargs):
                      tuple(sorted(kwargs.items())))
 
 
-def shard_map():
-    """jax's shard_map across version drift: top-level in modern jax,
-    jax.experimental.shard_map before that."""
-    try:
-        from jax import shard_map as sm
-        return sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-
-        # the legacy check_rep analyzer predates pcast/vma annotations
-        # and rejects the cond/fori carries this code marks via pcast
-        # (an identity on these versions) — disable it; the collectives
-        # themselves are unchanged
-        return functools.partial(sm, check_rep=False)
-
-
-def pcast(x, axis_name, to):
-    """jax.lax.pcast across version drift: an annotation for the
-    varying-manual-axes type system in modern jax; identity on versions
-    without it (which also don't enforce vma, so skipping is sound)."""
-    import jax
-
-    fn = getattr(jax.lax, "pcast", None)
-    return x if fn is None else fn(x, axis_name, to=to)
-
-
-def vma(x):
-    """x's varying-manual-axes set; empty where jax lacks the vma type
-    system (there `pcast` is an identity, consistently)."""
-    import jax
-
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return frozenset()
-    return getattr(typeof(x), "vma", frozenset())
-
-
 @functools.lru_cache(maxsize=64)
 def _spmd_jit(sharded_fn, mesh, in_specs, out_specs, kwargs_items):
     import jax
 
-    return jax.jit(shard_map()(
+    return jax.jit(jax.shard_map(
         functools.partial(sharded_fn, **dict(kwargs_items)),
         mesh=mesh, in_specs=in_specs, out_specs=out_specs))
 

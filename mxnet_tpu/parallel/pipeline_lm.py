@@ -50,7 +50,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..base import MXNetError
-from . import mesh as _mesh_mod
 
 
 def init_pipeline_lm(vocab, d_model, n_layers, d_ff, n_heads, seq_len,
@@ -219,10 +218,8 @@ def _lm_sharded(params, toks, targets, *, n_micro, P, tp, sp, n_heads,
         # cotangent psums INSIDE the branch — a collective that the
         # other devices never join (deadlock).  Casting here moves the
         # transpose psum to this (unconditional) point.
-        have = _mesh_mod.vma(x)
-        missing = tuple(axes - set(have))
-        return (_mesh_mod.pcast(x, missing, to="varying")
-                if missing else x)
+        return jax.lax.pcast(x, tuple(axes - jax.typeof(x).vma),
+                             to="varying")
 
     blocks = jax.tree.map(lambda p: p[0], params["blocks"])  # local stage
     emb = jax.tree.map(vma3, params["embed"])
@@ -286,13 +283,7 @@ def _lm_sharded(params, toks, targets, *, n_micro, P, tp, sp, n_heads,
         # each sp shard scored its own sequence slice
         loss = jax.lax.pmean(loss, sp_axis)
     # identical on every tp member already; make it collective-visible
-    loss = jax.lax.pmean(loss, tp_axis)
-    # value is now equal on every device: cast back to replicated so
-    # out_specs=P() accepts it
-    have = _mesh_mod.vma(loss)
-    if have:
-        loss = _mesh_mod.pcast(loss, tuple(have), to="invarying")
-    return loss
+    return jax.lax.pmean(loss, tp_axis)
 
 
 class PipelineLMTrainer:
@@ -358,7 +349,7 @@ class PipelineLMTrainer:
             sp=self.sp, n_heads=n_heads, pp_axis=pp_axis,
             tp_axis=tp_axis, dp_axis=dp_axis, sp_axis=self._sp_axis,
             remat=bool(remat))
-        sharded_loss = _mesh_mod.shard_map()(
+        sharded_loss = jax.shard_map(
             lm, mesh=mesh,
             in_specs=(self._specs, data_spec, data_spec),
             out_specs=Ps())

@@ -58,9 +58,7 @@ def ring_attention_sharded(q, k, v, axis_name, *, causal=False, scale=None):
     acc = jnp.zeros(q.shape, jnp.float32)
     # mark the init carry as varying over the ring axis (shard_map vma
     # check: outputs of the loop body vary over 'sp')
-    from . import mesh as _mesh_mod
-
-    m, l, acc = _mesh_mod.pcast((m, l, acc), axis_name, to="varying")
+    m, l, acc = jax.lax.pcast((m, l, acc), axis_name, to="varying")
 
     def step(i, carry):
         m_prev, l_prev, acc_prev, k_cur, v_cur = carry

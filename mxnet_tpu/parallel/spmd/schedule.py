@@ -90,9 +90,7 @@ def _pipeline_sharded(params, xs_local, *, stage_fn, axis_name, n_micro,
     T = n_micro + P - 1
     # carries vary across the 'pp' axis (per-device state) — mark them
     # so shard_map's vma check accepts the fori_loop carry
-    from .. import mesh as _mesh_mod
-
-    acts, outs = _mesh_mod.pcast(
+    acts, outs = jax.lax.pcast(
         (jnp.zeros_like(xs_local[0]), jnp.zeros_like(xs_local)),
         axis_name, to="varying")
 
@@ -134,8 +132,6 @@ def pipeline_apply(stage_fn, stacked_params, x, mesh, axis="pp",
 
     from .. import mesh as mesh_mod
 
-    shard_map = mesh_mod.shard_map()
-
     P = mesh.shape[axis]
     n_micro = default_microbatches(P) if n_micro is None else int(n_micro)
     if n_micro < 1:
@@ -158,7 +154,7 @@ def pipeline_apply(stage_fn, stacked_params, x, mesh, axis="pp",
         # unhashable param pytree (dict specs): uncached fallback
         import functools
 
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             functools.partial(_pipeline_sharded, stage_fn=stage_fn,
                               axis_name=axis, n_micro=n_micro, P=P),
             mesh=mesh, in_specs=in_specs, out_specs=PartitionSpec()))
@@ -241,8 +237,6 @@ class PipelineTrainStep:
 
         from jax.sharding import PartitionSpec as PS
 
-        from .. import mesh as mesh_mod
-
         pspec = jax.tree.map(lambda _: PS(self.pp_axis), params)
         # batch arrives microbatch-major (n_micro, mb, ...): dim 1 — the
         # per-microbatch batch — shards over 'dp'; the microbatch dim is
@@ -253,7 +247,7 @@ class PipelineTrainStep:
             loss_fn=self.loss_fn, pp_axis=self.pp_axis,
             dp_axis=self.dp_axis, n_micro=self.n_micro, P=self.P,
             momentum=self.momentum)
-        return jax.jit(mesh_mod.shard_map()(
+        return jax.jit(jax.shard_map(
             body, mesh=self.mesh,
             in_specs=(pspec, pspec, data, data, PS()),
             out_specs=(PS(), pspec, pspec)))

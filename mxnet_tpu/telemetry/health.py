@@ -96,14 +96,16 @@ _SCOPE_PHASE = {
 }
 _STEP_SCOPES = ("trainer.step", "whole_step")
 
-# per-backend peak dense FLOP/s by device_kind substring (first match
-# wins — order matters: "v5p" before "v5").  CPU gets a NOMINAL figure
-# so MFU stays comparable across runs on a dev box; override with
-# MXTPU_HEALTH_PEAK_FLOPS for real hardware numbers.
+# peak dense bf16 FLOP/s by device_kind substring (public spec sheets;
+# v5e — which jax reports as "TPU v5 lite" — is 197 TFLOP/s per Google
+# Cloud's "TPU v5e" page).  The CPU backend gets a NOMINAL figure so
+# MFU stays comparable across runs on a dev box; an accelerator that is
+# not in the table is an error (set MXTPU_HEALTH_PEAK_FLOPS or add it
+# with its source), never a guess.
 _PEAK_FLOPS_TABLE = (
     ("v6", 918e12),
     ("v5p", 459e12),
-    ("v5", 197e12),
+    ("v5 lite", 197e12), ("v5litepod", 197e12), ("v5e", 197e12),
     ("v4", 275e12),
     ("v3", 123e12),
     ("v2", 45e12),
@@ -310,17 +312,19 @@ def _resolve_peak_flops(override=None):
     env = getenv("HEALTH_PEAK_FLOPS", None, float)
     if env:
         return float(env)
-    kind = ""
-    try:
-        import jax
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # noqa: BLE001 — no backend yet: nominal CPU
-        pass
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return _CPU_NOMINAL_PEAK
+    kind = dev.device_kind.lower()
     for sub, peak in _PEAK_FLOPS_TABLE:
         if sub in kind:
             return peak
-    return _CPU_NOMINAL_PEAK
+    raise MXNetError(
+        f"no published peak FLOP/s for device_kind {dev.device_kind!r}: "
+        "pass peak_flops= / set MXTPU_HEALTH_PEAK_FLOPS, or add the "
+        "device to telemetry.health's table with its source")
 
 
 # -- the monitor -------------------------------------------------------------

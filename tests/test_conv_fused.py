@@ -1,7 +1,7 @@
 """Pallas conv1x1+BN+ReLU epilogue-fusion kernels and ops (interpret
 mode on CPU).  Ref: the cuDNN fused-op pattern
 (CUDNN_FUSED_SCALE_BIAS_ACTIVATION_CONV_BNSTATS) rebuilt tpu-style —
-see ops/pallas/conv_fused.py and docs/BENCHMARKS.md roofline notes."""
+see ops/pallas/conv_fused.py."""
 import numpy as np
 import pytest
 

@@ -346,7 +346,8 @@ def test_pipeline_causal_attention_flash_parity(interpret_pallas,
                                                 monkeypatch):
     """_causal_attention's TPU route (Pallas flash, no (S,S) matrix in
     HBM) must match the XLA reference — checked in interpret mode with
-    the backend probe forced to the TPU branch, and with a spy proving
+    the platform dispatch steered onto its TPU branch (on this CPU
+    lax.platform_dependent lowers the XLA one), and with a spy proving
     the kernel ACTUALLY ran (a silent fallback would make this
     naive-vs-naive)."""
     import jax
@@ -370,7 +371,8 @@ def test_pipeline_causal_attention_flash_parity(interpret_pallas,
     naive = plm._causal_attention(q, k, v)
     assert not calls  # reference side really was the reference
     monkeypatch.delenv("MXTPU_DISABLE_PALLAS")
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax.lax, "platform_dependent",
+                        lambda *args, tpu, default: tpu(*args))
     flash = plm._causal_attention(q, k, v)
     assert calls, "flash kernel never ran (silent fallback)"
     np.testing.assert_allclose(np.asarray(flash), np.asarray(naive),

@@ -166,9 +166,7 @@ def test_sync_batch_norm_pmean_across_shard_map():
             axis_name="dp")
         return out
 
-    from mxnet_tpu.parallel import mesh as mesh_mod
-
-    sharded = jax.jit(mesh_mod.shard_map()(
+    sharded = jax.jit(jax.shard_map(
         shard_fn, mesh=mesh, in_specs=P("dp"), out_specs=P("dp")))(
             jnp.asarray(x))
     np.testing.assert_allclose(np.asarray(sharded), np.asarray(full),

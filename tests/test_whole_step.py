@@ -469,3 +469,20 @@ def test_profiler_whole_step_counters_window_scoped():
     again = json.loads(profiler.dumps(reset=True))["trainerStep"]
     assert again["whole_step_steps"] == 0
     assert again["whole_step_compiles"] == 0
+
+
+@pytest.mark.parametrize("n_ctx", [1, 4])
+def test_whole_step_survives_real_donation(donation_on, n_ctx):
+    """Found on four v5e chips (PR 22): after the donating step the
+    mesh path asked the optimizer-state HOLDER for its device — the
+    holder's array was the donated buffer, deleted on a chip (the CPU
+    backend ignores donation, so nothing here could see it)."""
+    ctxs = [mx.xla(i) for i in range(n_ctx)]
+    net, tr = build(True, ctx=ctxs, layers=2)
+    losses = [float(tr.whole_step(net, loss_fn, X, Y).asnumpy())
+              for _ in range(5)]
+    assert losses[-1] < losses[0]
+    net_r, tr_r = build(True, ctx=ctxs, layers=2)
+    ref = [float(tr_r.whole_step(net_r, loss_fn, X, Y).asnumpy())
+           for _ in range(5)]
+    np.testing.assert_allclose(losses, ref, rtol=1e-6)

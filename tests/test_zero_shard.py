@@ -192,7 +192,7 @@ def test_traced_bucket_reduce_scatter_allgather_roundtrip(monkeypatch):
              for r in range(WORLD)])
         for i, s in enumerate(shapes)]
 
-    sm = mesh_mod.shard_map()
+    sm = jax.shard_map
     f1 = jax.jit(sm(lambda gs: rs_ag(*[g[0] for g in gs]), mesh=mesh,
                     in_specs=(P("dp"),), out_specs=P()))
     f2 = jax.jit(sm(lambda gs: ar(*[g[0] for g in gs]), mesh=mesh,
@@ -525,3 +525,16 @@ def test_profiler_zero_counters_window_scoped():
     assert ts["zero_fallbacks"] == 0
     again = json.loads(profiler.dumps(reset=True))["trainerStep"]
     assert again["zero_steps"] == 0
+
+
+def test_zero_whole_step_survives_real_donation(donation_on):
+    """The ZeRO twin of test_whole_step_survives_real_donation: the
+    per-rank shard holders' arrays are donated with the step."""
+    net, tr = build(True, ctx=CTXS)
+    losses = [float(tr.whole_step(net, loss_fn, X, Y).asnumpy())
+              for _ in range(5)]
+    assert losses[-1] < losses[0]
+    net_u, tr_u = build(False, ctx=CTXS)
+    ref = [float(tr_u.whole_step(net_u, loss_fn, X, Y).asnumpy())
+           for _ in range(5)]
+    np.testing.assert_allclose(losses, ref, rtol=1e-5)

@@ -22,20 +22,9 @@ def main():
 
     if args.cpu_devices:
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", args.cpu_devices)
-        except AttributeError:  # older jax: flag-based device count
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "") +
-                f" --xla_force_host_platform_device_count="
-                f"{args.cpu_devices}").strip()
+        jax.config.update("jax_num_cpu_devices", args.cpu_devices)
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec
-
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
 
     devs = jax.devices()
     n = len(devs)
@@ -48,7 +37,7 @@ def main():
 
     @jax.jit
     def allreduce(x):
-        f = shard_map(lambda v: jax.lax.psum(v, "dp"), mesh=mesh,
+        f = jax.shard_map(lambda v: jax.lax.psum(v, "dp"), mesh=mesh,
                       in_specs=PartitionSpec("dp"),
                       out_specs=PartitionSpec())
         return f(x)

@@ -10,9 +10,9 @@ each key's direction — throughput-like (higher is better),
 latency/cost-like (lower is better), or informational — and reports
 every leaf whose value moved PAST its tolerance in the bad direction.
 
-With no history file yet, it falls back to the archived ``BENCH_r0*.json``
-driver snapshots (their ``parsed`` field is the same record shape), so
-the existing trajectory is readable before the first post-change run.
+A short history is padded from ``BENCH_r*.json`` driver snapshots
+beside it, if any are there (their ``parsed`` field is the same record
+shape); none are kept in the repo.
 
 Usage::
 
