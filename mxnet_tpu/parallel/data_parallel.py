@@ -11,20 +11,69 @@ with parameter donation for in-place update.
 Works with any HybridBlock + gluon Loss + optimizer name.  The eager
 Trainer (gluon/trainer.py) stays for MXNet-parity semantics; this class
 is the performance path the bench uses.
+
+What the host does in a step is always on record: `step()` reads the
+clock at the four boundaries of its three phases (the put of the batch,
+the key and the two scalars, the enqueue of the compiled step) and
+appends one tuple to a bounded log, `step_log()`.  The profiler section
+`dataParallelStep` is summed from that log.  The same phases are
+`profiler.op_scope` spans (`dp.step`, `dp.step.put`, ...), so a
+`jax.profiler` trace holds them on its host plane, and the compiled
+step's instructions carry `forward` / `optimizer` in their `op_name`
+(backward is `transpose(jvp(forward))`), so a device trace splits by
+phase (docs/observability.md, "Device trace").
 """
 from __future__ import annotations
 
-import functools
+import collections
+import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .. import autograd
+from .. import profiler as _profiler
 from .. import random as _random
 from ..base import MXNetError
 from ..ndarray.ndarray import NDArray, _wrap
 from . import mesh as mesh_mod
+
+# one record a `step()` call, oldest first: (trainer serial, t, begin_ns,
+# put_ns, args_ns, enqueue_ns, put_bytes).  `begin_ns` is
+# time.perf_counter_ns() when the put began; the three after it are the
+# phases' nanoseconds.  Always on: the cost is four clock reads, a tuple
+# and an append, and the readers run where nothing was armed beforehand.
+_step_log = collections.deque(maxlen=4096)
+_built = 0          # trainers built so far; the newest one's serial
+_window = (0, 0)    # the clock and `_built` when the section's window opened
+
+
+def step_log(last=None):
+    """The newest `last` records of the step log (all that are kept,
+    at most 4096, by default), oldest first."""
+    log = list(_step_log)
+    return log if last is None else log[max(len(log) - last, 0):]
+
+
+def data_parallel_step_stats():
+    """The `dataParallelStep` profiler section: the step log's records
+    since the window opened, summed.  A window of more steps than the
+    log keeps reports the newest 4096 of them."""
+    opened_ns, built_then = _window
+    records = [r for r in step_log() if r[2] >= opened_ns]
+
+    def ms(field):
+        return round(sum(r[field] for r in records) / 1e6, 3)
+
+    return {"steps": len(records), "builds": _built - built_then,
+            "put_ms": ms(3), "args_ms": ms(4), "enqueue_ms": ms(5),
+            "put_bytes": sum(r[6] for r in records)}
+
+
+def reset_data_parallel_step_stats():
+    global _window
+    _window = (time.perf_counter_ns(), _built)
 
 
 class DataParallelTrainer:
@@ -222,6 +271,11 @@ class DataParallelTrainer:
                 return r.astype(cdt)
             return r
 
+        # `forward` and `optimizer` below name the program's phases in
+        # every instruction's op_name: jit(step)/jvp(forward)/...,
+        # .../transpose(jvp(forward))/..., .../optimizer/...  Metadata
+        # only; a reader maps device events to phases through them
+        @jax.named_scope("forward")
         def forward_loss(param_raws, x_raw, y_raw, key):
             orig_dtypes = [r.dtype for r in param_raws]
             if cdt is not None:
@@ -339,22 +393,32 @@ class DataParallelTrainer:
 
         mesh = self.mesh
 
-        def step(params, states, x, y, key, lr, t):
+        # Named `step_phases`, not `step`, on purpose: JAX leaves metadata
+        # out of the persistent compile cache's key, so under the old name
+        # a cache filled by a build without the scopes above (the same
+        # arithmetic) would be LOADED in this one's place, and the
+        # compiled text would name no phase.  The program's name is in
+        # the key.  Whoever renames a scope without touching the
+        # arithmetic has to rename this too.  (Keying by metadata instead
+        # puts source paths and the tracing call stack into the key: one
+        # program, a key per checkout and per caller; PERF.md, PR 27.)
+        def step_phases(params, states, x, y, key, lr, t):
             with mesh_mod.auto_partitioned(mesh):
                 (loss, aux), grads = (
                     _grads_accum if accum > 1 else _grads_once)(
                         params, x, y, key)
             new_params, new_states = [], []
-            for raw, g, st, tr, new_raw in zip(params, grads, states,
-                                               trainable, aux):
-                if not tr:
-                    # non-trainable: take the aux-updated value (BN stats)
-                    new_params.append(new_raw)
-                    new_states.append(st)
-                else:
-                    nw, ns = apply_opt(raw, g, st, lr, t)
-                    new_params.append(nw)
-                    new_states.append(ns)
+            with jax.named_scope("optimizer"):
+                for raw, g, st, tr, new_raw in zip(params, grads, states,
+                                                   trainable, aux):
+                    if not tr:
+                        # non-trainable: the aux-updated value (BN stats)
+                        new_params.append(new_raw)
+                        new_states.append(st)
+                    else:
+                        nw, ns = apply_opt(raw, g, st, lr, t)
+                        new_params.append(nw)
+                        new_states.append(ns)
             return loss, tuple(new_params), tuple(new_states)
 
         data_sh = mesh_mod.batch_sharding(self.mesh)
@@ -371,10 +435,11 @@ class DataParallelTrainer:
         # in_shardings check rejects them
         out_shardings = (repl, tuple(self._param_shardings), state_sh)
         donate = (0, 1) if self._donate else ()
-        self._step_core = step
+        self._data_sh = data_sh
+        self._step_core = step_phases
         self._in_shardings = in_shardings
         self._out_shardings = out_shardings
-        self._step_fn = jax.jit(step, in_shardings=in_shardings,
+        self._step_fn = jax.jit(step_phases, in_shardings=in_shardings,
                                 out_shardings=out_shardings,
                                 donate_argnums=donate)
         self._many_fns = {}
@@ -472,6 +537,7 @@ class DataParallelTrainer:
         """Trace + compile the step for example input(s) `x` without
         running a step (needed before `load_states` on a fresh
         trainer). Idempotent."""
+        global _built
         if self._step_fn is not None:
             return
         multi = isinstance(x, (tuple, list))
@@ -484,9 +550,13 @@ class DataParallelTrainer:
                 x = x._data
             self._n_inputs = 1
             probe = _wrap(jnp.asarray(x[:2]))
-        self._gather_params(probe)
-        self._init_opt_states()
-        self._build_step()
+        with _profiler.op_scope("dp.build", "trainer") as scope:
+            self._gather_params(probe)
+            self._init_opt_states()
+            self._build_step()
+            scope.note(params=len(self._named))
+        _built += 1
+        self._serial = _built
 
     def step(self, x, y):
         """One compiled SPMD step; returns scalar loss NDArray.
@@ -495,27 +565,45 @@ class DataParallelTrainer:
         multi-input blocks (BERT: tokens/types/targets/...); every
         input is batch-sharded on the 'dp' mesh axis.
         """
-        multi = isinstance(x, (tuple, list))
-        if multi:
-            x = tuple(v._data if isinstance(v, NDArray) else v for v in x)
-        elif isinstance(x, NDArray):
-            x = x._data
-        if isinstance(y, NDArray):
-            y = y._data
-        self.build(x)
-        data_sh = mesh_mod.batch_sharding(self.mesh)
-        if multi:
-            x = tuple(mesh_mod.global_put(jnp.asarray(v), data_sh)
-                      for v in x)
-        else:
-            x = mesh_mod.global_put(jnp.asarray(x), data_sh)
-        y = mesh_mod.global_put(jnp.asarray(y), data_sh)
-        self._t += 1
-        key = _random.next_key()
-        loss, self._params, self._states = self._step_fn(
-            self._params, self._states, x, y, key,
-            jnp.asarray(self._lr, jnp.float32),
-            jnp.asarray(float(self._t), jnp.float32))
+        now = time.perf_counter_ns
+        t = self._t + 1
+        with _profiler.op_scope("dp.step", "trainer", t=t, step_num=t, _r=1):
+            multi = isinstance(x, (tuple, list))
+            if multi:
+                x = tuple(v._data if isinstance(v, NDArray) else v
+                          for v in x)
+            elif isinstance(x, NDArray):
+                x = x._data
+            if isinstance(y, NDArray):
+                y = y._data
+            self.build(x)
+            begin = now()
+            with _profiler.op_scope("dp.step.put", "trainer") as scope:
+                data_sh = self._data_sh
+                if multi:
+                    x = tuple(mesh_mod.global_put(jnp.asarray(v), data_sh)
+                              for v in x)
+                    put_bytes = sum(v.nbytes for v in x)
+                else:
+                    x = mesh_mod.global_put(jnp.asarray(x), data_sh)
+                    put_bytes = x.nbytes
+                y = mesh_mod.global_put(jnp.asarray(y), data_sh)
+                put_bytes += y.nbytes
+                scope.note(bytes=put_bytes)
+            put_end = now()
+            with _profiler.op_scope("dp.step.args", "trainer"):
+                self._t = t
+                key = _random.next_key()
+                lr = jnp.asarray(self._lr, jnp.float32)
+                t_arg = jnp.asarray(float(t), jnp.float32)
+            args_end = now()
+            with _profiler.op_scope("dp.step.enqueue", "trainer"):
+                loss, self._params, self._states = self._step_fn(
+                    self._params, self._states, x, y, key, lr, t_arg)
+            enqueue_end = now()
+            _step_log.append((self._serial, t, begin, put_end - begin,
+                              args_end - put_end, enqueue_end - args_end,
+                              put_bytes))
         return _wrap(loss)
 
     def step_many(self, x, y, n_steps=None):
@@ -546,33 +634,36 @@ class DataParallelTrainer:
             n_steps = int((x[0] if multi else x).shape[0])
         if n_steps < 1:
             raise MXNetError(f"step_many needs n_steps >= 1, got {n_steps}")
-        # build the single-step path first (shapes from ONE minibatch)
-        probe = tuple(v[0] for v in x) if (stacked and multi) else \
-            (x[0] if stacked else x)
-        self.build(probe)
-        fn = self._many_fns.get((n_steps, stacked)) or \
-            self._build_step_many(n_steps, stacked)
-        data_sh = mesh_mod.batch_sharding(self.mesh)
-        from jax.sharding import NamedSharding, PartitionSpec
+        with _profiler.op_scope("dp.step_many", "trainer", n_steps=n_steps):
+            # build the single-step path first (shapes from ONE minibatch)
+            probe = tuple(v[0] for v in x) if (stacked and multi) else \
+                (x[0] if stacked else x)
+            self.build(probe)
+            fn = self._many_fns.get((n_steps, stacked)) or \
+                self._build_step_many(n_steps, stacked)
+            data_sh = self._data_sh
+            from jax.sharding import NamedSharding, PartitionSpec
 
-        if stacked:
-            put_sh = NamedSharding(self.mesh,
-                                   PartitionSpec(None, *data_sh.spec))
-        else:
-            put_sh = data_sh
-        if multi:
-            x = tuple(mesh_mod.global_put(jnp.asarray(v), put_sh)
-                      for v in x)
-        else:
-            x = mesh_mod.global_put(jnp.asarray(x), put_sh)
-        y = mesh_mod.global_put(jnp.asarray(y), put_sh)
-        # consume the SAME key sequence n individual step() calls would
-        keys = jnp.stack([_random.next_key() for _ in range(n_steps)])
-        t0 = jnp.asarray(float(self._t + 1), jnp.float32)
-        self._t += n_steps
-        losses, self._params, self._states = fn(
-            self._params, self._states, x, y, keys,
-            jnp.asarray(self._lr, jnp.float32), t0)
+            if stacked:
+                put_sh = NamedSharding(self.mesh,
+                                       PartitionSpec(None, *data_sh.spec))
+            else:
+                put_sh = data_sh
+            with _profiler.op_scope("dp.step_many.put", "trainer"):
+                if multi:
+                    x = tuple(mesh_mod.global_put(jnp.asarray(v), put_sh)
+                              for v in x)
+                else:
+                    x = mesh_mod.global_put(jnp.asarray(x), put_sh)
+                y = mesh_mod.global_put(jnp.asarray(y), put_sh)
+            # consume the SAME key sequence n individual step() calls would
+            keys = jnp.stack([_random.next_key() for _ in range(n_steps)])
+            t0 = jnp.asarray(float(self._t + 1), jnp.float32)
+            lr = jnp.asarray(self._lr, jnp.float32)
+            self._t += n_steps
+            with _profiler.op_scope("dp.step_many.enqueue", "trainer"):
+                losses, self._params, self._states = fn(
+                    self._params, self._states, x, y, keys, lr, t0)
         return _wrap(losses)
 
     @property

@@ -6,6 +6,9 @@ Two tiers, per SURVEY §5:
    exactly like the reference's MXDumpProfile.
 2. XLA-level — ``start()`` can also open a jax.profiler trace
    (tensorboard-plugin-profile readable) capturing device timelines.
+   Every ``op_scope`` is also a ``jax.profiler.TraceAnnotation``, so
+   whoever runs a jax.profiler session finds the tier-1 scopes on the
+   host plane of the same ``.xplane.pb`` as the device's ops.
 """
 from __future__ import annotations
 
@@ -13,6 +16,8 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 from .base import getenv
 from .telemetry import health as _health
@@ -36,6 +41,7 @@ _xla_running = False
 # profiler records memory-pool events per device — profiler.cc
 # DeviceStats); sampled from PjRt memory_stats + the native staging pool
 _mem_peak = {"device_bytes_in_use": 0, "pool_used_bytes": 0}
+_mem_lock = threading.Lock()  # ops on several threads sample at once
 
 
 def set_config(**kwargs):
@@ -104,9 +110,10 @@ def _memory_sample():
             sample["pool_reserved_bytes"] = int(st["pool_bytes"])
     except Exception:
         pass
-    for k in _mem_peak:
-        if sample.get(k, 0) > _mem_peak[k]:
-            _mem_peak[k] = sample[k]
+    with _mem_lock:
+        for k in _mem_peak:
+            if sample.get(k, 0) > _mem_peak[k]:
+                _mem_peak[k] = sample[k]
     return sample
 
 
@@ -158,17 +165,25 @@ def active_scopes():
 
 
 class _OpScope:
-    __slots__ = ("name", "cat", "t0")
+    __slots__ = ("name", "cat", "attrs", "t0", "_ann")
 
-    def __init__(self, name, cat="operator"):
+    def __init__(self, name, cat, attrs):
         self.name = name
         self.cat = cat
+        self.attrs = attrs
 
     def __enter__(self):
         if _scope_track:
             with _scope_lock:
                 _open_scopes.setdefault(threading.get_ident(),
                                         []).append(self.name)
+        # an event on the host plane of whatever jax.profiler session is
+        # running (ours, a benchmark's, a TensorBoard capture), so host
+        # spans and device ops share one file and one clock; without a
+        # session TraceMe does nothing.  Unconditional: a binding armed
+        # by start() would miss every session something else starts
+        ann = self._ann = TraceAnnotation(self.name, **self.attrs)
+        ann.__enter__()
         # telemetry span hook: the disarmed binding is a ~ns no-op
         # (engine.fault_point pattern); armed, every op scope is a
         # span in the exported trace / flight-recorder ring
@@ -176,10 +191,17 @@ class _OpScope:
         self.t0 = time.perf_counter() * 1e6
         return self
 
-    def __exit__(self, exc_type, *a):
+    def note(self, **attrs):
+        """Attach what is known only inside the scope (bytes moved) to
+        the span and to its profiler-trace event."""
+        self.attrs.update(attrs)
+        self._ann.set_metadata(**attrs)
+
+    def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter() * 1e6
         record_op(self.name, self.t0, t1, cat=self.cat)
-        _tracer.span_end(self.name, self.cat)
+        _tracer.span_end(self.name, self.cat, **self.attrs)
+        self._ann.__exit__(exc_type, exc, tb)
         if exc_type is None:
             # health-monitor phase sink (telemetry.health): disarmed
             # it IS the module no-op, same ~ns contract as the tracer
@@ -194,10 +216,12 @@ class _OpScope:
                     stack.pop()
 
 
-def op_scope(name, cat="operator"):
+def op_scope(name, cat="operator", **attrs):
     """Trace bracket; `cat` groups rows in chrome://tracing (checkpoint
-    save/restore phases are tagged cat="checkpoint")."""
-    return _OpScope(name, cat)
+    save/restore phases are tagged cat="checkpoint").  `attrs` ride on
+    the telemetry span and on the jax.profiler trace event
+    (`step_num=` and `_r=1` make it a step of xprof's step view)."""
+    return _OpScope(name, cat, attrs)
 
 
 def _graph_cache_counters(reset=False):
@@ -360,6 +384,22 @@ def _tune_counters(reset=False):
     return stats
 
 
+def _data_parallel_step_counters(reset=False):
+    """`parallel.DataParallelTrainer` host-side step split (steps,
+    builds, put/args/enqueue ms, bytes put), summed from its step log
+    -- window-scoped under reset=True exactly like every other section;
+    only present when the data-parallel tier is loaded."""
+    import sys
+
+    dp = sys.modules.get(__package__ + ".parallel.data_parallel")
+    if dp is None:
+        return None
+    stats = dp.data_parallel_step_stats()
+    if reset:
+        dp.reset_data_parallel_step_stats()
+    return stats
+
+
 def _telemetry_counters(reset=False):
     """Telemetry-subsystem counters (spans/instants/requests recorded,
     drops, flight dumps, scrapes, aggregations) — window-scoped under
@@ -476,6 +516,14 @@ register_section("trainerStep", _trainer_step_counters, _rows_table(
      ("zero-sharded steps", "zero_steps"),
      ("zero-shard fallbacks", "zero_fallbacks"),
      ("spmd mesh steps", "spmd_steps"))))
+register_section("dataParallelStep", _data_parallel_step_counters, _rows_table(
+    "Data-Parallel Step (host side)",
+    (("steps", "steps"),
+     ("trainers built", "builds"),
+     ("batch put (ms)", "put_ms"),
+     ("key and scalars (ms)", "args_ms"),
+     ("step enqueue (ms)", "enqueue_ms"),
+     ("bytes put", "put_bytes"))))
 register_section("dataPipeline", _data_pipeline_counters, _rows_table(
     "Data Pipeline",
     (("batches delivered", "batches"),
@@ -651,8 +699,9 @@ def dump(finished=True, profile_process="worker"):
 def reset():
     with _events_lock:
         _events.clear()
-    for k in _mem_peak:
-        _mem_peak[k] = 0
+    with _mem_lock:
+        for k in _mem_peak:
+            _mem_peak[k] = 0
 
 
 def pause(profile_process="worker"):

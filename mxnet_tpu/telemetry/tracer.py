@@ -34,11 +34,11 @@ Span model:
   request across threads — how a serve request is traced
   submit→queue→dispatch→resolve.
 
-Per-thread buffers: a thread's spans append to its own list (no lock
-on the hot path); the global registry of lanes is only locked on
-first-touch and at export.  Each lane is capped (``_LANE_CAP``) so a
-runaway trace degrades by dropping (counted) instead of eating the
-heap.
+Per-thread buffers: a thread's spans append to its own list and bump
+its own lane's counts (no lock on the hot path); the global registry of
+lanes is only locked on first-touch, at export and when the counts are
+summed.  Each lane is capped (``_LANE_CAP``) so a runaway trace
+degrades by dropping (counted) instead of eating the heap.
 """
 from __future__ import annotations
 
@@ -54,8 +54,8 @@ _PID = os.getpid()
 _LANE_CAP = 200_000          # events per thread lane before dropping
 
 _lock = threading.Lock()     # lanes registry + arm/disarm + counters
-_lanes = []                  # [{"tid", "name", "events": []}]
-_state = threading.local()   # .events (this thread's lane), .stack
+_lanes = []                  # [{"tid", "name", "events": [], "counts": {}}]
+_state = threading.local()   # .lane (this thread's), .stack
 _trace_on = False
 _trace_path = None
 _flight_ring = None          # collections.deque(maxlen=...) when armed
@@ -65,12 +65,14 @@ _rid_counter = itertools.count(1)
 # later one — begin/end must see the same epoch to emit
 _epoch = 0
 
-# window-scoped telemetry counters (the profiler's "telemetry" section)
+# window-scoped telemetry counters (the profiler's "telemetry" section).
+# What every event bumps is counted per lane, by the lane's own thread
+# and never down: completed scope spans, point events, async request
+# spans opened, events lost to the per-lane cap.  telemetry_stats()
+# sums the lanes less their sums at the last reset.
+_LANE_COUNTS = ("spans", "instants", "requests", "dropped")
+_lane_base = dict.fromkeys(_LANE_COUNTS, 0)
 _counters = {
-    "spans": 0,              # completed scope spans recorded
-    "instants": 0,           # point events recorded
-    "requests": 0,           # async request spans opened
-    "dropped": 0,            # events lost to the per-lane cap
     "flight_dumps": 0,       # flight-recorder files written
     "scrapes": 0,            # /metrics renders served
     "aggregations": 0,       # telemetry.aggregate() calls
@@ -91,26 +93,26 @@ def _now_us():
     return time.perf_counter() * 1e6
 
 
-def _lane_events():
-    ev = getattr(_state, "events", None)
-    if ev is None:
-        ev = _state.events = []
-        _state.stack = []
+def _lane():
+    lane = getattr(_state, "lane", None)
+    if lane is None:
         th = threading.current_thread()
+        lane = _state.lane = {"tid": th.ident % 100000, "name": th.name,
+                              "events": [],
+                              "counts": dict.fromkeys(_LANE_COUNTS, 0)}
+        _state.stack = []
         with _lock:
-            _lanes.append({"tid": th.ident % 100000, "name": th.name,
-                           "events": ev})
-    return ev
+            _lanes.append(lane)
+    return lane
 
 
-def _emit(ev):
+def _emit(ev, lane):
     if _flight_ring is not None:
         _flight_ring.append(ev)     # deque.append is atomic
     if _trace_on:
-        events = _lane_events()
+        events = lane["events"]
         if len(events) >= _LANE_CAP:
-            with _lock:
-                _counters["dropped"] += 1
+            lane["counts"]["dropped"] += 1
             return
         events.append(ev)
 
@@ -121,7 +123,7 @@ def _clean_attrs(attrs):
 
 
 def _span_begin(name, cat="op"):
-    _lane_events()                   # ensure .stack exists
+    _lane()                          # ensure .stack exists
     _state.stack.append((name, _now_us(), _epoch))
 
 
@@ -139,9 +141,9 @@ def _span_end(name, cat="op", **attrs):
           "tid": threading.get_ident() % 100000, "cat": cat}
     if attrs:
         ev["args"] = _clean_attrs(attrs)
-    with _lock:
-        _counters["spans"] += 1
-    _emit(ev)
+    lane = _lane()
+    lane["counts"]["spans"] += 1
+    _emit(ev, lane)
 
 
 def _instant(name, cat="op", **attrs):
@@ -149,9 +151,9 @@ def _instant(name, cat="op", **attrs):
           "tid": threading.get_ident() % 100000, "cat": cat, "s": "t"}
     if attrs:
         ev["args"] = _clean_attrs(attrs)
-    with _lock:
-        _counters["instants"] += 1
-    _emit(ev)
+    lane = _lane()
+    lane["counts"]["instants"] += 1
+    _emit(ev, lane)
 
 
 def _request_begin(name, cat="request", **attrs):
@@ -160,9 +162,9 @@ def _request_begin(name, cat="request", **attrs):
           "tid": threading.get_ident() % 100000, "cat": cat, "id": rid}
     if attrs:
         ev["args"] = _clean_attrs(attrs)
-    with _lock:
-        _counters["requests"] += 1
-    _emit(ev)
+    lane = _lane()
+    lane["counts"]["requests"] += 1
+    _emit(ev, lane)
     return rid
 
 
@@ -173,7 +175,7 @@ def _request_instant(name, rid, cat="request", **attrs):
           "tid": threading.get_ident() % 100000, "cat": cat, "id": rid}
     if attrs:
         ev["args"] = _clean_attrs(attrs)
-    _emit(ev)
+    _emit(ev, _lane())
 
 
 def _request_end(name, rid, cat="request", **attrs):
@@ -183,7 +185,7 @@ def _request_end(name, rid, cat="request", **attrs):
           "tid": threading.get_ident() % 100000, "cat": cat, "id": rid}
     if attrs:
         ev["args"] = _clean_attrs(attrs)
-    _emit(ev)
+    _emit(ev, _lane())
 
 
 # -- the rebindable hook surface (disarmed = _noop) --------------------------
@@ -305,13 +307,21 @@ def bump(counter, n=1):
         _counters[counter] += n
 
 
+def _lane_sums():
+    return {k: sum(lane["counts"][k] for lane in _lanes)
+            for k in _LANE_COUNTS}
+
+
 def telemetry_stats():
     """Snapshot of the telemetry counters since the last reset."""
     with _lock:
-        return dict(_counters)
+        stats = {k: n - _lane_base[k] for k, n in _lane_sums().items()}
+        stats.update(_counters)
+        return stats
 
 
 def reset_telemetry_stats():
     with _lock:
+        _lane_base.update(_lane_sums())
         for k in _counters:
             _counters[k] = 0
