@@ -6,16 +6,40 @@ batch_dot + softmax, materializing the (S,S) score matrix in HBM.  This
 kernel is the capability upgrade the survey prescribes: online-softmax
 blockwise attention that keeps scores in VMEM, MXU-aligned 128-tiles.
 
-Both directions are Pallas kernels. Forward saves the per-row
-log-sum-exp; backward recomputes P blockwise from (q, k, lse) — the
-standard flash-attention-2 scheme: one kernel accumulates dQ over
-k-blocks, a second accumulates dK/dV over q-blocks, with
-delta = rowsum(dO * O) precomputed in XLA.
+Both directions are Pallas kernels, three an attention.  Forward saves
+the per-row log-sum-exp; backward recomputes P blockwise from (q, k,
+lse) — the standard flash-attention-2 scheme: one kernel accumulates dQ
+over k-blocks, a second accumulates dK/dV over q-blocks.
+
+The RESIDENT kernels (K/V whole in VMEM) work on a GROUP of heads a
+grid step: blocks (heads, 128, d) and (heads, seq, d) cut by the
+BlockSpec from the same (b*h, seq, d) operands, a grid of
+(b*h // heads, blocks), the same arithmetic head after head inside.
+At a short sequence one head's tile is 40 ns of MXU work, and a grid
+step a head is the price of starting 1536 steps and waiting on their
+16 KB DMAs; `_heads_per_step` takes the largest divisor of the head
+count whose blocks fit `_GROUP_VMEM_BYTES` — 12 at BERT-base's (128,
+12, 128, 64) bf16, 6 at sequence 512, 1 where one head's K/V fill the
+budget — from the operands' shapes and item size alone.  The row
+statistics travel lane-dense, (b*h // heads, heads, seq) float32 with
+the sequence along the lanes: lse from the forward, and delta =
+rowsum(dO * O), which the dQ kernel makes from the O tile and hands
+to the dK/dV kernel.  (A (b*h, seq, 1) column array is stored (8,
+128)-tiled, one lane in 128: 100 MB an array at BERT's shape, and the
+DMA of a 128 x 128 tile a head.)  Each kernel turns lanes into
+sublanes or back with ONE transpose a grid step; the dK/dV kernel
+works on the transposed scores, where lane-dense rows broadcast as
+they are.  The STREAMED kernels (K/V swept by a third grid dimension,
+past `MXTPU_FLASH_MAX_KV_VMEM_MB`) keep a head a step.
+
+Every kernel built while a program is traced is counted
+(`flash_attention_stats`, the profiler section `flashAttention`).
 
 Falls back transparently when seq/head dims don't tile (caller guards).
 """
 from __future__ import annotations
 
+import collections
 import functools
 import math
 
@@ -27,70 +51,150 @@ from jax.experimental.pallas import tpu as pltpu
 from . import pallas_call
 
 _NEG_INF = -1e9
+_LANES = 128
+# VMEM the blocks of one grid step of the resident kernels may take,
+# the pipeline's second buffer of each included (_heads_per_step); a
+# quarter of the 16 MB a kernel may use on a v5e-class core, which
+# leaves the rest to the float32 intermediates of the unrolled heads
+_GROUP_VMEM_BYTES = 4 * 2 ** 20
+
+
+# every kernel built while a program was traced: (variant, kernel, (b, h,
+# sq, sk, d), dtype, heads a grid step, grid) -> how many times.  Counted
+# when a program is TRACED, so a step that runs costs nothing; the
+# profiler section `flashAttention` reads it.  The keys say how the
+# kernels engaged; the counts are traces (a primal trace that
+# differentiation replaces counts, a layer whose jaxpr JAX reuses does
+# not), not the instances in a compiled program.
+_built = collections.Counter()
+
+
+def _record_built(variant, kernel, q, sk, heads, grid):
+    b, h, sq, d = q.shape
+    _built[(variant, kernel, (b, h, sq, sk, d), q.dtype.name, heads,
+            tuple(grid))] += 1
+
+
+def flash_attention_stats():
+    """The `flashAttention` profiler section: how the kernels engaged
+    in the programs traced since the last reset.  `built` has a row for
+    each distinct kernel, named by its variant (resident / streamed),
+    which of the three it is, its shapes, the heads a grid step works
+    on and the grid."""
+    built = {}
+    for (variant, kernel, shape, dtype, heads, grid), n in _built.items():
+        dims = " ".join(f"{k}{v}" for k, v in zip(
+            ("b", "h", "sq", "sk", "d"), shape))
+        built[f"{variant} {kernel} {dims} {dtype} heads{heads} "
+              f"grid{'x'.join(map(str, grid))}"] = n
+
+    def count(variant):
+        return sum(n for key, n in _built.items() if key[0] == variant)
+
+    return {"kernels": sum(_built.values()),
+            "resident": count("resident"), "streamed": count("streamed"),
+            "built": built}
+
+
+def reset_flash_attention_stats():
+    _built.clear()
+
+
+def _heads_per_step(h, sq, sk, d, itemsize, block=128):
+    """How many heads one grid step of the resident kernels works on:
+    the largest divisor of `h` whose blocks fit `_GROUP_VMEM_BYTES`.
+
+    One head's blocks, each held twice by the pipeline: four of
+    (block, d) -- q, dO, O, dQ in the dQ kernel; k, v, dK, dV in the
+    dK/dV kernel -- and up to three of (seq, d) resident beside them
+    (k, v; q, dO, O), the forward needing less than either.  A divisor
+    of `h` keeps a group inside one batch row, so the key-padding
+    mask's block is one row a step whatever the batch; where one
+    head's blocks already fill the budget (long K/V, a large head) the
+    answer is 1, a grid step a head."""
+    per_head = 2 * itemsize * d * (4 * block + 3 * max(sq, sk))
+    fit = min(max(_GROUP_VMEM_BYTES // per_head, 1), _LANES)
+    return max(g for g in range(1, h + 1) if h % g == 0 and g <= fit)
+
+
+def _lanes_to_rows(cols, heads):
+    """(rows, 128) with head g's row statistic in lane g -> (heads,
+    rows), the statistic along the lanes: one transpose a grid step."""
+    return cols.T[:heads]
 
 
 def _flash_fwd_kernel(*refs, block_k, causal, scale, seq_k, has_mask):
-    # refs carry a leading block dim of 1: (1, block_q, d) / (1, seq_k, d);
-    # with has_mask an additive key-padding row (1, 1, seq_k) rides along.
-    # lse rides as (1, block_q, 1): Mosaic's tiling rule wants the minor
-    # block dim equal to the array dim (here 1) or 128-divisible, and the
-    # sublane dim 8-divisible (block_q is) — a flat (1, block_q) row
-    # block violates it (sublane dim 1 vs array dim b*h).
+    # one grid step works on a GROUP of heads of one batch row: q / o
+    # blocks are (heads, block_q, d), k / v (heads, seq_k, d); with
+    # has_mask the batch row's additive key-padding row (1, 1, seq_k)
+    # rides along, shared by the group.  The per-row log-sum-exp leaves
+    # lane-dense, (1, heads, block_q) of a (b*h // heads, heads, sq)
+    # array: a (block_q, 1) column a head would be stored (8, 128)-
+    # tiled, one lane in 128, and cost the DMA of a 128 x 128 tile.
+    # Each head's column goes into its lane of one (block_q, 128) value
+    # and a single transpose a step turns lanes into rows.
     if has_mask:
         q_ref, k_ref, v_ref, km_ref, o_ref, lse_ref = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref = refs
         km_ref = None
-    block_q = q_ref.shape[1]
-    d = q_ref.shape[2]
+    heads, block_q, d = q_ref.shape
     qi = pl.program_id(1)  # q-block index
-
-    q = q_ref[0] * scale
-    m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-
     num_kb = seq_k // block_k
+    lane = jax.lax.broadcasted_iota(jnp.int32, (block_q, _LANES), 1)
+    lse_cols = jnp.zeros((block_q, _LANES), jnp.float32)
 
-    def body(kb, carry):
-        m_prev, l_prev, acc = carry
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :]
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        if km_ref is not None:
-            s = s + km_ref[0, 0, pl.ds(kb * block_k, block_k)][None, :]
+    for g in range(heads):
+        q = q_ref[g] * scale
+        m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
+        l0 = jnp.zeros((block_q, 1), jnp.float32)
+        acc0 = jnp.zeros((block_q, d), jnp.float32)
+
+        def body(kb, carry, g=g, q=q):
+            m_prev, l_prev, acc = carry
+            k = k_ref[g, pl.ds(kb * block_k, block_k), :]
+            v = v_ref[g, pl.ds(kb * block_k, block_k), :]
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+            if km_ref is not None:
+                s = s + km_ref[0, 0, pl.ds(kb * block_k, block_k)][None, :]
+            if causal:
+                q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 0)
+                k_pos = kb * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 1)
+                s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            m_cur = jnp.max(s, axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            acc_new = acc * alpha + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            return m_new, l_new, acc_new
+
         if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_new = acc * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
-
-    if causal:
-        # only k-blocks at or before this q-block contribute
-        max_kb = jnp.minimum(((qi + 1) * block_q + block_k - 1) // block_k,
-                             num_kb)
-        m, l, acc = jax.lax.fori_loop(0, max_kb, body, (m0, l0, acc0))
-    else:
-        m, l, acc = jax.lax.fori_loop(0, num_kb, body, (m0, l0, acc0))
-    l = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(l)
+            # only k-blocks at or before this q-block contribute
+            max_kb = jnp.minimum(
+                ((qi + 1) * block_q + block_k - 1) // block_k, num_kb)
+            m, l, acc = jax.lax.fori_loop(0, max_kb, body, (m0, l0, acc0))
+        else:
+            m, l, acc = jax.lax.fori_loop(0, num_kb, body, (m0, l0, acc0))
+        l = jnp.maximum(l, 1e-30)
+        o_ref[g] = (acc / l).astype(o_ref.dtype)
+        lse_cols = jnp.where(lane == g, m + jnp.log(l), lse_cols)
+    lse_ref[0] = _lanes_to_rows(lse_cols, heads)
 
 
-def _km_spec(h, sk):
-    """BlockSpec mapping the flattened (b*h) grid dim onto the original
-    (b, 1, sk) mask — no h-fold HBM copy of the mask is ever made."""
-    return pl.BlockSpec((1, 1, sk), lambda i, j: (i // h, 0, 0),
-                        memory_space=pltpu.VMEM)
+def _vmem(shape, index_map):
+    return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
+
+
+def _km_spec(h, heads, sk):
+    """BlockSpec mapping the grid's (b*h // heads) dim onto the original
+    (b, 1, sk) mask: a group of heads lies within one batch row, and no
+    h-fold HBM copy of the mask is ever made."""
+    groups = h // heads
+    return _vmem((1, 1, sk), lambda i, j: (i // groups, 0, 0))
 
 
 # ---------------------------------------------------------------------------
@@ -182,11 +286,13 @@ def _flash_forward_stream(q, k, v, *, causal, scale, kmask=None,
             memory_space=pltpu.VMEM))
         args.append(kmask.astype(jnp.float32).reshape(b, 1, sk))
 
+    grid = (bh, sq // block_q, num_kb)
+    _record_built("streamed", "fwd", q, sk, 1, grid)
     out, lse = pallas_call(
         functools.partial(_flash_fwd_stream_kernel, causal=causal,
                           scale=scale, has_mask=kmask is not None,
                           num_kb=num_kb),
-        grid=(bh, sq // block_q, num_kb),
+        grid=grid,
         in_specs=in_specs,
         out_shape=(
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
@@ -212,41 +318,36 @@ def _flash_forward(q, k, v, *, causal, scale, kmask=None,
     b, h, sq, d = q.shape
     sk = k.shape[2]
     bh = b * h
+    heads = _heads_per_step(h, sq, sk, d, q.dtype.itemsize,
+                            max(block_q, block_k))
+    # the first operand stays the (b*h, sq, d) query: the group is cut
+    # by the BlockSpec, not by a reshape in front of the call
     q3 = q.reshape(bh, sq, d)
     k3 = k.reshape(bh, sk, d)
     v3 = v.reshape(bh, sk, d)
 
-    in_specs = [
-        pl.BlockSpec((1, block_q, d),
-                     lambda i, j: (i, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, sk, d), lambda i, j: (i, 0, 0),
-                     memory_space=pltpu.VMEM),
-    ]
+    tile = _vmem((heads, block_q, d), lambda i, j: (i, j, 0))
+    full = _vmem((heads, sk, d), lambda i, j: (i, 0, 0))
+    in_specs = [tile, full, full]
     args = [q3, k3, v3]
     if kmask is not None:
-        in_specs.append(_km_spec(h, sk))
+        in_specs.append(_km_spec(h, heads, sk))
         args.append(kmask.astype(jnp.float32).reshape(b, 1, sk))
 
-    grid = (bh, sq // block_q)
+    grid = (bh // heads, sq // block_q)
+    _record_built("resident", "fwd", q, sk, heads, grid)
     out, lse = pallas_call(
         functools.partial(_flash_fwd_kernel, block_k=block_k,
                           causal=causal, scale=scale, seq_k=sk,
                           has_mask=kmask is not None),
         out_shape=(
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh // heads, heads, sq), jnp.float32),
         ),
         grid=grid,
         in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-        ),
+        out_specs=(tile,
+                   _vmem((1, heads, block_q), lambda i, j: (i, 0, j))),
     )(*args)
     return out.reshape(b, h, sq, d), lse
 
@@ -381,11 +482,13 @@ def _flash_backward_stream(q, k, v, o, lse, do, *, causal, scale,
         dq_specs.append(pl.BlockSpec((1, 1, block_k), _km_blk,
                                      memory_space=pltpu.VMEM))
         dq_args.append(km3)
+    grid = (bh, num_qb, num_kb)
+    _record_built("streamed", "dq", q, sk, 1, grid)
     dq = pallas_call(
         functools.partial(_flash_dq_stream_kernel, causal=causal,
                           scale=scale, has_mask=has_mask,
                           num_kb=num_kb),
-        grid=(bh, num_qb, num_kb),
+        grid=grid,
         in_specs=dq_specs,
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         out_specs=q_blk,
@@ -406,11 +509,13 @@ def _flash_backward_stream(q, k, v, o, lse, do, *, causal, scale,
             (1, 1, block_k), lambda i, j, kk: (i // h, 0, j),
             memory_space=pltpu.VMEM))
         dkv_args.append(km3)
+    grid = (bh, num_kb, num_qb)
+    _record_built("streamed", "dkv", q, sk, 1, grid)
     dk, dv = pallas_call(
         functools.partial(_flash_dkv_stream_kernel, causal=causal,
                           scale=scale, has_mask=has_mask,
                           num_qb=num_qb),
-        grid=(bh, num_kb, num_qb),
+        grid=grid,
         in_specs=dkv_specs,
         out_shape=(
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
@@ -427,50 +532,70 @@ def _flash_backward_stream(q, k, v, o, lse, do, *, causal, scale,
 
 
 def _flash_dq_kernel(*refs, block_k, causal, scale, seq_k, has_mask):
+    # a group of heads a step, as the forward; lse arrives lane-dense
+    # (1, heads, block_q) and one transpose a step gives every head its
+    # (block_q, 1) column.  delta = rowsum(dO * O) is made here from the
+    # O tile (no XLA pass writes it, no padded (b*h, sq, 1) array holds
+    # it) and leaves lane-dense for the dK/dV kernel.
     if has_mask:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, km_ref,
-         dq_ref) = refs
+        (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, km_ref,
+         dq_ref, delta_ref) = refs
     else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref = refs
+        (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+         dq_ref, delta_ref) = refs
         km_ref = None
-    block_q = q_ref.shape[1]
-    d = q_ref.shape[2]
+    heads, block_q, d = q_ref.shape
     qi = pl.program_id(1)
-
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]          # (block_q, 1)
-    delta = delta_ref[0]      # (block_q, 1)
-    dq0 = jnp.zeros((block_q, d), jnp.float32)
     num_kb = seq_k // block_k
+    lse_cols = lse_ref[0].T           # (block_q, heads)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (block_q, _LANES), 1)
+    delta_cols = jnp.zeros((block_q, _LANES), jnp.float32)
 
-    def body(kb, dq):
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        s = scale * jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        if km_ref is not None:
-            s = s + km_ref[0, 0, pl.ds(kb * block_k, block_k)][None, :]
+    for g in range(heads):
+        q = q_ref[g].astype(jnp.float32)
+        do = do_ref[g].astype(jnp.float32)
+        lse = lse_cols[:, g:g + 1]    # (block_q, 1)
+        delta = jnp.sum(do * o_ref[g].astype(jnp.float32), axis=-1,
+                        keepdims=True)
+        dq0 = jnp.zeros((block_q, d), jnp.float32)
+
+        def body(kb, dq, g=g, q=q, do=do, lse=lse, delta=delta):
+            k = k_ref[g, pl.ds(kb * block_k, block_k), :] \
+                .astype(jnp.float32)
+            v = v_ref[g, pl.ds(kb * block_k, block_k), :] \
+                .astype(jnp.float32)
+            s = scale * jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+            if km_ref is not None:
+                s = s + km_ref[0, 0, pl.ds(kb * block_k, block_k)][None, :]
+            if causal:
+                q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 0)
+                k_pos = kb * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 1)
+                s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            p = jnp.exp(s - lse)
+            dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
+            ds = p * (dp - delta)
+            return dq + jnp.dot(ds, k, preferred_element_type=jnp.float32)
+
         if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        return dq + jnp.dot(ds, k, preferred_element_type=jnp.float32)
-
-    if causal:
-        max_kb = jnp.minimum(((qi + 1) * block_q + block_k - 1) // block_k,
-                             num_kb)
-        dq = jax.lax.fori_loop(0, max_kb, body, dq0)
-    else:
-        dq = jax.lax.fori_loop(0, num_kb, body, dq0)
-    dq_ref[0] = (scale * dq).astype(dq_ref.dtype)
+            max_kb = jnp.minimum(
+                ((qi + 1) * block_q + block_k - 1) // block_k, num_kb)
+            dq = jax.lax.fori_loop(0, max_kb, body, dq0)
+        else:
+            dq = jax.lax.fori_loop(0, num_kb, body, dq0)
+        dq_ref[g] = (scale * dq).astype(dq_ref.dtype)
+        delta_cols = jnp.where(lane == g, delta, delta_cols)
+    delta_ref[0] = _lanes_to_rows(delta_cols, heads)
 
 
 def _flash_dkv_kernel(*refs, block_q, causal, scale, seq_q, has_mask):
+    # works on the TRANSPOSED scores, (block_k, block_q): the keys of
+    # this tile down the sublanes, the queries along the lanes.  The
+    # lane-dense lse and delta rows then broadcast down the sublanes as
+    # they are, and dV = P^T dO, dK = dS^T Q are plain products of the
+    # transposed P and dS; every dot contracts the same elements in
+    # float32 as the (block_q, block_k) form did.
     if has_mask:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, km_ref,
          dk_ref, dv_ref) = refs
@@ -478,49 +603,54 @@ def _flash_dkv_kernel(*refs, block_q, causal, scale, seq_q, has_mask):
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
          dk_ref, dv_ref) = refs
         km_ref = None
-    block_k = k_ref.shape[1]
-    d = k_ref.shape[2]
+    heads, block_k, d = k_ref.shape
     ki = pl.program_id(1)
-
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    # this k-block's additive mask column: constant across q-blocks
-    km_col = (km_ref[0, 0, pl.ds(ki * block_k, block_k)][None, :]
-              if km_ref is not None else None)
-    dk0 = jnp.zeros((block_k, d), jnp.float32)
-    dv0 = jnp.zeros((block_k, d), jnp.float32)
     num_qb = seq_q // block_q
+    nt = (((1,), (1,)), ((), ()))     # a @ b.T
+    # this k-block's additive mask as a column: constant across
+    # q-blocks and heads
+    km_col = (km_ref[0, :, pl.ds(ki * block_k, block_k)].T
+              if km_ref is not None else None)
 
-    def body(qb, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qb * block_q, block_q), :]
-        delta = delta_ref[0, pl.ds(qb * block_q, block_q), :]
-        s = scale * jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        if km_col is not None:
-            s = s + km_col
+    for g in range(heads):
+        k = k_ref[g].astype(jnp.float32)
+        v = v_ref[g].astype(jnp.float32)
+        dk0 = jnp.zeros((block_k, d), jnp.float32)
+        dv0 = jnp.zeros((block_k, d), jnp.float32)
+
+        def body(qb, carry, g=g, k=k, v=v):
+            dk, dv = carry
+            rows = pl.ds(qb * block_q, block_q)
+            q = q_ref[g, rows, :].astype(jnp.float32)
+            do = do_ref[g, rows, :].astype(jnp.float32)
+            lse = lse_ref[0, g:g + 1, rows]        # (1, block_q)
+            delta = delta_ref[0, g:g + 1, rows]
+            st = scale * jax.lax.dot_general(
+                k, q, nt, preferred_element_type=jnp.float32)
+            if km_col is not None:
+                st = st + km_col
+            if causal:
+                k_pos = ki * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 0)
+                q_pos = qb * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 1)
+                st = jnp.where(q_pos >= k_pos, st, _NEG_INF)
+            pt = jnp.exp(st - lse)
+            dv = dv + jnp.dot(pt, do, preferred_element_type=jnp.float32)
+            dpt = jax.lax.dot_general(
+                v, do, nt, preferred_element_type=jnp.float32)
+            dst = pt * (dpt - delta)
+            dk = dk + jnp.dot(dst, q, preferred_element_type=jnp.float32)
+            return dk, dv
+
         if causal:
-            q_pos = qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dv = dv + jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk = dk + jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
-        return dk, dv
-
-    if causal:
-        # q-blocks strictly before this k-block see nothing
-        min_qb = (ki * block_k) // block_q
-        dk, dv = jax.lax.fori_loop(min_qb, num_qb, body, (dk0, dv0))
-    else:
-        dk, dv = jax.lax.fori_loop(0, num_qb, body, (dk0, dv0))
-    dk_ref[0] = (scale * dk).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+            # q-blocks strictly before this k-block see nothing
+            min_qb = (ki * block_k) // block_q
+            dk, dv = jax.lax.fori_loop(min_qb, num_qb, body, (dk0, dv0))
+        else:
+            dk, dv = jax.lax.fori_loop(0, num_qb, body, (dk0, dv0))
+        dk_ref[g] = (scale * dk).astype(dk_ref.dtype)
+        dv_ref[g] = dv.astype(dv_ref.dtype)
 
 
 def _flash_backward(q, k, v, o, lse, do, *, causal, scale, kmask=None,
@@ -528,63 +658,53 @@ def _flash_backward(q, k, v, o, lse, do, *, causal, scale, kmask=None,
     b, h, sq, d = q.shape
     sk = k.shape[2]
     bh = b * h
+    heads = lse.shape[1]              # as the forward grouped them
     q3, k3, v3 = (t.reshape(bh, -1, d) for t in (q, k, v))
     o3 = o.reshape(bh, sq, d)
     do3 = do.reshape(bh, sq, d)
-    # delta = rowsum(dO * O): one fused XLA elementwise+reduce, carried
-    # as (bh, sq, 1) so its blocks satisfy Mosaic's minor-dim tiling rule
-    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
-                    axis=-1, keepdims=True)
 
-    full_q = lambda i, j: (i, 0, 0)  # noqa: E731
     has_mask = kmask is not None
     km3 = (kmask.astype(jnp.float32).reshape(b, 1, sk)
            if has_mask else None)
-    km_spec = _km_spec(h, sk)
+    km_spec = _km_spec(h, heads, sk)
+    rows = jax.ShapeDtypeStruct((bh // heads, heads, sq), jnp.float32)
 
-    dq_specs = [
-        pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, sk, d), full_q, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, sk, d), full_q, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_q, 1), lambda i, j: (i, j, 0),
-                     memory_space=pltpu.VMEM),
-    ]
-    dq_args = [q3, k3, v3, do3, lse, delta]
+    def tile(block):
+        return _vmem((heads, block, d), lambda i, j: (i, j, 0))
+
+    def full(seq):
+        return _vmem((heads, seq, d), lambda i, j: (i, 0, 0))
+
+    q_rows = _vmem((1, heads, block_q), lambda i, j: (i, 0, j))
+    dq_specs = [tile(block_q), full(sk), full(sk), tile(block_q),
+                tile(block_q), q_rows]
+    dq_args = [q3, k3, v3, do3, o3, lse]
     if has_mask:
         dq_specs.append(km_spec)
         dq_args.append(km3)
 
-    dq = pallas_call(
+    grid = (bh // heads, sq // block_q)
+    _record_built("resident", "dq", q, sk, heads, grid)
+    dq, delta = pallas_call(
         functools.partial(_flash_dq_kernel, block_k=block_k,
                           causal=causal, scale=scale, seq_k=sk,
                           has_mask=has_mask),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-        grid=(bh, sq // block_q),
+        out_shape=(jax.ShapeDtypeStruct((bh, sq, d), q.dtype), rows),
+        grid=grid,
         in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0),
-                               memory_space=pltpu.VMEM),
+        out_specs=(tile(block_q), q_rows),
     )(*dq_args)
 
-    dkv_specs = [
-        pl.BlockSpec((1, sq, d), full_q, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, sq, d), full_q, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, sq, 1), full_q, memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, sq, 1), full_q, memory_space=pltpu.VMEM),
-    ]
+    all_rows = _vmem((1, heads, sq), lambda i, j: (i, 0, 0))
+    dkv_specs = [full(sq), tile(block_k), tile(block_k), full(sq),
+                 all_rows, all_rows]
     dkv_args = [q3, k3, v3, do3, lse, delta]
     if has_mask:
         dkv_specs.append(km_spec)
         dkv_args.append(km3)
 
+    grid = (bh // heads, sk // block_k)
+    _record_built("resident", "dkv", q, sk, heads, grid)
     dk, dv = pallas_call(
         functools.partial(_flash_dkv_kernel, block_q=block_q,
                           causal=causal, scale=scale, seq_q=sq,
@@ -593,14 +713,9 @@ def _flash_backward(q, k, v, o, lse, do, *, causal, scale, kmask=None,
             jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
             jax.ShapeDtypeStruct((bh, sk, d), v.dtype),
         ),
-        grid=(bh, sk // block_k),
+        grid=grid,
         in_specs=dkv_specs,
-        out_specs=(
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-        ),
+        out_specs=(tile(block_k), tile(block_k)),
     )(*dkv_args)
 
     return (dq.reshape(b, h, sq, d), dk.reshape(b, h, sk, d),

@@ -400,6 +400,23 @@ def _data_parallel_step_counters(reset=False):
     return stats
 
 
+def _flash_attention_counters(reset=False):
+    """How the flash-attention kernels engaged in the programs traced
+    in the window: kernels built, resident / streamed, and a row for
+    each distinct kernel with its shapes, the heads a grid step works
+    on and the grid.  Counted when a program is traced, never when it
+    runs; only present once the kernels' module is loaded."""
+    import sys
+
+    fa = sys.modules.get(__package__ + ".ops.pallas.flash_attention")
+    if fa is None:
+        return None
+    stats = fa.flash_attention_stats()
+    if reset:
+        fa.reset_flash_attention_stats()
+    return stats
+
+
 def _telemetry_counters(reset=False):
     """Telemetry-subsystem counters (spans/instants/requests recorded,
     drops, flight dumps, scrapes, aggregations) — window-scoped under
@@ -484,6 +501,17 @@ def _rows_table(title, rows):
     return render
 
 
+def _flash_attention_table(stats):
+    out = ["Flash Attention (kernels built at trace time):"]
+    for label, key in (("kernels", "kernels"),
+                       ("resident (K/V in VMEM)", "resident"),
+                       ("streamed (K/V swept by the grid)", "streamed")):
+        out.append(f"{label:<40}{stats[key]:>12}")
+    for row in sorted(stats["built"]):
+        out.append(f"  {row}  x{stats['built'][row]}")
+    return out
+
+
 def _resilience_table(stats):
     out = ["Resilience (supervisor):"]
     for label, key in (("restarts", "restarts"),
@@ -524,6 +552,8 @@ register_section("dataParallelStep", _data_parallel_step_counters, _rows_table(
      ("key and scalars (ms)", "args_ms"),
      ("step enqueue (ms)", "enqueue_ms"),
      ("bytes put", "put_bytes"))))
+register_section("flashAttention", _flash_attention_counters,
+                 _flash_attention_table)
 register_section("dataPipeline", _data_pipeline_counters, _rows_table(
     "Data Pipeline",
     (("batches delivered", "batches"),

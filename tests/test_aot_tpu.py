@@ -217,11 +217,21 @@ BERT_ATTN = (64, 12, 128, 64)   # bench/chip_smoke batch x BERT-base heads
     (BERT_ATTN, jnp.float32),           # gluon.Trainer whole step, fp32
     ((8, 12, 128, 64), jnp.float32),    # chip_smoke's gluon phase
     ((1, 2, 256, 192), jnp.bfloat16),   # the d % 64 rule past 64 and 128
-], ids=["bert-bf16", "bert-f32", "bert-b8-f32", "d192-bf16"])
+    ((128, 12, 128, 64), jnp.bfloat16),  # the benchmark's bert_base.seq128
+    ((32, 12, 512, 64), jnp.bfloat16),  # bert_base.seq512, the cell to come
+], ids=["bert-bf16", "bert-f32", "bert-b8-f32", "d192-bf16",
+        "bert-seq128-cell", "bert-seq512-cell"])
 def test_flash_real_width_aot(one_chip, shape, dt):
     """Flash fwd+bwd with the key-padding mask at the shapes the static
     gate admits on the main path — what the deleted dispatch-time
-    compile probe used to ask the chip at a toy shape."""
+    compile probe used to ask the chip at a toy shape.  Three kernels
+    an attention, and each one's FIRST operand is the (b*h, s, d)
+    query in the operands' dtype: the benchmark's `flash_attn_roofline`
+    finds the kernels' device events by that shape, so the heads a grid
+    step works on are cut by the BlockSpec, never by a reshape of q in
+    front of the call."""
+    import re
+
     from mxnet_tpu.ops.pallas.flash_attention import _flash_sdpa, _tiles_ok
 
     q = jax.ShapeDtypeStruct(shape, dt)
@@ -232,8 +242,17 @@ def test_flash_real_width_aot(one_chip, shape, dt):
         return _flash_sdpa(a, a, a, km, False, shape[3] ** -0.5) \
             .astype(jnp.float32).sum()
 
-    assert _aot_grad_compile(one_chip, loss, q).count(
-        "tpu_custom_call") == 3   # fwd, dq, dk/dv
+    text = _aot_grad_compile(one_chip, loss, q)
+    assert text.count("tpu_custom_call") == 3   # fwd, dq, dk/dv
+    b, h, s, d = shape
+    hlo_type = {jnp.bfloat16: "bf16", jnp.float32: "f32"}[dt]
+    want = f"{hlo_type}[{b * h},{s},{d}]"
+    shapes = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", text))
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    for line in calls:
+        first = re.search(r"custom-call\(%([\w.\-]+)", line).group(1)
+        assert shapes[first] == want, (first, shapes[first], want)
 
 
 def _dispatch_loss(q, mask):

@@ -399,3 +399,232 @@ def test_flash_causal_cross_length_uses_oracle():
     out = flash_attention(q, k, k, causal=True)
     ref = sdpa_reference(q, k, k, causal=True)
     assert np.allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# grouped resident kernels: one grid step works on G heads of a batch row
+# ---------------------------------------------------------------------------
+
+
+def _grouped_case(b, h, sq, sk, d, dtype, masked, seed=0):
+    """q, k, v, the (b, 1, 1, sk) additive key-padding mask (another
+    length in every batch row, so a group that took a neighbour's row
+    would show) and a weight for the loss that gives every output
+    element a gradient of its own."""
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.randn(b, h, sq, d) * 0.5, dtype)
+    k = jnp.asarray(rng.randn(b, h, sk, d) * 0.5, dtype)
+    v = jnp.asarray(rng.randn(b, h, sk, d) * 0.5, dtype)
+    mask = None
+    if masked:
+        valid = sk - 32 * (1 + np.arange(b))
+        mask = jnp.asarray(np.where(
+            np.arange(sk)[None] < valid[:, None], 0.0, -1e9),
+            jnp.float32).reshape(b, 1, 1, sk)
+    w = jnp.asarray(rng.randn(b, h, sq, d), jnp.float32)
+    return q, k, v, mask, w
+
+
+def _out_and_grads(fn, q, k, v, mask, w, causal):
+    import jax
+    import jax.numpy as jnp
+
+    def loss(q, k, v):
+        out = fn(q, k, v, mask, causal=causal)
+        return (out.astype(jnp.float32) * w).sum(), out
+
+    (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                         has_aux=True)(q, k, v)
+    return (out,) + grads
+
+
+def _with_heads_per_step(monkeypatch, heads):
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_heads_per_step",
+                        lambda h, *a, **kw: h if heads == "all" else heads)
+    fa.reset_flash_attention_stats()
+    return fa
+
+
+def _assert_close_to_oracle(got, q, k, v, mask, w, causal):
+    """Forward and the three gradients against `sdpa_reference` in
+    float32 on the same (already rounded) operands."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.attention import sdpa_reference
+
+    f32 = [t.astype(jnp.float32) for t in (q, k, v)]
+    want = _out_and_grads(sdpa_reference, *f32, mask, w, causal)
+    tol = 2e-5 if q.dtype == jnp.float32 else 2e-2
+    for name, a, r in zip(("out", "dq", "dk", "dv"), got, want):
+        a = np.asarray(a.astype(jnp.float32))
+        r = np.asarray(r)
+        err = np.abs(a - r).max() / (np.abs(r).max() + 1e-9)
+        assert err < tol, (name, err)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("masked", [False, True], ids=["nomask", "padmask"])
+@pytest.mark.parametrize("heads", [1, 2, 4, "all"])
+def test_flash_grouped_heads_match_reference(heads, masked, causal, dtype,
+                                             interpret_pallas, monkeypatch):
+    """Whatever the heads a grid step (1, 2, 4 or all 8 of a batch
+    row), forward and backward agree with the oracle: two batch rows
+    with a key-padding mask of another length each, two q- and k-blocks
+    (the causal loop bounds), both dtypes."""
+    fa = _with_heads_per_step(monkeypatch, heads)
+    b, h, s, d = 2, 8, 256, 64
+    q, k, v, mask, w = _grouped_case(b, h, s, s, d, dtype, masked)
+    got = _out_and_grads(fa.flash_attention, q, k, v, mask, w, causal)
+    _assert_close_to_oracle(got, q, k, v, mask, w, causal)
+    want_heads = h if heads == "all" else heads
+    assert {key[4] for key in fa._built} == {want_heads}
+    assert {key[0] for key in fa._built} == {"resident"}
+
+
+@pytest.mark.parametrize("heads", [1, 3, "all"])
+def test_flash_grouped_heads_cross_length(heads, interpret_pallas,
+                                          monkeypatch):
+    """sq != sk, not causal, three q-blocks against two k-blocks, a
+    group size that is not a power of two."""
+    fa = _with_heads_per_step(monkeypatch, heads)
+    q, k, v, mask, w = _grouped_case(2, 6, 384, 256, 64, "float32", True)
+    got = _out_and_grads(fa.flash_attention, q, k, v, mask, w, False)
+    _assert_close_to_oracle(got, q, k, v, mask, w, False)
+    assert {key[2] for key in fa._built} == {(2, 6, 384, 256, 64)}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_flash_one_head_a_step_equals_all_heads_a_step(
+        dtype, interpret_pallas, monkeypatch):
+    """The group is a matter of blocks, not of arithmetic: a head a
+    step and all heads a step give the same numbers.  To 1e-6 of the
+    largest element, not to the bit: the CPU's compiled interpreter may
+    fuse the unrolled heads' float32 sums in another order."""
+    import jax.numpy as jnp
+
+    q, k, v, mask, w = _grouped_case(2, 4, 256, 256, 64, dtype, True,
+                                     seed=3)
+    runs = []
+    for heads in (1, "all"):
+        fa = _with_heads_per_step(monkeypatch, heads)
+        runs.append(_out_and_grads(fa.flash_attention, q, k, v, mask, w,
+                                   True))
+    for name, one, every in zip(("out", "dq", "dk", "dv"), *runs):
+        one = np.asarray(one.astype(jnp.float32))
+        every = np.asarray(every.astype(jnp.float32))
+        assert np.abs(one - every).max() <= 1e-6 * np.abs(one).max(), name
+
+
+def test_heads_per_step_rule():
+    """The heads a grid step come from the operands' shapes and item
+    size alone: a divisor of h (a group never straddles two batch
+    rows), all 12 at BERT-base's shape, fewer as the sequence grows,
+    one where a single head's K/V fill the budget; the batch is not an
+    argument at all."""
+    import inspect
+
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+
+    rule = fa._heads_per_step
+    assert "b" not in inspect.signature(rule).parameters
+    assert rule(12, 128, 128, 64, 2) == 12          # bert_base.seq128
+    assert rule(12, 512, 512, 64, 2) == 6           # bert_base.seq512
+    assert rule(12, 128, 128, 64, 4) == 6           # the fp32 whole step
+    assert rule(16, 2048, 2048, 128, 2) == 1
+    assert rule(1, 16384, 16384, 128, 2) == 1
+    assert rule(7, 128, 128, 64, 2) == 7            # a prime head count
+    assert rule(7, 1024, 1024, 64, 2) == 1
+    for h in (1, 2, 6, 8, 12, 16, 40, 96):
+        for s in (128, 256, 1024, 4096):
+            for d in (64, 128, 192):
+                for itemsize in (2, 4):
+                    g = rule(h, s, s, d, itemsize)
+                    assert h % g == 0 and 1 <= g <= 128
+                    blocks = 2 * itemsize * d * (4 * 128 + 3 * s) * g
+                    assert g == 1 or blocks <= fa._GROUP_VMEM_BYTES
+    # the longer of the two sequences decides (the dK/dV kernel keeps q
+    # resident, the dQ kernel k and v)
+    assert rule(12, 128, 2048, 64, 2) == rule(12, 2048, 128, 64, 2) \
+        == rule(12, 2048, 2048, 64, 2)
+    # a group's blocks are cut from the (b*h, s, d) operand by a
+    # BlockSpec: b*h is a multiple of the group for every batch
+    import jax.numpy as jnp
+
+    q = jnp.zeros((5, 12, 128, 64), jnp.bfloat16)
+    assert (q.shape[0] * q.shape[1]) % rule(12, 128, 128, 64, 2) == 0
+
+
+def test_flash_attention_profiler_section(interpret_pallas):
+    """The `flashAttention` section: every kernel built while a program
+    is TRACED is counted once, with its variant, shapes, heads a grid
+    step and grid; running the traced program again counts nothing;
+    the section is in the profiler's dump, its table and /metrics, and
+    a reset dump clears it."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import profiler
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+    from mxnet_tpu.telemetry import metrics
+
+    assert "flashAttention" in profiler.section_names()
+    profiler.sections(reset=True)
+    assert profiler.sections()["flashAttention"] == {
+        "kernels": 0, "resident": 0, "streamed": 0, "built": {}}
+
+    q, k, v, mask, w = _grouped_case(2, 4, 128, 128, 64, "float32", True)
+    step = jax.jit(jax.grad(
+        lambda q: (fa.flash_attention(q, k, v, mask) * w).sum()))
+    step(q)
+    step(q + 1.0)     # the same program: nothing is traced, or counted
+    rows = {
+        f"resident {kernel} b2 h4 sq128 sk128 d64 float32 heads4 grid2x1": 1
+        for kernel in ("fwd", "dq", "dkv")}
+    assert profiler.sections()["flashAttention"] == {
+        "kernels": 3, "resident": 3, "streamed": 0, "built": rows}
+    assert fa._heads_per_step(4, 128, 128, 64, 4) == 4
+
+    # a second program is traced (another batch): its kernels are rows
+    # of their own
+    jax.jit(lambda q: fa.flash_attention(q, q, q))(
+        jnp.concatenate([q, q]))
+    stats = profiler.sections()["flashAttention"]
+    assert stats["kernels"] == 4 and stats["built"][
+        "resident fwd b4 h4 sq128 sk128 d64 float32 heads4 grid4x1"] == 1
+
+    assert json.loads(profiler.dumps())["flashAttention"] == stats
+    table = "\n".join(profiler._section_tables())
+    assert "Flash Attention (kernels built at trace time):" in table
+    assert "resident dkv b2 h4 sq128 sk128 d64 float32 heads4 grid2x1  x1" \
+        in table
+    text = metrics.default_registry().render()
+    assert "mxtpu_flash_attention_kernels 4" in text
+    assert "mxtpu_flash_attention_resident 4" in text
+    assert ('mxtpu_flash_attention_built{key="resident dq b2 h4 sq128 '
+            'sk128 d64 float32 heads4 grid2x1"} 1') in text
+
+    assert json.loads(profiler.dumps(reset=True))[
+        "flashAttention"]["kernels"] == 4
+    assert profiler.sections()["flashAttention"]["kernels"] == 0
+
+
+def test_flash_attention_section_counts_streamed(interpret_pallas,
+                                                 monkeypatch):
+    from mxnet_tpu import profiler
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setenv("MXTPU_FLASH_MAX_KV_VMEM_MB", "0.0001")
+    profiler.sections(reset=True)
+    q, k, v, _, _ = _grouped_case(1, 2, 128, 256, 64, "float32", False)
+    fa.flash_attention(q, k, v)
+    assert profiler.sections()["flashAttention"] == {
+        "kernels": 1, "resident": 0, "streamed": 1, "built": {
+            "streamed fwd b1 h2 sq128 sk256 d64 float32 heads1 "
+            "grid2x1x2": 1}}
