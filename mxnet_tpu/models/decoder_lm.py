@@ -1,0 +1,267 @@
+"""Decoder-only language model whose layers differ in kind: full or
+sliding-window grouped-query attention with a per-head output gate and
+partial / YaRN rotary embeddings, a dense SwiGLU or a sparse-expert
+feed-forward with a shared expert, RMS pre-norms, an untied head and a
+next-token loss (the shape of poolside's Laguna family; nothing of it
+is hard-wired but the defaults).
+
+Everything a layer is comes from the config's own per-layer lists, in
+HF `config.json` names: `layer_types` (`full_attention` /
+`sliding_attention`), `num_attention_heads_per_layer`,
+`mlp_layer_types` (`dense` / `sparse`), `rope_parameters` by layer
+type, `sliding_window`.  The expert layers are ONE chip's share of an
+expert-parallel deployment (ops/moe.py): `num_experts` experts are held
+here, ids `first_expert ...`, under a router of `router_width` outputs.
+
+`forward(ids, labels)` returns the scalar loss, so the model trains as
+BERT's pre-training block does: `DataParallelTrainer(net, lambda out,
+_: out, "adamw", ..., compute_dtype="bfloat16", remat=True)`; every
+decoder layer and the head are direct children, which is what the
+trainer's `remat` recomputes one at a time.  The rows each held expert
+got in the newest step are in `routing_log`, a non-trainable parameter
+(`routing_rows()` reads it; the profiler section `moeRouting` reads the
+live trainers' copies).
+
+TPU notes: attention is the registry's scaled_dot_product_attention
+(the grouped Pallas flash kernels), the experts one grouped product
+(`moe_ffn`); `jax.named_scope`s name each part in the device trace.
+"""
+from __future__ import annotations
+
+import time
+
+import jax
+import numpy as np
+
+from ..gluon.block import HybridBlock
+from ..ops.nn import rotary_frequencies
+
+_opened_ns = 0      # the clock when the `moeRouting` section's window opened
+
+
+class DecoderLayer(HybridBlock):
+    """Pre-norm decoder layer: x + Attn(norm(x)), then + FFN(norm(.))."""
+
+    def __init__(self, config, index, **kwargs):
+        super().__init__(**kwargs)
+        h, d = config["hidden_size"], config["head_dim"]
+        self._kind = config["layer_types"][index]
+        per_layer = config.get("num_attention_heads_per_layer")
+        self._heads = per_layer[index] if per_layer \
+            else config["num_attention_heads"]
+        self._kv_heads = config["num_key_value_heads"]
+        self._head_dim = d
+        self._eps = config.get("rms_norm_eps", 1e-6)
+        self._window = config["sliding_window"] \
+            if self._kind == "sliding_attention" else None
+        rope = dict(config["rope_parameters"][self._kind])
+        rotary_dim = int(d * rope.pop("partial_rotary_factor", 1.0))
+        self._inv_freq, self._attention_factor = rotary_frequencies(
+            rotary_dim, **rope)
+        self._sparse = config["mlp_layer_types"][index] == "sparse"
+        n, kv = self._heads, self._kv_heads
+        get = self.params.get
+        self.attn_norm = get("attn_norm", shape=(h,), init="ones")
+        self.q_weight = get("q_weight", shape=(n * d, h))
+        self.k_weight = get("k_weight", shape=(kv * d, h))
+        self.v_weight = get("v_weight", shape=(kv * d, h))
+        self.gate_weight = get("gate_weight", shape=(n, h))
+        self.out_weight = get("out_weight", shape=(h, n * d))
+        self.ffn_norm = get("ffn_norm", shape=(h,), init="ones")
+        if self._sparse:
+            width = config["moe_intermediate_size"]
+            shared = config["shared_expert_intermediate_size"]
+            held = config["num_experts"]
+            self._top_k = config["num_experts_per_tok"]
+            self._scale = config.get("moe_routed_scaling_factor", 1.0)
+            self._first_expert = config.get("first_expert", 0)
+            self.router_weight = get(
+                "router_weight", shape=(h, config.get("router_width", held)))
+            self.expert_in_weight = get(
+                "expert_in_weight", shape=(held, h, 2 * width))
+            self.expert_out_weight = get(
+                "expert_out_weight", shape=(held, width, h))
+            self.shared_in_weight = get(
+                "shared_in_weight", shape=(2 * shared, h))
+            self.shared_out_weight = get(
+                "shared_out_weight", shape=(h, shared))
+        else:
+            width = config["intermediate_size"]
+            self.ffn_in_weight = get("ffn_in_weight", shape=(2 * width, h))
+            self.ffn_out_weight = get("ffn_out_weight", shape=(h, width))
+
+    @staticmethod
+    def _linear(F, x, weight):
+        return F.FullyConnected(x, weight, no_bias=True, flatten=False,
+                                num_hidden=weight.shape[0])
+
+    def _swiglu_ffn(self, F, u, w_in, w_out):
+        return self._linear(F, F.swiglu(self._linear(F, u, w_in)), w_out)
+
+    def _attention(self, F, u, q_w, k_w, v_w, gate_w, out_w):
+        b, s, _ = u.shape
+        n, kv, d = self._heads, self._kv_heads, self._head_dim
+
+        def heads(x, count):
+            x = x.reshape(b, s, count, d).transpose((0, 2, 1, 3))
+            return F.rotary_embedding(
+                x, inv_freq=self._inv_freq,
+                attention_factor=self._attention_factor)
+
+        q = heads(self._linear(F, u, q_w), n)
+        k = heads(self._linear(F, u, k_w), kv)
+        v = self._linear(F, u, v_w).reshape(b, s, kv, d) \
+            .transpose((0, 2, 1, 3))
+        att = F.scaled_dot_product_attention(
+            q, k, v, causal=True, window=self._window)
+        gate = F.sigmoid(self._linear(F, u, gate_w).astype("float32"))
+        att = att.transpose((0, 2, 1, 3)) \
+            * gate.astype(att.dtype).reshape(b, s, n, 1)
+        return self._linear(F, att.reshape(b, s, n * d), out_w)
+
+    def hybrid_forward(self, F, x, attn_norm, q_weight, k_weight, v_weight,
+                       gate_weight, out_weight, ffn_norm, **ffn):
+        scope = "attention_window" if self._window else "attention_full"
+        with jax.named_scope(scope):
+            a = x + self._attention(
+                F, F.rms_norm(x, attn_norm, eps=self._eps), q_weight,
+                k_weight, v_weight, gate_weight, out_weight)
+        u = F.rms_norm(a, ffn_norm, eps=self._eps)
+        if not self._sparse:
+            with jax.named_scope("dense_ffn"):
+                return a + self._swiglu_ffn(F, u, ffn["ffn_in_weight"],
+                                            ffn["ffn_out_weight"])
+        routed, rows = F.moe_ffn(
+            u, ffn["router_weight"], ffn["expert_in_weight"],
+            ffn["expert_out_weight"], first_expert=self._first_expert,
+            top_k=self._top_k, scale=self._scale)
+        with jax.named_scope("shared_expert"):
+            shared = self._swiglu_ffn(F, u, ffn["shared_in_weight"],
+                                      ffn["shared_out_weight"])
+        return a + shared + routed, rows
+
+
+class LMHead(HybridBlock):
+    """Final norm, untied head, mean next-token cross-entropy in
+    float32 over the vocabulary rows held here."""
+
+    def __init__(self, config, **kwargs):
+        super().__init__(**kwargs)
+        h = config["hidden_size"]
+        self._eps = config.get("rms_norm_eps", 1e-6)
+        self.norm = self.params.get("norm", shape=(h,), init="ones")
+        self.weight = self.params.get(
+            "weight", shape=(config["vocab_size"], h))
+
+    def hybrid_forward(self, F, x, labels, norm, weight):
+        with jax.named_scope("lm_head"):
+            x = F.rms_norm(x, norm, eps=self._eps)
+            # float32 logits from the compute dtype's operands (the
+            # products are exact in float32, and accumulate there)
+            logits = F.FullyConnected(
+                x.astype("float32"), weight.astype("float32"), no_bias=True,
+                flatten=False, num_hidden=weight.shape[0])
+            picked = F.pick(F.log_softmax(logits), labels, axis=-1)
+            return -F.mean(picked)
+
+
+class DecoderLM(HybridBlock):
+    def __init__(self, config, **kwargs):
+        super().__init__(**kwargs)
+        self.config = config
+        n_layers = config["num_hidden_layers"]
+        self._sparse_layers = [
+            i for i in range(n_layers)
+            if config["mlp_layer_types"][i] == "sparse"]
+        if self._sparse_layers:
+            self.routing_log = self.params.get(
+                "routing_log", grad_req="null", init="zeros",
+                shape=(len(self._sparse_layers), config["num_experts"] + 1))
+        self.embed = self.params.get(
+            "embed", shape=(config["vocab_size"], config["hidden_size"]))
+        self.layers = []
+        for i in range(n_layers):
+            layer = DecoderLayer(config, i)
+            setattr(self, f"layer{i}", layer)
+            self.layers.append(layer)
+        self.head = LMHead(config)
+
+    def hybrid_forward(self, F, ids, labels, embed, routing_log=None):
+        x = F.Embedding(ids, embed, input_dim=embed.shape[0],
+                        output_dim=embed.shape[1])
+        for i, layer in enumerate(self.layers):
+            if i in self._sparse_layers:
+                x, rows = layer(x)
+                F.moe_routing_log(rows, routing_log,
+                                  layer=self._sparse_layers.index(i))
+            else:
+                x = layer(x)
+        return self.head(x, labels)
+
+    def routing_rows(self, log=None):
+        """{decoder layer index: float array (held + 1,)}: the rows each
+        held expert got in the newest step, then the assignments that
+        went to absent experts.  `log` is the routing log's values where
+        the block's own are stale: under a `DataParallelTrainer`,
+        `trainer.aux_params()[net.routing_log.name]`."""
+        if not self._sparse_layers:
+            return {}
+        if log is None:
+            log = self.routing_log.data().asnumpy()
+        return {layer: log[row]
+                for row, layer in enumerate(self._sparse_layers)}
+
+
+def routing_stats(rows):
+    """One expert layer's line of the `moeRouting` section from its
+    (held + 1,) routing counts."""
+    held = np.asarray(rows[:-1], np.float64)
+    here, total = float(held.sum()), float(np.sum(rows))
+    mean = here / len(held) if len(held) else 0.0
+    return {"rows_per_expert": [int(r) for r in held],
+            "rows_here": int(here),
+            "share_here": here / total if total else 0.0,
+            "max_over_mean": float(held.max() / mean) if mean else 0.0}
+
+
+def moe_routing_stats(newest=False, window=False):
+    """The `moeRouting` profiler section: for every live
+    `DataParallelTrainer` whose block is a `DecoderLM` with expert
+    layers (the newest such trainer alone under `newest=True`; under
+    `window=True` those alone that stepped since the section's window
+    opened), each layer's routing in the trainer's newest step, read
+    from its routing log on demand (reading costs a training window
+    nothing: the log leaves the compiled step as an aux output).  Keyed
+    `trainer<n>.layer<l>`, n the trainer's serial in this process (and
+    `.expert<e>` for the rows of one held expert), under each quantity:
+    the shape `/metrics` renders as labelled samples.  A model run
+    eagerly has its own `routing_rows()`."""
+    from ..parallel import data_parallel
+
+    out = {"layers": 0, "rows_here": {}, "share_here": {},
+           "max_over_mean": {}, "rows_per_expert": {}}
+    trainers = [t for t in data_parallel.live_trainers()
+                if isinstance(t.block, DecoderLM) and t.block._sparse_layers]
+    if window:
+        stepped = {record[0] for record in data_parallel.step_log()
+                   if record[2] >= _opened_ns}
+        trainers = [t for t in trainers if t._serial in stepped]
+    for trainer in trainers[-1:] if newest else trainers:
+        log = trainer.aux_params()[trainer.block.routing_log.name]
+        for layer, rows in trainer.block.routing_rows(log).items():
+            key = f"trainer{trainer._serial}.layer{layer}"
+            stats = routing_stats(rows)
+            out["layers"] += 1
+            for name in ("rows_here", "share_here", "max_over_mean"):
+                out[name][key] = stats[name]
+            for e, count in enumerate(stats["rows_per_expert"]):
+                out["rows_per_expert"][f"{key}.expert{e}"] = count
+    return out
+
+
+def reset_moe_routing_stats():
+    """Open the section's window, as a reset dump does for every
+    section: a trainer that takes no step after this is left out of
+    `moe_routing_stats(window=True)` (its log is an older window's)."""
+    global _opened_ns
+    _opened_ns = time.perf_counter_ns()

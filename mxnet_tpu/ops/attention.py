@@ -24,19 +24,32 @@ from ..base import getenv
 from .registry import register
 
 
-def sdpa_reference(q, k, v, mask=None, *, scale=None, causal=False):
+def sdpa_reference(q, k, v, mask=None, *, scale=None, causal=False,
+                   window=None):
     """Scaled dot-product attention, XLA fallback / numeric oracle.
 
-    q,k,v: (batch, heads, seq, head_dim). mask: additive (b,1,sq,sk) or
-    bool; causal adds a lower-triangular mask.
+    q: (batch, heads, seq, head_dim); k, v the same, or with fewer
+    heads (a divisor of q's: query head j reads K/V head
+    j // (heads // kv_heads)).  mask: additive (b,1,sq,sk) or bool;
+    causal adds a lower-triangular mask, and `window` (with causal)
+    keeps of it the pairs with i - j < window.
     """
+    if window is not None and not causal:
+        raise ValueError("attention window needs causal=True")
     d = q.shape[-1]
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k = jnp.repeat(k, group, axis=1)
+        v = jnp.repeat(v, group, axis=1)
     s = scale if scale is not None else 1.0 / jnp.sqrt(
         jnp.asarray(d, q.dtype))
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * s
     if causal:
         sq, sk = logits.shape[-2], logits.shape[-1]
         causal_mask = jnp.tril(jnp.ones((sq, sk), bool), sk - sq)
+        if window is not None:
+            causal_mask &= ~jnp.tril(jnp.ones((sq, sk), bool),
+                                     sk - sq - window)
         logits = jnp.where(causal_mask, logits, jnp.asarray(-1e9, q.dtype))
     if mask is not None:
         if mask.dtype == jnp.bool_:
@@ -48,9 +61,14 @@ def sdpa_reference(q, k, v, mask=None, *, scale=None, causal=False):
 
 
 def _k_sdpa(q, k, v, mask=None, *, scale=None, causal=False,
-            dropout_p=0.0):
+            dropout_p=0.0, window=None):
+    """q: (batch, heads, seq, head_dim).  K/V may carry fewer heads
+    than Q (grouped-query attention, read from the shapes); `window`
+    with `causal` is sliding-window attention: position i sees j with
+    0 <= i - j < window."""
     if getenv("DISABLE_PALLAS", False, bool):
-        return sdpa_reference(q, k, v, mask, scale=scale, causal=causal)
+        return sdpa_reference(q, k, v, mask, scale=scale, causal=causal,
+                              window=window)
     from .pallas.flash_attention import flash_attention
 
     # the branch is picked when the computation is lowered, by the
@@ -61,15 +79,15 @@ def _k_sdpa(q, k, v, mask=None, *, scale=None, causal=False,
     def _xla(q, k, v, mask):
         # both branches return the query's dtype (an additive f32 mask
         # would otherwise promote the XLA form of a bf16 model to f32)
-        return sdpa_reference(q, k, v, mask, scale=scale,
-                              causal=causal).astype(q.dtype)
+        return sdpa_reference(q, k, v, mask, scale=scale, causal=causal,
+                              window=window).astype(q.dtype)
 
     def _tpu(q, k, v, mask):
         from ..parallel.mesh import per_batch_shard
 
         return per_batch_shard(
             functools.partial(flash_attention, scale=scale,
-                              causal=causal), q, k, v, mask
+                              causal=causal, window=window), q, k, v, mask
         ).astype(q.dtype)
 
     return jax.lax.platform_dependent(q, k, v, mask, tpu=_tpu,

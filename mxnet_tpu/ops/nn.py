@@ -14,9 +14,11 @@ costs nothing at steady state.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .registry import register
@@ -776,3 +778,102 @@ _svm_core.defvjp(_svm_fwd, _svm_bwd)
 
 register("SVMOutput", _k_svm_output, arg_names=("data", "label"),
          aliases=("svm_output",))
+
+
+# ---------------------------------------------------------------------------
+# decoder building blocks: RMS norm, rotary position embedding, SwiGLU.
+# Each is its own oracle: the XLA form below is the only form.
+
+
+def _k_rms_norm(data, gamma, *, eps=1e-6):
+    """x / sqrt(mean(x^2) + eps) * gamma over the last axis; the
+    statistics and the scaling in float32, the result in data's dtype."""
+    x = data.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+    return (x * inv * gamma.astype(jnp.float32)).astype(data.dtype)
+
+
+register("rms_norm", _k_rms_norm, arg_names=("data", "gamma"),
+         aliases=("RMSNorm",))
+
+
+def rotary_frequencies(rotary_dim, *, rope_theta=10000.0,
+                       rope_type="default", factor=1.0,
+                       original_max_position_embeddings=None,
+                       beta_fast=32.0, beta_slow=1.0, attention_factor=None,
+                       **_ignored):
+    """(inverse frequencies, attention factor) of a rotary embedding
+    over `rotary_dim` dimensions of a head, made once from a model
+    config's rope parameters: `default`, or `yarn` as HF transformers'
+    `_compute_yarn_parameters` (frequencies blended between the
+    interpolated and the original ones by a linear ramp between the two
+    correction dimensions; cos and sin scaled by `attention_factor`,
+    0.1 ln(factor) + 1 when not given)."""
+    half = rotary_dim // 2
+    plain = [rope_theta ** (-2.0 * i / rotary_dim) for i in range(half)]
+    if rope_type == "default":
+        return tuple(plain), 1.0
+    if rope_type != "yarn":
+        raise ValueError(f"unknown rope_type {rope_type!r}")
+    if attention_factor is None:
+        attention_factor = 0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    def correction_dim(rotations):
+        return rotary_dim * math.log(
+            original_max_position_embeddings / (rotations * 2 * math.pi)) \
+            / (2 * math.log(rope_theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), rotary_dim - 1)
+    if low == high:
+        high += 0.001
+    freqs = []
+    for i, f in enumerate(plain):
+        ramp = min(max((i - low) / (high - low), 0.0), 1.0)
+        freqs.append(f / factor * ramp + f * (1.0 - ramp))
+    return tuple(freqs), float(attention_factor)
+
+
+def _k_rotary_embedding(data, *, inv_freq, attention_factor=1.0, offset=0):
+    """Rotary position embedding of (batch, heads, seq, head_dim) with
+    HF's `rotate_half` pairing over the first 2 * len(inv_freq)
+    dimensions of a head (dimension i pairs with i + len(inv_freq));
+    the rest of the head passes unchanged.  Position t of the sequence
+    axis is `offset + t`; angles, cos and sin in float32.
+
+    Computed as x * cos + swap(x) * sin over the WHOLE head, with cos 1
+    and sin 0 on the dimensions that do not rotate and the sign of
+    `rotate_half` folded into sin; swap exchanges the two halves of the
+    rotated part by a reshape and a reverse.  (The textbook
+    concatenate of two 64-wide float32 pieces aborts the TPU compiler's
+    fusion emitter: `IsFusibleUnalignedDUS`, PERF.md PR 29.)"""
+    half, d = len(inv_freq), data.shape[-1]
+    if d % (2 * half):
+        raise ValueError(f"rotary_embedding: head size {d} is not a "
+                         f"multiple of the {2 * half} rotated dimensions")
+    still = d - 2 * half
+    freq = np.concatenate([inv_freq, inv_freq, np.zeros(still)])
+    scale = np.concatenate([np.full(2 * half, attention_factor),
+                            np.ones(still)])
+    sign = np.concatenate([-np.ones(half), np.ones(half), np.zeros(still)])
+    pos = offset + jnp.arange(data.shape[-2], dtype=jnp.float32)
+    angles = pos[:, None] * jnp.asarray(freq, jnp.float32)[None, :]
+    cos = jnp.cos(angles) * jnp.asarray(scale, jnp.float32)
+    sin = jnp.sin(angles) * jnp.asarray(sign * attention_factor, jnp.float32)
+    x = data.astype(jnp.float32)
+    blocks = x.reshape(x.shape[:-1] + (d // (2 * half), 2, half))
+    swapped = jnp.flip(blocks, axis=-2).reshape(x.shape)
+    return (x * cos + swapped * sin).astype(data.dtype)
+
+
+register("rotary_embedding", _k_rotary_embedding, arg_names=("data",))
+
+
+def _k_swiglu(data):
+    """silu(gate) * up of a (..., 2 * width) input that packs [gate |
+    up] along its last axis; the product in float32."""
+    gate, up = jnp.split(data.astype(jnp.float32), 2, axis=-1)
+    return (jax.nn.silu(gate) * up).astype(data.dtype)
+
+
+register("swiglu", _k_swiglu, arg_names=("data",))

@@ -32,6 +32,11 @@ works on the transposed scores, where lane-dense rows broadcast as
 they are.  The STREAMED kernels (K/V swept by a third grid dimension,
 past `MXTPU_FLASH_MAX_KV_VMEM_MB`) keep a head a step.
 
+The GROUPED kernels serve K/V with fewer heads than Q (the query
+heads that read one K/V head are one tile) and a sliding window
+(k-blocks outside it are never touched); `window=None` with equal head
+counts never reaches them.  They are described where they stand.
+
 Every kernel built while a program is traced is counted
 (`flash_attention_stats`, the profiler section `flashAttention`).
 
@@ -69,22 +74,27 @@ _GROUP_VMEM_BYTES = 4 * 2 ** 20
 _built = collections.Counter()
 
 
-def _record_built(variant, kernel, q, sk, heads, grid):
+def _record_built(variant, kernel, q, sk, heads, grid, kv_heads=None,
+                  window=None):
     b, h, sq, d = q.shape
     _built[(variant, kernel, (b, h, sq, sk, d), q.dtype.name, heads,
-            tuple(grid))] += 1
+            tuple(grid), kv_heads or h, window)] += 1
 
 
 def flash_attention_stats():
     """The `flashAttention` profiler section: how the kernels engaged
     in the programs traced since the last reset.  `built` has a row for
-    each distinct kernel, named by its variant (resident / streamed),
-    which of the three it is, its shapes, the heads a grid step works
-    on and the grid."""
+    each distinct kernel, named by its variant (resident / streamed /
+    grouped), which of the three it is, its shapes (a grouped kernel's
+    with its K/V head count and its window, 0 for none), the heads a
+    grid step works on and the grid."""
     built = {}
-    for (variant, kernel, shape, dtype, heads, grid), n in _built.items():
+    for (variant, kernel, shape, dtype, heads, grid, kv_heads,
+         window), n in _built.items():
         dims = " ".join(f"{k}{v}" for k, v in zip(
             ("b", "h", "sq", "sk", "d"), shape))
+        if variant == "grouped":
+            dims += f" kv{kv_heads} window{window or 0}"
         built[f"{variant} {kernel} {dims} {dtype} heads{heads} "
               f"grid{'x'.join(map(str, grid))}"] = n
 
@@ -93,7 +103,7 @@ def flash_attention_stats():
 
     return {"kernels": sum(_built.values()),
             "resident": count("resident"), "streamed": count("streamed"),
-            "built": built}
+            "grouped": count("grouped"), "built": built}
 
 
 def reset_flash_attention_stats():
@@ -722,6 +732,380 @@ def _flash_backward(q, k, v, o, lse, do, *, causal, scale, kmask=None,
             dv.reshape(b, h, sk, d))
 
 
+# ---------------------------------------------------------------------------
+# grouped variant: K/V with fewer heads than Q (grouped-query attention)
+# and / or a sliding window.  The `group` query heads that read one K/V
+# head are ONE tile: q block (1, group, block_q, d) of the (b * kv_heads,
+# group, sq, d) view, worked on as (group * block_q, d) rows against the
+# shared K/V, so the group's products are one MXU product eight (or six)
+# times as tall, and dK/dV's sum over the group is the contraction of
+# that product.  Forward and dQ keep the K/V head resident (its block
+# index does not move along the q-blocks, so it is fetched once a K/V
+# head) and loop over the k-blocks a q-block can see: those above the
+# diagonal and those wholly left of the window are never touched, and
+# only the blocks the mask's edge crosses pay for the mask.  dK/dV
+# sweeps the q-blocks a k-block is seen from by a third grid dimension,
+# the tiles of all the group's heads arriving together; steps past the
+# visible range keep the last block's index (nothing is fetched) and do
+# nothing.  Products take the operands' dtype (bf16 on the chip) and
+# accumulate in float32; the scale is applied to the float32 scores.
+# Row statistics travel lane-dense as (b * kv_heads, q-blocks, 1,
+# group * block_q): one row a tile, in the tile's own row order.
+# ---------------------------------------------------------------------------
+
+_GROUPED_BLOCK_Q = 128
+_GROUPED_BLOCK_K = 128      # forward and dQ: the k-block of the loop
+# the forward's k-block without a window: the online softmax's
+# bookkeeping (running maximum, rescaling the accumulator, columns of
+# one lane in 128) is paid a block, so wide blocks where the visible
+# span is long; under a window the span is short and wide blocks would
+# mostly be masked
+_GROUPED_BLOCK_K_FULL = 512
+_GROUPED_BLOCK_DKV = 256    # dK/dV: the k-tile a grid step owns
+# what a grouped kernel may use of the chip's 128 MB of VMEM, and the
+# share of it the resident K/V head may take, the pipeline's second
+# buffer included: 8 MB at (8192, 128) bf16 and 16 MB in float32 (a
+# trainer's eager float32 probe of the model), where the default 16 MB
+# a kernel would not hold the tiles beside it
+_GROUPED_VMEM_LIMIT = 48 * 2 ** 20
+_GROUPED_KV_VMEM_BYTES = 32 * 2 ** 20
+
+
+def _visible_k_blocks(qi, block_q, block_k, num_kb, causal, window):
+    """(a, b, c, e): k-blocks [a, e) hold a visible pair for q-block
+    `qi`; of them [b, c) are visible whole and need no mask."""
+    if not causal:
+        return 0, 0, num_kb, num_kb
+    first_row, last_row = qi * block_q, qi * block_q + block_q - 1
+    hi = jnp.minimum(last_row // block_k + 1, num_kb)
+    whole_hi = (first_row + 1) // block_k
+    if window is None:
+        lo = whole_lo = 0
+    else:
+        lo = jnp.maximum(first_row - window + 1, 0) // block_k
+        whole_lo = jnp.maximum(last_row - window + block_k, 0) // block_k
+    b = jnp.clip(whole_lo, lo, hi)
+    c = jnp.clip(whole_hi, b, hi)
+    return lo, b, c, hi
+
+
+_NT = (((1,), (1,)), ((), ()))     # a @ b.T
+
+
+def _tile_positions(qi, group, block_q, block_k):
+    """(query position of every row of a (group * block_q, block_k)
+    score tile, key offset of every column inside its k-block)."""
+    rows = group * block_q
+    q_pos = qi * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (group, block_q, block_k), 1).reshape(rows, block_k)
+    return q_pos, jax.lax.broadcasted_iota(jnp.int32, (rows, block_k), 1)
+
+
+def _sweep_k_blocks(body, bounds, carry):
+    """`body(kb, carry, masked=)` over the k-blocks [a, e) of
+    `_visible_k_blocks`: masked on the edges, unmasked on [b, c)."""
+    a, b, c, e = bounds
+    edge = functools.partial(body, masked=True)
+    carry = jax.lax.fori_loop(a, b, edge, carry)
+    carry = jax.lax.fori_loop(b, c, functools.partial(body, masked=False),
+                              carry)
+    return jax.lax.fori_loop(c, e, edge, carry)
+
+
+def _pair_mask(q_pos, k_pos, window):
+    seen = q_pos >= k_pos
+    if window is not None:
+        seen = seen & (q_pos - k_pos < window)
+    return seen
+
+
+def _col_to_row(col):
+    """(rows, 1) -> (1, rows): sublanes to lanes by one transpose."""
+    return jnp.broadcast_to(col, (col.shape[0], _LANES)).T[:1]
+
+
+def _row_to_col(row):
+    """(1, rows) -> (rows, 1)."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T[:, :1]
+
+
+def _grouped_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
+                        causal, window, scale, seq_k):
+    _, group, block_q, d = q_ref.shape
+    rows = group * block_q
+    qi = pl.program_id(1)
+    # the scale goes onto the q tile once a grid step, not onto every
+    # block of scores: the loop is bound by the VPU, not the MXU
+    q = (q_ref[0].reshape(rows, d).astype(jnp.float32) * scale).astype(
+        q_ref.dtype)
+    q_pos, k_off = _tile_positions(qi, group, block_q, block_k)
+
+    def body(kb, carry, masked):
+        m_prev, l_prev, acc = carry
+        k = k_ref[0, pl.ds(kb * block_k, block_k), :]
+        v = v_ref[0, pl.ds(kb * block_k, block_k), :]
+        s = jax.lax.dot_general(
+            q, k, _NT, preferred_element_type=jnp.float32)
+        if masked:
+            s = jnp.where(_pair_mask(q_pos, kb * block_k + k_off, window),
+                          s, _NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        acc = acc * alpha + jnp.dot(p.astype(v.dtype), v,
+                                    preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    m, l, acc = _sweep_k_blocks(
+        body, _visible_k_blocks(qi, block_q, block_k, seq_k // block_k,
+                                causal, window),
+        (jnp.full((rows, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((rows, 1), jnp.float32),
+         jnp.zeros((rows, d), jnp.float32)))
+    l = jnp.maximum(l, 1e-30)
+    o_ref[0] = (acc / l).astype(o_ref.dtype).reshape(group, block_q, d)
+    lse_ref[0, 0] = _col_to_row(m + jnp.log(l))
+
+
+def _grouped_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                       dq_ref, delta_ref, *, block_k, causal, window,
+                       scale, seq_k):
+    _, group, block_q, d = q_ref.shape
+    rows = group * block_q
+    qi = pl.program_id(1)
+    q = q_ref[0].reshape(rows, d)
+    do = do_ref[0].reshape(rows, d)
+    delta = jnp.sum(do.astype(jnp.float32)
+                    * o_ref[0].reshape(rows, d).astype(jnp.float32),
+                    axis=-1, keepdims=True)
+    lse = _row_to_col(lse_ref[0, 0])
+    q_pos, k_off = _tile_positions(qi, group, block_q, block_k)
+
+    def body(kb, dq, masked):
+        k = k_ref[0, pl.ds(kb * block_k, block_k), :]
+        v = v_ref[0, pl.ds(kb * block_k, block_k), :]
+        s = scale * jax.lax.dot_general(
+            q, k, _NT, preferred_element_type=jnp.float32)
+        if masked:
+            s = jnp.where(_pair_mask(q_pos, kb * block_k + k_off, window),
+                          s, _NEG_INF)
+        p = jnp.exp(s - lse)
+        dp = jax.lax.dot_general(do, v, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta)
+        return dq + jnp.dot(ds.astype(k.dtype), k,
+                            preferred_element_type=jnp.float32)
+
+    dq = _sweep_k_blocks(
+        body, _visible_k_blocks(qi, block_q, block_k, seq_k // block_k,
+                                causal, window),
+        jnp.zeros((rows, d), jnp.float32))
+    dq_ref[0] = (scale * dq).astype(dq_ref.dtype).reshape(group, block_q, d)
+    delta_ref[0, 0] = _col_to_row(delta)
+
+
+def _visible_q_blocks(ki, block_q, block_k, num_qb, causal, window):
+    """q-blocks [lo, hi) hold a pair that sees k-tile `ki`."""
+    if not causal:
+        return 0, num_qb
+    lo = (ki * block_k) // block_q
+    if window is None:
+        return lo, num_qb
+    last_seen_from = ki * block_k + block_k - 2 + window
+    return lo, jnp.minimum(last_seen_from // block_q + 1, num_qb)
+
+
+def _grouped_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                        dk_ref, dv_ref, dk_scr, dv_scr, *, causal, window,
+                        scale, num_qb, sweep):
+    # transposed scores (block_k, group * block_q): the lane-dense lse
+    # and delta rows broadcast down the sublanes as they are
+    _, group, block_q, d = q_ref.shape
+    block_k = k_ref.shape[1]
+    rows = group * block_q
+    ki = pl.program_id(1)
+    t = pl.program_id(2)
+    lo, hi = _visible_q_blocks(ki, block_q, block_k, num_qb, causal, window)
+    qb = lo + t
+
+    @pl.when(t == 0)
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    def step(masked):
+        k = k_ref[0]
+        v = v_ref[0]
+        q = q_ref[0].reshape(rows, d)
+        do = do_ref[0].reshape(rows, d)
+        st = scale * jax.lax.dot_general(
+            k, q, _NT, preferred_element_type=jnp.float32)
+        if masked:
+            k_pos = ki * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, rows), 0)
+            q_pos = qb * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, group, block_q), 2).reshape(
+                    block_k, rows)
+            st = jnp.where(_pair_mask(q_pos, k_pos, window), st, _NEG_INF)
+        pt = jnp.exp(st - lse_ref[0, 0])
+        dv_scr[:] += jnp.dot(pt.astype(do.dtype), do,
+                             preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(v, do, _NT,
+                                  preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta_ref[0, 0])
+        dk_scr[:] += jnp.dot(dst.astype(q.dtype), q,
+                             preferred_element_type=jnp.float32)
+
+    if causal:
+        live = qb < hi
+        # whole: every query of the block at or after every key of the
+        # tile, and the farthest pair still inside the window
+        whole = qb * block_q >= ki * block_k + block_k - 1
+        if window is not None:
+            whole = whole & (qb * block_q + block_q - 1 - ki * block_k
+                             < window)
+        pl.when(live & whole)(functools.partial(step, False))
+        pl.when(live & jnp.logical_not(whole))(
+            functools.partial(step, True))
+    else:
+        step(False)
+
+    @pl.when(t == sweep - 1)
+    def _flush():
+        dk_ref[0] = (scale * dk_scr[:]).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _grouped_params(semantics):
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=_GROUPED_VMEM_LIMIT)
+
+
+def _grouped_views(q, k, v):
+    b, h, sq, d = q.shape
+    kvh, sk = k.shape[1], k.shape[2]
+    return (q.reshape(b * kvh, h // kvh, sq, d), k.reshape(b * kvh, sk, d),
+            v.reshape(b * kvh, sk, d))
+
+
+def _grouped_forward(q, k, v, *, causal, window, scale):
+    b, h, sq, d = q.shape
+    kvh, sk = k.shape[1], k.shape[2]
+    group, block_q = h // kvh, _GROUPED_BLOCK_Q
+    q4, k3, v3 = _grouped_views(q, k, v)
+    tile = _vmem((1, group, block_q, d), lambda i, j: (i, 0, j, 0))
+    full = _vmem((1, sk, d), lambda i, j: (i, 0, 0))
+    stat = _vmem((1, 1, 1, group * block_q), lambda i, j: (i, j, 0, 0))
+    grid = (b * kvh, sq // block_q)
+    _record_built("grouped", "fwd", q, sk, group, grid, kvh, window)
+    block_k = _GROUPED_BLOCK_K_FULL if window is None \
+        and sk % _GROUPED_BLOCK_K_FULL == 0 else _GROUPED_BLOCK_K
+    out, lse = pallas_call(
+        functools.partial(_grouped_fwd_kernel, block_k=block_k,
+                          causal=causal, window=window, scale=scale,
+                          seq_k=sk),
+        out_shape=(
+            jax.ShapeDtypeStruct(q4.shape, q.dtype),
+            jax.ShapeDtypeStruct((b * kvh, sq // block_q, 1,
+                                  group * block_q), jnp.float32)),
+        grid=grid, in_specs=[tile, full, full], out_specs=(tile, stat),
+        compiler_params=_grouped_params(("parallel", "arbitrary")),
+    )(q4, k3, v3)
+    return out.reshape(b, h, sq, d), lse
+
+
+def _grouped_backward(q, k, v, o, lse, do, *, causal, window, scale):
+    b, h, sq, d = q.shape
+    kvh, sk = k.shape[1], k.shape[2]
+    group, block_q = h // kvh, _GROUPED_BLOCK_Q
+    block_k = _GROUPED_BLOCK_DKV if sk % _GROUPED_BLOCK_DKV == 0 \
+        else _GROUPED_BLOCK_K
+    num_qb = sq // block_q
+    q4, k3, v3 = _grouped_views(q, k, v)
+    o4, do4 = o.reshape(q4.shape), do.reshape(q4.shape)
+
+    tile = _vmem((1, group, block_q, d), lambda i, j: (i, 0, j, 0))
+    full = _vmem((1, sk, d), lambda i, j: (i, 0, 0))
+    stat = _vmem((1, 1, 1, group * block_q), lambda i, j: (i, j, 0, 0))
+    grid = (b * kvh, num_qb)
+    _record_built("grouped", "dq", q, sk, group, grid, kvh, window)
+    dq, delta = pallas_call(
+        functools.partial(_grouped_dq_kernel, block_k=_GROUPED_BLOCK_K,
+                          causal=causal, window=window, scale=scale,
+                          seq_k=sk),
+        out_shape=(jax.ShapeDtypeStruct(q4.shape, q.dtype),
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)),
+        grid=grid, in_specs=[tile, full, full, tile, tile, stat],
+        out_specs=(tile, stat),
+        compiler_params=_grouped_params(("parallel", "arbitrary")),
+    )(q4, k3, v3, do4, o4, lse)
+
+    # the q-blocks one k-tile is seen from: all of them without a
+    # window (the causal half of the steps does nothing), else those
+    # that reach window - 1 rows past the tile
+    sweep = num_qb if not causal or window is None else min(
+        num_qb, (block_k + window - 2) // block_q + 1)
+
+    def q_block(i, j, t):
+        lo, hi = _visible_q_blocks(j, block_q, block_k, num_qb, causal,
+                                   window)
+        return jnp.minimum(lo + t, hi - 1)
+
+    q_tile = _vmem((1, group, block_q, d),
+                   lambda i, j, t: (i, 0, q_block(i, j, t), 0))
+    q_stat = _vmem((1, 1, 1, group * block_q),
+                   lambda i, j, t: (i, q_block(i, j, t), 0, 0))
+    k_tile = _vmem((1, block_k, d), lambda i, j, t: (i, j, 0))
+    grid = (b * kvh, sk // block_k, sweep)
+    _record_built("grouped", "dkv", q, sk, group, grid, kvh, window)
+    dk, dv = pallas_call(
+        functools.partial(_grouped_dkv_kernel, causal=causal,
+                          window=window, scale=scale, num_qb=num_qb,
+                          sweep=sweep),
+        out_shape=(jax.ShapeDtypeStruct(k3.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v3.shape, v.dtype)),
+        grid=grid,
+        in_specs=[q_tile, k_tile, k_tile, q_tile, q_stat, q_stat],
+        out_specs=(k_tile, k_tile),
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        compiler_params=_grouped_params(
+            ("parallel", "parallel", "arbitrary")),
+    )(q4, k3, v3, do4, lse, delta)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _grouped_sdpa(q, k, v, causal, window, scale):
+    return _grouped_forward(q, k, v, causal=causal, window=window,
+                            scale=scale)[0]
+
+
+def _grouped_sdpa_fwd(q, k, v, causal, window, scale):
+    out, lse = _grouped_forward(q, k, v, causal=causal, window=window,
+                                scale=scale)
+    return out, (q, k, v, out, lse)
+
+
+def _grouped_sdpa_bwd(causal, window, scale, res, g):
+    q, k, v, o, lse = res
+    return _grouped_backward(q, k, v, o, lse, g, causal=causal,
+                             window=window, scale=scale)
+
+
+_grouped_sdpa.defvjp(_grouped_sdpa_fwd, _grouped_sdpa_bwd)
+
+
+def _grouped_ok(q, k, mask):
+    """Gate of the grouped variant: no mask operand, self-attention
+    lengths, 128-tiles, and a K/V head that stays resident (32k tokens
+    at head size 128 in bf16): a rule of the shapes alone."""
+    kv_bytes = 2 * 2 * k.shape[2] * k.shape[3] * k.dtype.itemsize
+    return (mask is None and q.shape[2] == k.shape[2]
+            and _tiles_ok(q, k) and kv_bytes <= _GROUPED_KV_VMEM_BYTES)
+
+
 def _tiles_ok(q, k, block_q=128, block_k=128):
     b, h, sq, d = q.shape
     sk = k.shape[2]
@@ -800,24 +1184,39 @@ def _as_key_padding_mask(mask, q, k):
     return row.astype(jnp.float32)
 
 
-def flash_attention(q, k, v, mask=None, scale=None, causal=False):
-    """Fused attention; q,k,v: (batch, heads, seq, head_dim).
+def flash_attention(q, k, v, mask=None, scale=None, causal=False,
+                    window=None):
+    """Fused attention; q: (batch, heads, seq, head_dim), k and v the
+    same or with fewer heads, a divisor of q's: query head j then reads
+    K/V head j // (heads // kv_heads).  `window` (with `causal`) keeps
+    the pairs with 0 <= i - j < window.
 
     Key-padding masks — additive or bool, shape (b, 1, 1, seq_k), the
     form BERT-style encoders build — ride inside the kernel; full
     per-score masks and unaligned shapes fall back to the XLA
-    reference (the caller treats this function as best-effort)."""
+    reference (the caller treats this function as best-effort).  With
+    equal head counts and no window the kernels are the resident /
+    streamed ones; shared K/V heads or a window take the grouped ones."""
     from ..attention import sdpa_reference
 
+    if window is not None and not causal:
+        raise ValueError("attention window needs causal=True")
+    reference = functools.partial(sdpa_reference, q, k, v, mask,
+                                  scale=scale, causal=causal, window=window)
+    s = float(scale) if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+    if window is not None or k.shape[1] != q.shape[1]:
+        if not _grouped_ok(q, k, mask):
+            return reference()
+        return _grouped_sdpa(q, k, v, bool(causal),
+                             None if window is None else int(window), s)
     if not _tiles_ok(q, k):
-        return sdpa_reference(q, k, v, mask, scale=scale, causal=causal)
+        return reference()
     if causal and q.shape[2] != k.shape[2]:
         # the kernels use the start-aligned q_pos >= k_pos convention;
         # the reference's causal mask for sq != sk is END-aligned
         # (tril offset sk-sq) — keep the oracle's semantics
-        return sdpa_reference(q, k, v, mask, scale=scale, causal=causal)
-    s = float(scale) if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+        return reference()
     km = _as_key_padding_mask(mask, q, k)
     if mask is not None and km is None:  # full score mask: XLA fallback
-        return sdpa_reference(q, k, v, mask, scale=scale, causal=causal)
+        return reference()
     return _flash_sdpa(q, k, v, km, bool(causal), s)

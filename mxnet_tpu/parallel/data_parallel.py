@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import collections
 import time
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +48,7 @@ from . import mesh as mesh_mod
 _step_log = collections.deque(maxlen=4096)
 _built = 0          # trainers built so far; the newest one's serial
 _window = (0, 0)    # the clock and `_built` when the section's window opened
+_live = weakref.WeakValueDictionary()   # serial -> trainer, while it lives
 
 
 def step_log(last=None):
@@ -54,6 +56,13 @@ def step_log(last=None):
     at most 4096, by default), oldest first."""
     log = list(_step_log)
     return log if last is None else log[max(len(log) - last, 0):]
+
+
+def live_trainers():
+    """The trainers built and still alive, oldest first: where a reader
+    of a model's non-trainable state starts (`aux_params()`), as
+    `step_log()` is where a reader of the spans does."""
+    return [_live[serial] for serial in sorted(_live)]
 
 
 def data_parallel_step_stats():
@@ -145,14 +154,19 @@ class DataParallelTrainer:
     def _gather_params(self, sample_x):
         if self.block._active is False:
             self.block.hybridize()
-        # one eager probe to finish deferred init
-        if isinstance(sample_x, tuple):
-            probe = self.block(*sample_x)
-        else:
-            probe = self.block(sample_x)
-        if isinstance(probe, (list, tuple)):
-            for p in probe:
-                p.wait_to_read()
+        # one eager probe to finish deferred init, where any is pending
+        # (a model whose shapes are all stated needs none: at 16k tokens
+        # an eager float32 forward is a second program to compile and
+        # gigabytes of intermediates for nothing)
+        if any(p._deferred_init is not None
+               for p in self.block.collect_params().values()):
+            if isinstance(sample_x, tuple):
+                probe = self.block(*sample_x)
+            else:
+                probe = self.block(sample_x)
+            if isinstance(probe, (list, tuple)):
+                for p in probe:
+                    p.wait_to_read()
         self._named = self.block._ordered_params()
         from jax.sharding import NamedSharding
 
@@ -557,6 +571,7 @@ class DataParallelTrainer:
             scope.note(params=len(self._named))
         _built += 1
         self._serial = _built
+        _live[_built] = self
 
     def step(self, x, y):
         """One compiled SPMD step; returns scalar loss NDArray.
@@ -809,6 +824,18 @@ class DataParallelTrainer:
             z.close()
         self._params = tuple(new_params)
         self._states = tuple(new_states)
+
+    def aux_params(self):
+        """{name: numpy array} of the non-trainable parameters as the
+        newest step left them (BatchNorm's running statistics, a
+        decoder's routing log): the step's aux outputs, read on
+        demand.  The block's own Parameters are stale once the trainer
+        has taken them; these are the live values."""
+        if self._named is None:
+            return {}
+        return {name: np.asarray(jax.device_get(raw))
+                for (name, _), raw, tr in zip(self._named, self._params,
+                                              self._trainable) if not tr}
 
     def sync_to_block(self):
         """Write the trained params back into the block's Parameters."""

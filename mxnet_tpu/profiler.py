@@ -417,6 +417,34 @@ def _flash_attention_counters(reset=False):
     return stats
 
 
+def _moe_routing_counters(reset=False):
+    """Routing of the expert layers of every live trainer's decoder
+    model in its newest step (models/decoder_lm.py): rows each held
+    expert got, rows here, the share of all assignments that landed
+    here, max over mean.  Read from the trainers' routing logs on
+    demand; window-scoped like every section: after a reset dump a
+    trainer is back once it has taken a step; only present once the
+    model's module is loaded."""
+    import sys
+
+    lm = sys.modules.get(__package__ + ".models.decoder_lm")
+    if lm is None:
+        return None
+    stats = lm.moe_routing_stats(window=True)
+    if reset:
+        lm.reset_moe_routing_stats()
+    return stats
+
+
+def _moe_routing_table(stats):
+    out = ["MoE Routing (newest step, by expert layer):"]
+    for key in sorted(stats["rows_here"]):
+        out.append(f"  {key}: rows here {stats['rows_here'][key]}, share "
+                   f"{stats['share_here'][key]:.4f}, max/mean "
+                   f"{stats['max_over_mean'][key]:.3f}")
+    return out
+
+
 def _telemetry_counters(reset=False):
     """Telemetry-subsystem counters (spans/instants/requests recorded,
     drops, flight dumps, scrapes, aggregations) — window-scoped under
@@ -505,7 +533,8 @@ def _flash_attention_table(stats):
     out = ["Flash Attention (kernels built at trace time):"]
     for label, key in (("kernels", "kernels"),
                        ("resident (K/V in VMEM)", "resident"),
-                       ("streamed (K/V swept by the grid)", "streamed")):
+                       ("streamed (K/V swept by the grid)", "streamed"),
+                       ("grouped (shared K/V heads, window)", "grouped")):
         out.append(f"{label:<40}{stats[key]:>12}")
     for row in sorted(stats["built"]):
         out.append(f"  {row}  x{stats['built'][row]}")
@@ -554,6 +583,7 @@ register_section("dataParallelStep", _data_parallel_step_counters, _rows_table(
      ("bytes put", "put_bytes"))))
 register_section("flashAttention", _flash_attention_counters,
                  _flash_attention_table)
+register_section("moeRouting", _moe_routing_counters, _moe_routing_table)
 register_section("dataPipeline", _data_pipeline_counters, _rows_table(
     "Data Pipeline",
     (("batches delivered", "batches"),

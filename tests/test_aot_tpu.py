@@ -255,6 +255,71 @@ def test_flash_real_width_aot(one_chip, shape, dt):
         assert shapes[first] == want, (first, shapes[first], want)
 
 
+@pytest.mark.parametrize("dt", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("heads,window", [(48, None), (64, 512)],
+                         ids=["full-48", "window-64"])
+def test_grouped_flash_at_the_laguna_cells_shapes_aot(one_chip, heads,
+                                                      window, dt):
+    """The grouped kernels (8 K/V heads under 48 or 64 query heads, a
+    window of 512 beside full causal attention) at the shapes of the
+    `laguna_xs2.seq8k` cell, forward and both backward kernels; in
+    float32 too, which is what the trainer's eager probe of the model
+    runs (the XLA form's scores would be 26 GB there)."""
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((2, heads, 8192, 128), dt)
+    kv = jax.ShapeDtypeStruct((2, 8, 8192, 128), dt)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window) \
+            .astype(jnp.float32).sum()
+
+    jitted = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                     in_shardings=(one_chip,) * 3)
+    text = jitted.lower(q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+
+
+@pytest.mark.parametrize("rotated", [128, 64], ids=["whole", "half"])
+def test_rotary_embedding_float32_aot(one_chip, rotated):
+    """The rotary op in float32 at the cell's head shapes (what the
+    trainer's eager probe runs).  Written as a concatenate of two
+    64-wide float32 pieces it aborted the TPU compiler
+    (`IsFusibleUnalignedDUS`); the op is a reshape and a reverse."""
+    from mxnet_tpu.ops.nn import _k_rotary_embedding, rotary_frequencies
+
+    inv_freq, _ = rotary_frequencies(rotated, rope_theta=10000.0)
+    spec = jax.ShapeDtypeStruct((2, 8, 8192, 128), jnp.float32)
+
+    def loss(x):
+        return _k_rotary_embedding(x, inv_freq=inv_freq,
+                                   attention_factor=1.4).sum()
+
+    jax.jit(jax.value_and_grad(loss), in_shardings=(one_chip,)) \
+        .lower(spec).compile()
+
+
+def test_expert_layer_at_the_laguna_cells_shapes_aot(one_chip):
+    """`moe_ffn` at the cell's size: 16,384 tokens, a 256-wide router,
+    16 held experts of width 512, top-8: the grouped products compile
+    to the TPU's ragged-dot kernels, forward and backward."""
+    from mxnet_tpu.ops.moe import _k_moe_ffn
+
+    bf16 = jnp.bfloat16
+    specs = (jax.ShapeDtypeStruct((16384, 2048), bf16),
+             jax.ShapeDtypeStruct((2048, 256), bf16),
+             jax.ShapeDtypeStruct((16, 2048, 1024), bf16),
+             jax.ShapeDtypeStruct((16, 512, 2048), bf16))
+
+    def loss(x, router, w_in, w_out):
+        return _k_moe_ffn(x, router, w_in, w_out, first_expert=0, top_k=8,
+                          scale=2.5)[0].astype(jnp.float32).sum()
+
+    text = _aot_grad_compile(one_chip, loss, *specs)
+    assert "ragged-dot" in text or "ragged_dot" in text
+
+
 def _dispatch_loss(q, mask):
     from mxnet_tpu.ops.attention import _k_sdpa
 

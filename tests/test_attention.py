@@ -577,7 +577,8 @@ def test_flash_attention_profiler_section(interpret_pallas):
     assert "flashAttention" in profiler.section_names()
     profiler.sections(reset=True)
     assert profiler.sections()["flashAttention"] == {
-        "kernels": 0, "resident": 0, "streamed": 0, "built": {}}
+        "kernels": 0, "resident": 0, "streamed": 0, "grouped": 0,
+        "built": {}}
 
     q, k, v, mask, w = _grouped_case(2, 4, 128, 128, 64, "float32", True)
     step = jax.jit(jax.grad(
@@ -588,7 +589,8 @@ def test_flash_attention_profiler_section(interpret_pallas):
         f"resident {kernel} b2 h4 sq128 sk128 d64 float32 heads4 grid2x1": 1
         for kernel in ("fwd", "dq", "dkv")}
     assert profiler.sections()["flashAttention"] == {
-        "kernels": 3, "resident": 3, "streamed": 0, "built": rows}
+        "kernels": 3, "resident": 3, "streamed": 0, "grouped": 0,
+        "built": rows}
     assert fa._heads_per_step(4, 128, 128, 64, 4) == 4
 
     # a second program is traced (another batch): its kernels are rows
@@ -625,6 +627,165 @@ def test_flash_attention_section_counts_streamed(interpret_pallas,
     q, k, v, _, _ = _grouped_case(1, 2, 128, 256, 64, "float32", False)
     fa.flash_attention(q, k, v)
     assert profiler.sections()["flashAttention"] == {
-        "kernels": 1, "resident": 0, "streamed": 1, "built": {
+        "kernels": 1, "resident": 0, "streamed": 1, "grouped": 0, "built": {
             "streamed fwd b1 h2 sq128 sk256 d64 float32 heads1 "
             "grid2x1x2": 1}}
+
+
+# -- grouped variant: shared K/V heads and / or a sliding window -------------
+
+def _shared_kv_case(group, seq, d, dtype="float32", kv_heads=2, seed=5):
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.randn(1, kv_heads * group, seq, d), dtype)
+    k = jnp.asarray(rng.randn(1, kv_heads, seq, d), dtype)
+    v = jnp.asarray(rng.randn(1, kv_heads, seq, d), dtype)
+    w = jnp.asarray(rng.randn(1, kv_heads * group, seq, d), "float32")
+    return q, k, v, w
+
+
+def _windowed_out_and_grads(fn, q, k, v, w, window):
+    import jax
+    import jax.numpy as jnp
+
+    def loss(q, k, v):
+        out = fn(q, k, v, None, causal=True, window=window)
+        return (out.astype(jnp.float32) * w).sum(), out
+
+    (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                         has_aux=True)(q, k, v)
+    return (out,) + grads
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("group", [1, 6, 8])
+@pytest.mark.parametrize("window", [None, 128, 512])
+def test_flash_window_and_shared_kv_heads_match_reference(
+        window, group, d, interpret_pallas):
+    """Forward and the three gradients of the kernels against
+    `sdpa_reference` over window x query heads a K/V head x head size;
+    sequence 768 puts whole, edge and unseen k-blocks under every
+    window."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.attention import sdpa_reference
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+
+    fa.reset_flash_attention_stats()
+    q, k, v, w = _shared_kv_case(group, 768, d)
+    got = _windowed_out_and_grads(fa.flash_attention, q, k, v, w, window)
+    want = _windowed_out_and_grads(sdpa_reference, q, k, v, w, window)
+    for name, a, r in zip(("out", "dq", "dk", "dv"), got, want):
+        err = float(jnp.abs(a - r).max() / (jnp.abs(r).max() + 1e-9))
+        assert err < 2e-5, (name, err)
+    stats = fa.flash_attention_stats()
+    if window is None and group == 1:
+        assert stats["grouped"] == 0 and stats["resident"] == 3
+    else:
+        assert stats["grouped"] == 3 and stats["resident"] == 0
+        assert any(f"kv2 window{window or 0}" in row
+                   for row in stats["built"])
+
+
+def test_flash_shared_kv_heads_wide_k_blocks(interpret_pallas):
+    """Without a window the grouped forward walks k-blocks of 512 under
+    q-blocks of 128 (sequence a multiple of 512): the diagonal crosses
+    a k-block four q-blocks long."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.attention import sdpa_reference
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+
+    assert fa._GROUPED_BLOCK_K_FULL == 512
+    q, k, v, w = _shared_kv_case(3, 1024, 128, kv_heads=1)
+    got = _windowed_out_and_grads(fa.flash_attention, q, k, v, w, None)
+    want = _windowed_out_and_grads(sdpa_reference, q, k, v, w, None)
+    for name, a, r in zip(("out", "dq", "dk", "dv"), got, want):
+        err = float(jnp.abs(a - r).max() / (jnp.abs(r).max() + 1e-9))
+        assert err < 2e-5, (name, err)
+
+
+def test_no_window_and_equal_heads_trace_to_todays_kernels(
+        interpret_pallas):
+    """`window=None` with as many K/V heads as query heads is the
+    resident kernels' program of before, equation for equation."""
+    import jax
+
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+
+    q, k, v, _ = _shared_kv_case(1, 256, 64)
+
+    def today(q, k, v):
+        return fa._flash_sdpa(q, k, v, None, True, 0.125).sum()
+
+    def now(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, window=None).sum()
+
+    assert str(jax.make_jaxpr(jax.grad(now, argnums=(0, 1, 2)))(q, k, v)) \
+        == str(jax.make_jaxpr(jax.grad(today, argnums=(0, 1, 2)))(q, k, v))
+    fa.reset_flash_attention_stats()
+    jax.make_jaxpr(now)(q, k, v)
+    stats = fa.flash_attention_stats()
+    assert stats["resident"] == 1 and stats["grouped"] == 0
+
+
+def test_flash_window_bfloat16_and_op_dispatch(interpret_pallas,
+                                               monkeypatch):
+    """bf16 operands (the chip's): products in bf16, statistics and
+    accumulation in float32.  And the registry op: `window` reaches the
+    XLA form on the CPU, K/V heads are read from the shapes."""
+    import jax.numpy as jnp
+
+    import mxnet_tpu as mx
+    from mxnet_tpu.ops.attention import sdpa_reference
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+
+    q, k, v, w = _shared_kv_case(4, 384, 128, "bfloat16")
+    got = _windowed_out_and_grads(fa.flash_attention, q, k, v, w, 200)
+    f32 = [t.astype(jnp.float32) for t in (q, k, v)]
+    want = _windowed_out_and_grads(sdpa_reference, *f32, w, 200)
+    for name, a, r in zip(("out", "dq", "dk", "dv"), got, want):
+        err = float(jnp.abs(a.astype(jnp.float32) - r).max()
+                    / (jnp.abs(r).max() + 1e-9))
+        assert err < 2e-2, (name, err)
+
+    out = mx.nd.scaled_dot_product_attention(
+        mx.nd.array(np.asarray(f32[0])), mx.nd.array(np.asarray(f32[1])),
+        mx.nd.array(np.asarray(f32[2])), causal=True, window=200)
+    np.testing.assert_allclose(out.asnumpy(), np.asarray(want[0]),
+                               rtol=2e-5, atol=2e-5)
+    with pytest.raises(ValueError, match="window needs causal"):
+        sdpa_reference(*f32, window=8)
+    with pytest.raises(ValueError, match="window needs causal"):
+        fa.flash_attention(q, k, v, window=8)
+
+
+def test_sdpa_reference_window_and_groups_by_hand():
+    """The oracle itself, against the definition: position i sees j
+    with 0 <= i - j < window; query head j reads K/V head j // group."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.attention import sdpa_reference
+
+    q, k, v, _ = _shared_kv_case(3, 16, 8)
+    got = sdpa_reference(q, k, v, causal=True, window=5)
+    i, j = np.arange(16)[:, None], np.arange(16)[None, :]
+    seen = (j <= i) & (i - j < 5)
+    for head in range(6):
+        logits = np.asarray(q[0, head] @ k[0, head // 3].T) / np.sqrt(8)
+        probs = jax.nn.softmax(jnp.where(seen, logits, -np.inf), axis=-1)
+        np.testing.assert_allclose(np.asarray(got[0, head]),
+                                   np.asarray(probs @ v[0, head // 3]),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_grouped_gate_falls_to_the_xla_form_outside_its_shapes(
+        interpret_pallas):
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+
+    fa.reset_flash_attention_stats()
+    q, k, v, _ = _shared_kv_case(2, 100, 64)       # 100 does not tile
+    fa.flash_attention(q, k, v, causal=True, window=16)
+    assert fa.flash_attention_stats()["kernels"] == 0

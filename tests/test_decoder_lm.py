@@ -1,0 +1,352 @@
+"""The decoder LM (models/decoder_lm.py), its expert layer (ops/moe.py)
+and its small ops against the plain reference of the benchmark
+(benchmarks/reference/laguna_xs2.py, float32 jax.numpy, no mxnet_tpu),
+at a small size on the CPU with seeded weights: loss and every leaf's
+gradient through `DataParallelTrainer.step`, both layer kinds and both
+rotary forms; no assignment dropped whatever the imbalance; and the
+shares of an expert-parallel layer adding up to the uncut layer."""
+from __future__ import annotations
+
+import copy
+import importlib.util
+import os
+import sys
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "benchmarks")
+
+SMALL = dict(
+    vocab_size=128, hidden_size=64, head_dim=16, num_key_value_heads=2,
+    intermediate_size=128, moe_intermediate_size=32,
+    shared_expert_intermediate_size=32, num_experts=16, router_width=16,
+    first_expert=0, num_experts_per_tok=2, moe_routed_scaling_factor=2.5,
+    rms_norm_eps=1e-6, sliding_window=8, num_hidden_layers=3,
+    layer_types=["full_attention", "sliding_attention", "full_attention"],
+    num_attention_heads_per_layer=[12, 16, 12],
+    mlp_layer_types=["dense", "sparse", "sparse"],
+    rope_parameters={
+        "full_attention": dict(
+            rope_theta=500000, rope_type="yarn", factor=64,
+            original_max_position_embeddings=16, beta_slow=1, beta_fast=64,
+            attention_factor=1.4158883083359672, partial_rotary_factor=0.5),
+        "sliding_attention": dict(rope_type="default", rope_theta=10000,
+                                  partial_rotary_factor=1)},
+    assumed={"init_stdev": 0.05,
+             "optimizer": {"name": "adamw", "learning_rate": 1e-3,
+                           "wd": 0.01, "beta1": 0.9, "beta2": 0.999,
+                           "epsilon": 1e-8}})
+
+
+@pytest.fixture(scope="module")
+def reference():
+    if BENCH not in sys.path:
+        sys.path.insert(0, BENCH)
+    spec = importlib.util.spec_from_file_location(
+        "reference_laguna_xs2",
+        os.path.join(BENCH, "reference", "laguna_xs2.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def _weights(reference, config, seed=3):
+    from harness import weights
+
+    return weights.make(seed, reference.param_specs(config))
+
+
+def _batch(config, rows=4, seq=32, seed=0):
+    tokens = np.random.RandomState(seed).randint(
+        0, config["vocab_size"], (rows, seq + 1)).astype(np.int32)
+    return tokens[:, :-1], tokens[:, 1:]
+
+
+def _one_device_mesh():
+    """The cell's mesh: one chip (the suite has 8 virtual devices)."""
+    import jax
+
+    from mxnet_tpu.parallel import mesh
+
+    return mesh.make_mesh(devices=jax.devices()[:1])
+
+
+def _filled(config, arrays):
+    import mxnet_tpu as mx
+    from mxnet_tpu.models import decoder_lm
+    from mxnet_tpu.ndarray.ndarray import NDArray
+
+    net = decoder_lm.DecoderLM(config)
+    net.initialize(mx.init.Zero())
+    for (_, p), a in zip(net._ordered_params(), arrays):
+        assert tuple(p.shape) == tuple(a.shape)
+        p.set_data(NDArray(a))
+    return net
+
+
+@pytest.mark.parametrize("router_width,first", [(16, 0), (64, 16)],
+                         ids=["whole", "share"])
+def test_model_matches_reference_loss_and_every_gradient(
+        reference, router_width, first):
+    import jax
+
+    from mxnet_tpu.parallel import data_parallel
+
+    config = copy.deepcopy(SMALL)
+    config.update(router_width=router_width, first_expert=first)
+    params0 = _weights(reference, config)
+    ids, labels = _batch(config)
+    (want_loss, want_rows), want = jax.value_and_grad(
+        reference.loss_and_routing, has_aux=True)(
+            list(params0), ids, labels, config=config, precision="float32")
+
+    net = _filled(config, params0)
+    # plain SGD at rate 1 without decay: the step's change IS the
+    # gradient, through the entry point the benchmark drives
+    trainer = data_parallel.DataParallelTrainer(
+        net, lambda out, _: out, "sgd", {"learning_rate": 1.0, "wd": 0.0},
+        mesh=_one_device_mesh(), remat=True)
+    loss = float(trainer.step((ids, labels),
+                              np.zeros((len(ids),), np.float32)).asnumpy())
+    assert abs(loss - float(want_loss)) < 1e-5 * abs(float(want_loss))
+    names = [name for name, _ in net._ordered_params()]
+    assert len(names) == len(want) == len(reference.leaf_parts(config))
+    scale = np.median([float(np.abs(np.asarray(g)).max()) for g in want[1:]])
+    for name, p0, p1, g in zip(names[1:], params0[1:], trainer._params[1:],
+                               want[1:]):
+        got = np.asarray(p0) - np.asarray(p1)
+        err = np.abs(got - np.asarray(g)).max()
+        assert err < 2e-4 * max(np.abs(np.asarray(g)).max(), scale), name
+    # the routing log: the rows each held expert got, layer by layer
+    np.testing.assert_array_equal(np.asarray(trainer._params[0]),
+                                  np.asarray(want_rows))
+    # the block's own log is stale under a trainer: the live values
+    assert net.routing_rows()[2].sum() == 0
+    rows = net.routing_rows(trainer.aux_params()[net.routing_log.name])
+    assert sorted(rows) == [1, 2]
+    np.testing.assert_array_equal(rows[2], np.asarray(want_rows)[1])
+    assert float(np.asarray(want_rows)[0].sum()) == ids.size * 2
+
+
+def test_trainer_in_bfloat16_with_remat_learns(reference):
+    from mxnet_tpu.parallel import data_parallel
+
+    config = copy.deepcopy(SMALL)
+    net = _filled(config, _weights(reference, config))
+    trainer = data_parallel.DataParallelTrainer(
+        net, lambda out, _: out, "adamw",
+        {"learning_rate": 1e-2, "wd": 0.01}, mesh=_one_device_mesh(),
+        compute_dtype="bfloat16", remat=True)
+    x, y = _batch(config), np.zeros((4,), np.float32)
+    losses = [float(trainer.step(x, y).asnumpy()) for _ in range(6)]
+    assert np.isfinite(losses).all() and losses[-1] < losses[0] - 0.05
+
+
+def _moe_inputs(tokens=48, h=64, width=32, router_width=16, seed=0):
+    rng = np.random.RandomState(seed)
+    import jax.numpy as jnp
+
+    return (jnp.asarray(rng.randn(tokens, h), jnp.float32),
+            jnp.asarray(rng.randn(h, router_width) * 0.3, jnp.float32),
+            jnp.asarray(rng.randn(router_width, h, 2 * width) * 0.1,
+                        jnp.float32),
+            jnp.asarray(rng.randn(router_width, width, h) * 0.1,
+                        jnp.float32))
+
+
+def _dense_layer(x, router, w_in, w_out, top_k, scale):
+    """The uncut layer, expert by expert with masks."""
+    import jax
+    import jax.numpy as jnp
+
+    probs = jax.nn.softmax(jnp.matmul(x, router, precision="highest"), -1)
+    top, experts = jax.lax.top_k(probs, top_k)
+    weights = top / top.sum(-1, keepdims=True) * scale
+    out = jnp.zeros_like(x)
+    for e in range(router.shape[1]):
+        gate, up = jnp.split(
+            jnp.matmul(x, w_in[e], precision="highest"), 2, axis=-1)
+        y = jnp.matmul(jax.nn.silu(gate) * up, w_out[e], precision="highest")
+        out += jnp.where(experts == e, weights, 0).sum(-1, keepdims=True) * y
+    return out
+
+
+def test_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
+    """4 shares of a 16-wide layer: each routes over all 16, computes
+    its own 4 experts' part; the parts add up to the whole layer."""
+    from mxnet_tpu.ops.moe import _k_moe_ffn
+
+    x, router, w_in, w_out = _moe_inputs()
+    parts, rows = [], []
+    for first in range(0, 16, 4):
+        y, log = _k_moe_ffn(x, router, w_in[first:first + 4],
+                            w_out[first:first + 4], first_expert=first,
+                            top_k=2, scale=2.5)
+        parts.append(np.asarray(y))
+        rows.append(np.asarray(log))
+        assert log[:4].sum() + log[4] == x.shape[0] * 2
+    want = np.asarray(_dense_layer(x, router, w_in, w_out, 2, 2.5))
+    np.testing.assert_allclose(sum(parts), want, rtol=2e-5, atol=2e-6)
+    assert sum(r[:4].sum() for r in rows) == x.shape[0] * 2
+    # no share is the whole: each leaves the others' part out
+    assert all(np.abs(p - want).max() > 1e-3 for p in parts)
+
+
+def test_every_assignment_is_computed_when_all_tokens_pick_one_expert():
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.moe import _k_moe_ffn
+
+    x, router, w_in, w_out = _moe_inputs()
+    x = jnp.abs(x)
+    # expert 5 wins every token by a wide margin, expert 6 comes second
+    router = jnp.zeros_like(router).at[:, 5].set(1.0).at[:, 6].set(0.5)
+
+    def loss(x, w_in, w_out, fn):
+        return (fn(x, w_in, w_out) ** 2).sum()
+
+    held = _experts_4_to_7(_k_moe_ffn, router)
+    whole = lambda x, w_in, w_out: _dense_layer(  # noqa: E731
+        x, router, w_in, w_out, 2, 2.5)
+    y, log = _k_moe_ffn(x, router, w_in[4:8], w_out[4:8], first_expert=4,
+                        top_k=2, scale=2.5)
+    np.testing.assert_array_equal(np.asarray(log),
+                                  [0, x.shape[0], x.shape[0], 0, 0])
+    np.testing.assert_allclose(np.asarray(y), np.asarray(whole(x, w_in,
+                                                               w_out)),
+                               rtol=2e-5, atol=2e-6)
+    got = jax.grad(loss, argnums=(0, 1, 2))(x, w_in, w_out, held)
+    want = jax.grad(loss, argnums=(0, 1, 2))(x, w_in, w_out, whole)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5)
+
+
+def _experts_4_to_7(op, router):
+    """The op over experts 4-7 with full-size expert operands (so its
+    gradients have the uncut layer's shapes)."""
+    def fn(x, w_in, w_out):
+        return op(x, router, w_in[4:8], w_out[4:8], first_expert=4,
+                  top_k=2, scale=2.5)[0]
+    return fn
+
+
+def test_moe_gradients_match_the_dense_form_on_an_uneven_routing():
+    import jax
+
+    from mxnet_tpu.ops.moe import _k_moe_ffn
+
+    x, router, w_in, w_out = _moe_inputs(seed=4)
+
+    def op_loss(x, router, w_in, w_out):
+        return (_k_moe_ffn(x, router, w_in, w_out, first_expert=0, top_k=2,
+                           scale=2.5)[0] ** 2).sum()
+
+    def dense_loss(x, router, w_in, w_out):
+        return (_dense_layer(x, router, w_in, w_out, 2, 2.5) ** 2).sum()
+
+    got = jax.grad(op_loss, argnums=(0, 1, 2, 3))(x, router, w_in, w_out)
+    want = jax.grad(dense_loss, argnums=(0, 1, 2, 3))(x, router, w_in, w_out)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
+                                   atol=2e-5)
+
+
+@pytest.mark.parametrize("kind", ["full_attention", "sliding_attention"])
+def test_rotary_embedding_matches_reference_tables(reference, kind):
+    import jax.numpy as jnp
+
+    import mxnet_tpu as mx
+    from mxnet_tpu.ops.nn import rotary_frequencies
+
+    config = SMALL
+    rope = dict(config["rope_parameters"][kind])
+    r = int(config["head_dim"] * rope.pop("partial_rotary_factor"))
+    inv_freq, factor = rotary_frequencies(r, **rope)
+    x = np.random.RandomState(1).randn(2, 3, 32, 16).astype(np.float32)
+    got = mx.nd.rotary_embedding(mx.nd.array(x), inv_freq=inv_freq,
+                                 attention_factor=factor).asnumpy()
+    cos, sin, r_ref = reference.rotary_tables(config, kind, 32)
+    assert r_ref == r == (8 if kind == "full_attention" else 16)
+    want = np.asarray(reference._rotate(jnp.asarray(x), cos, sin, r))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(got[..., r:], x[..., r:])
+    if kind == "full_attention":
+        # YaRN: every frequency between the interpolated and the plain
+        plain = np.asarray(rotary_frequencies(r, rope_theta=500000)[0])
+        assert factor == pytest.approx(1.4158883083359672)
+        assert (np.asarray(inv_freq) <= plain + 1e-12).all()
+        assert (np.asarray(inv_freq) >= plain / 64 - 1e-12).all()
+
+
+def test_rms_norm_and_swiglu_ops():
+    import jax
+
+    import mxnet_tpu as mx
+
+    rng = np.random.RandomState(2)
+    x = rng.randn(3, 5, 64).astype(np.float32)
+    g = rng.rand(64).astype(np.float32) + 0.5
+    got = mx.nd.rms_norm(mx.nd.array(x), mx.nd.array(g), eps=1e-6).asnumpy()
+    want = x / np.sqrt((x ** 2).mean(-1, keepdims=True) + 1e-6) * g
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    half = x[..., :32]
+    want = np.asarray(jax.nn.silu(half)) * x[..., 32:]
+    np.testing.assert_allclose(mx.nd.swiglu(mx.nd.array(x)).asnumpy(), want,
+                               rtol=1e-5, atol=1e-6)
+    # differentiable through autograd
+    a = mx.nd.array(x)
+    a.attach_grad()
+    with mx.autograd.record():
+        y = mx.nd.rms_norm(a, mx.nd.array(g)).sum()
+    y.backward()
+    assert np.isfinite(a.grad.asnumpy()).all()
+
+
+def test_moe_routing_section_is_on_metrics(reference):
+    from mxnet_tpu import profiler
+    from mxnet_tpu.models import decoder_lm
+    from mxnet_tpu.parallel import data_parallel
+    from mxnet_tpu.telemetry import metrics
+
+    config = copy.deepcopy(SMALL)
+    net = _filled(config, _weights(reference, config))
+    trainer = data_parallel.DataParallelTrainer(
+        net, lambda out, _: out, "sgd", {"learning_rate": 0.1, "wd": 0.0},
+        mesh=_one_device_mesh())
+    assert trainer not in data_parallel.live_trainers()   # not built yet
+    ids, labels = _batch(config)
+    trainer.step((ids, labels), np.zeros((len(ids),), np.float32))
+    assert data_parallel.live_trainers()[-1] is trainer
+    assert "moeRouting" in profiler.section_names()
+    stats = decoder_lm.moe_routing_stats()
+    mine = [f"trainer{trainer._serial}.layer{l}" for l in (1, 2)]
+    assert set(mine) <= set(stats["rows_here"]) and stats["layers"] >= 2
+    # SMALL holds all its experts: every assignment lands here
+    assert stats["rows_here"][mine[0]] == ids.size * 2
+    assert stats["share_here"][mine[0]] == 1.0
+    assert stats["max_over_mean"][mine[0]] >= 1.0
+    assert sum(stats["rows_per_expert"][f"{mine[0]}.expert{e}"]
+               for e in range(16)) == ids.size * 2
+    assert list(decoder_lm.moe_routing_stats(newest=True)["rows_here"]) \
+        == mine
+    assert profiler.sections()["moeRouting"] == stats
+    text = metrics.default_registry().render()
+    assert "mxtpu_moe_routing_rows_here{key=" in text
+    assert "MoE Routing" in "\n".join(profiler._section_tables())
+    # window-scoped like every section: after a reset dump the trainer
+    # is back with its next step; the accessor itself takes no window
+    assert profiler.sections(reset=True)["moeRouting"] == stats
+    assert profiler.sections()["moeRouting"]["layers"] == 0
+    assert decoder_lm.moe_routing_stats() == stats
+    trainer.step((ids, labels), np.zeros((len(ids),), np.float32))
+    assert set(mine) <= set(profiler.sections()["moeRouting"]["rows_here"])
+    # and the section loses the trainer with the trainer's life
+    del trainer
+    import gc
+
+    gc.collect()
+    assert not set(mine) & set(decoder_lm.moe_routing_stats()["rows_here"])
