@@ -17,7 +17,13 @@ here, ids `first_expert ...`, under a router of `router_width` outputs.
 BERT's pre-training block does: `DataParallelTrainer(net, lambda out,
 _: out, "adamw", ..., compute_dtype="bfloat16", remat=True)`; every
 decoder layer and the head are direct children, which is what the
-trainer's `remat` recomputes one at a time.  The rows each held expert
+trainer's `remat` recomputes one at a time.  RECOMPUTED in the backward
+pass: a layer's norms, projections, rotary embedding, gate and its
+feed-forward or expert layer.  KEPT: the layer's input, and the flash
+attention kernel's output and row statistic (`flash_attention.
+RESIDUAL_NAMES`), b*s*heads*head_dim x itemsize + 4*b*heads*s bytes a
+layer (204 MB at 2 x 8,192 tokens, 48 heads of 128 in bf16), so the
+forward kernel runs once a layer, not twice.  The rows each held expert
 got in the newest step are in `routing_log`, a non-trainable parameter
 (`routing_rows()` reads it; the profiler section `moeRouting` reads the
 live trainers' copies).

@@ -37,8 +37,18 @@ heads that read one K/V head are one tile) and a sliding window
 (k-blocks outside it are never touched); `window=None` with equal head
 counts never reaches them.  They are described where they stand.
 
-Every kernel built while a program is traced is counted
-(`flash_attention_stats`, the profiler section `flashAttention`).
+The fwd rules of both `custom_vjp`s NAME the two values the backward
+kernels need beyond the layer's own q, k, v: the output (`flash_out`,
+b*h*s*d in the compute dtype) and the row statistic (`flash_lse`,
+float32 b*h*s); `RESIDUAL_NAMES` is the pair.  A name is an identity
+outside `jax.checkpoint`; inside one whose policy is
+`save_only_these_names(*RESIDUAL_NAMES)` (`DataParallelTrainer(remat=
+True)`) the pair is kept and the backward pass recomputes the layer
+WITHOUT running the forward kernel a second time.
+
+Every kernel built while a program is traced is counted, and every
+pair of residuals named (`flash_attention_stats`, the profiler section
+`flashAttention`).
 
 Falls back transparently when seq/head dims don't tile (caller guards).
 """
@@ -50,6 +60,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -62,6 +73,10 @@ _LANES = 128
 # quarter of the 16 MB a kernel may use on a v5e-class core, which
 # leaves the rest to the float32 intermediates of the unrolled heads
 _GROUP_VMEM_BYTES = 4 * 2 ** 20
+# the names the fwd rules give the kernel's output and its row statistic
+# (`_name_residuals`); a `jax.checkpoint` policy that saves them keeps
+# the forward kernel out of the recomputation
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
 
 
 # every kernel built while a program was traced: (variant, kernel, (b, h,
@@ -81,33 +96,65 @@ def _record_built(variant, kernel, q, sk, heads, grid, kv_heads=None,
             tuple(grid), kv_heads or h, window)] += 1
 
 
+# every (out, lse) pair a fwd rule named while a program was traced:
+# (variant, (b, h, sq, sk, d), dtype, kv heads, window) -> [pairs, bytes]
+_named = collections.defaultdict(lambda: [0, 0])
+
+
+def _name_residuals(variant, q, k, window, out, lse):
+    """`out` and `lse` under `RESIDUAL_NAMES`, the pair and its bytes
+    (by the avals) counted for the `flashAttention` section."""
+    b, h, sq, d = q.shape
+    row = _named[(variant, (b, h, sq, k.shape[2], d), q.dtype.name,
+                  k.shape[1], window)]
+    row[0] += 1
+    row[1] += sum(x.size * x.dtype.itemsize for x in (out, lse))
+    return tuple(checkpoint_name(x, name)
+                 for x, name in zip((out, lse), RESIDUAL_NAMES))
+
+
+def _dims(variant, shape, kv_heads, window):
+    dims = " ".join(f"{k}{v}" for k, v in zip(
+        ("b", "h", "sq", "sk", "d"), shape))
+    if variant == "grouped":
+        dims += f" kv{kv_heads} window{window or 0}"
+    return dims
+
+
 def flash_attention_stats():
     """The `flashAttention` profiler section: how the kernels engaged
     in the programs traced since the last reset.  `built` has a row for
     each distinct kernel, named by its variant (resident / streamed /
     grouped), which of the three it is, its shapes (a grouped kernel's
     with its K/V head count and its window, 0 for none), the heads a
-    grid step works on and the grid."""
+    grid step works on and the grid.  `residual_pairs` and
+    `residual_bytes` have a row for each variant and shape whose fwd
+    rule named its (out, lse) pair under `RESIDUAL_NAMES`: how many
+    pairs, and the bytes a `jax.checkpoint` that saves them keeps."""
     built = {}
     for (variant, kernel, shape, dtype, heads, grid, kv_heads,
          window), n in _built.items():
-        dims = " ".join(f"{k}{v}" for k, v in zip(
-            ("b", "h", "sq", "sk", "d"), shape))
-        if variant == "grouped":
-            dims += f" kv{kv_heads} window{window or 0}"
-        built[f"{variant} {kernel} {dims} {dtype} heads{heads} "
-              f"grid{'x'.join(map(str, grid))}"] = n
+        built[f"{variant} {kernel} {_dims(variant, shape, kv_heads, window)}"
+              f" {dtype} heads{heads} grid{'x'.join(map(str, grid))}"] = n
+    pairs, nbytes = {}, {}
+    for (variant, shape, dtype, kv_heads, window), (n, size) \
+            in _named.items():
+        row = f"{variant} {_dims(variant, shape, kv_heads, window)} {dtype}"
+        pairs[row], nbytes[row] = n, size
 
     def count(variant):
         return sum(n for key, n in _built.items() if key[0] == variant)
 
     return {"kernels": sum(_built.values()),
             "resident": count("resident"), "streamed": count("streamed"),
-            "grouped": count("grouped"), "built": built}
+            "grouped": count("grouped"), "built": built,
+            "residuals_named": sum(pairs.values()),
+            "residual_pairs": pairs, "residual_bytes": nbytes}
 
 
 def reset_flash_attention_stats():
     _built.clear()
+    _named.clear()
 
 
 def _heads_per_step(h, sq, sk, d, itemsize, block=128):
@@ -1083,8 +1130,8 @@ def _grouped_sdpa(q, k, v, causal, window, scale):
 
 
 def _grouped_sdpa_fwd(q, k, v, causal, window, scale):
-    out, lse = _grouped_forward(q, k, v, causal=causal, window=window,
-                                scale=scale)
+    out, lse = _name_residuals("grouped", q, k, window, *_grouped_forward(
+        q, k, v, causal=causal, window=window, scale=scale))
     return out, (q, k, v, out, lse)
 
 
@@ -1151,7 +1198,9 @@ def _flash_sdpa(q, k, v, km, causal, scale):
 
 def _flash_sdpa_fwd(q, k, v, km, causal, scale):
     fwd = _fwd_dispatch(q, k)
-    out, lse = fwd(q, k, v, causal=causal, scale=scale, kmask=km)
+    out, lse = _name_residuals(
+        "resident" if fwd is _flash_forward else "streamed", q, k, None,
+        *fwd(q, k, v, causal=causal, scale=scale, kmask=km))
     return out, (q, k, v, km, out, lse)
 
 

@@ -26,6 +26,7 @@ phase (docs/observability.md, "Device trace").
 from __future__ import annotations
 
 import collections
+import functools
 import time
 import weakref
 
@@ -47,7 +48,10 @@ from . import mesh as mesh_mod
 # and an append, and the readers run where nothing was armed beforehand.
 _step_log = collections.deque(maxlen=4096)
 _built = 0          # trainers built so far; the newest one's serial
-_window = (0, 0)    # the clock and `_built` when the section's window opened
+# [children wrapped in a jax.checkpoint, trainers built with remat=True]
+_remat_built = [0, 0]
+# the clock, `_built` and `_remat_built` when the section's window opened
+_window = (0, 0, (0, 0))
 _live = weakref.WeakValueDictionary()   # serial -> trainer, while it lives
 
 
@@ -68,21 +72,47 @@ def live_trainers():
 def data_parallel_step_stats():
     """The `dataParallelStep` profiler section: the step log's records
     since the window opened, summed.  A window of more steps than the
-    log keeps reports the newest 4096 of them."""
-    opened_ns, built_then = _window
+    log keeps reports the newest 4096 of them.  `remat_children` counts
+    the child blocks the trainers built in the window wrapped in a
+    `jax.checkpoint`; `remat_saves` names what that checkpoint's policy
+    keeps, each name with the number of those trainers."""
+    opened_ns, built_then, (children_then, remat_then) = _window
     records = [r for r in step_log() if r[2] >= opened_ns]
 
     def ms(field):
         return round(sum(r[field] for r in records) / 1e6, 3)
 
+    trainers = _remat_built[1] - remat_then
     return {"steps": len(records), "builds": _built - built_then,
             "put_ms": ms(3), "args_ms": ms(4), "enqueue_ms": ms(5),
-            "put_bytes": sum(r[6] for r in records)}
+            "put_bytes": sum(r[6] for r in records),
+            "remat_children": _remat_built[0] - children_then,
+            "remat_saves": dict.fromkeys(_remat_saves(), trainers)
+            if trainers else {}}
 
 
 def reset_data_parallel_step_stats():
     global _window
-    _window = (time.perf_counter_ns(), _built)
+    _window = (time.perf_counter_ns(), _built, tuple(_remat_built))
+
+
+def _remat_saves():
+    """The names `remat=True` keeps across a checkpoint: the flash
+    kernels' output and row statistic, as their fwd rules name them."""
+    from ..ops.pallas.flash_attention import RESIDUAL_NAMES
+
+    return RESIDUAL_NAMES
+
+
+@functools.cache
+def _remat_policy():
+    """ONE policy object a process: JAX caches a checkpoint's partial
+    evaluation by the policy's identity, and `save_only_these_names`
+    makes a new closure a call.  A policy a layer would give every
+    layer jaxprs of its own: nothing shared in the lowered module, and
+    the TPU compiler then names the grouped products' kernels without
+    the phase prefix the device trace's readers go by."""
+    return jax.checkpoint_policies.save_only_these_names(*_remat_saves())
 
 
 class DataParallelTrainer:
@@ -138,8 +168,20 @@ class DataParallelTrainer:
         # (a single outer checkpoint would recompute everything and
         # still materialize every residual at once — no peak-HBM win);
         # children holding aux-mutating params (BatchNorm moving stats)
-        # stay exact.  Trades ~1/3 more FLOPs for ~O(depth) less HBM
-        # (the reference's closest analogue is mirror/memonger).
+        # stay exact.  RECOMPUTED: everything of a child but what the
+        # flash attention kernels name (projections, rotary, gates,
+        # norms, feed-forward and expert layers; the kernels' q, k, v
+        # come from the recomputed projections).  KEPT, besides the
+        # child's inputs: a flash kernel's output and its row statistic
+        # (`flash_attention.RESIDUAL_NAMES`), b*s*h*d x itemsize +
+        # 4*b*h*s bytes an attention: the backward kernels read both
+        # whether kept or recomputed, so keeping them costs capacity
+        # and no traffic, and the forward kernel runs once.  Lowered for
+        # a CPU the names sit in the dispatch's dropped TPU branch, and
+        # the XLA form of attention is recomputed whole.
+        # Trades ~1/3 more FLOPs, less the attention forward's, for
+        # ~O(depth) less HBM (the reference's closest analogue is
+        # mirror/memonger).
         self._remat = bool(remat)
         self._step_fn = None
         self._many_fns = {}
@@ -358,10 +400,14 @@ class DataParallelTrainer:
             return raw - lr * upd, (nm, nv)
 
         loss_fn_for_grad = forward_loss
-        if self._remat and not self._apply_child_remat():
-            # no wrappable children (flat model): checkpoint the whole
-            # forward — full recompute, saves only the head residuals
-            loss_fn_for_grad = jax.checkpoint(forward_loss)
+        if self._remat:
+            _remat_built[1] += 1
+            if not self._apply_child_remat():
+                # no wrappable children (flat model): checkpoint the
+                # whole forward — full recompute but for what the
+                # policy keeps, saves only the head residuals
+                loss_fn_for_grad = jax.checkpoint(forward_loss,
+                                                  policy=_remat_policy())
 
         accum = self._accum
 
@@ -525,6 +571,7 @@ class DataParallelTrainer:
                 continue
             child.forward = self._make_remat_forward(child.forward)
             self._remat_count += 1
+        _remat_built[0] += self._remat_count
         return self._remat_count
 
     @staticmethod
@@ -539,7 +586,8 @@ class DataParallelTrainer:
                     return tuple(o._data for o in outs)
                 return (outs._data,)
 
-            outs = jax.checkpoint(pure)(*[a._data for a in args])
+            outs = jax.checkpoint(pure, policy=_remat_policy())(
+                *[a._data for a in args])
             wrapped = [_wrap(o) for o in outs]
             return wrapped[0] if len(wrapped) == 1 else tuple(wrapped)
 

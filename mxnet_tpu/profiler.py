@@ -386,7 +386,8 @@ def _tune_counters(reset=False):
 
 def _data_parallel_step_counters(reset=False):
     """`parallel.DataParallelTrainer` host-side step split (steps,
-    builds, put/args/enqueue ms, bytes put), summed from its step log
+    builds, put/args/enqueue ms, bytes put), summed from its step log,
+    and what `remat=True` wrapped and keeps in the trainers built
     -- window-scoped under reset=True exactly like every other section;
     only present when the data-parallel tier is loaded."""
     import sys
@@ -404,8 +405,10 @@ def _flash_attention_counters(reset=False):
     """How the flash-attention kernels engaged in the programs traced
     in the window: kernels built, resident / streamed, and a row for
     each distinct kernel with its shapes, the heads a grid step works
-    on and the grid.  Counted when a program is traced, never when it
-    runs; only present once the kernels' module is loaded."""
+    on and the grid; and the (out, lse) pairs their fwd rules named for
+    a `jax.checkpoint` to keep, with their bytes.  Counted when a
+    program is traced, never when it runs; only present once the
+    kernels' module is loaded."""
     import sys
 
     fa = sys.modules.get(__package__ + ".ops.pallas.flash_attention")
@@ -538,6 +541,30 @@ def _flash_attention_table(stats):
         out.append(f"{label:<40}{stats[key]:>12}")
     for row in sorted(stats["built"]):
         out.append(f"  {row}  x{stats['built'][row]}")
+    out.append(f"{'(out, lse) pairs named for remat':<40}"
+               f"{stats['residuals_named']:>12}")
+    for row in sorted(stats["residual_pairs"]):
+        out.append(f"  named {row}  x{stats['residual_pairs'][row]}  "
+                   f"{stats['residual_bytes'][row]} bytes")
+    return out
+
+
+_data_parallel_step_rows = _rows_table(
+    "Data-Parallel Step (host side)",
+    (("steps", "steps"),
+     ("trainers built", "builds"),
+     ("batch put (ms)", "put_ms"),
+     ("key and scalars (ms)", "args_ms"),
+     ("step enqueue (ms)", "enqueue_ms"),
+     ("bytes put", "put_bytes"),
+     ("remat: children checkpointed", "remat_children")))
+
+
+def _data_parallel_step_table(stats):
+    out = _data_parallel_step_rows(stats)
+    for name in sorted(stats["remat_saves"]):
+        out.append(f"{'remat keeps[' + name + '] (trainers)':<40}"
+                   f"{stats['remat_saves'][name]:>12}")
     return out
 
 
@@ -573,14 +600,8 @@ register_section("trainerStep", _trainer_step_counters, _rows_table(
      ("zero-sharded steps", "zero_steps"),
      ("zero-shard fallbacks", "zero_fallbacks"),
      ("spmd mesh steps", "spmd_steps"))))
-register_section("dataParallelStep", _data_parallel_step_counters, _rows_table(
-    "Data-Parallel Step (host side)",
-    (("steps", "steps"),
-     ("trainers built", "builds"),
-     ("batch put (ms)", "put_ms"),
-     ("key and scalars (ms)", "args_ms"),
-     ("step enqueue (ms)", "enqueue_ms"),
-     ("bytes put", "put_bytes"))))
+register_section("dataParallelStep", _data_parallel_step_counters,
+                 _data_parallel_step_table)
 register_section("flashAttention", _flash_attention_counters,
                  _flash_attention_table)
 register_section("moeRouting", _moe_routing_counters, _moe_routing_table)

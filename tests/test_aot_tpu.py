@@ -281,6 +281,102 @@ def test_grouped_flash_at_the_laguna_cells_shapes_aot(one_chip, heads,
     assert text.count("tpu_custom_call") == 3
 
 
+def _decoder_step_aot(topo, monkeypatch, layer_types, heads, seq, chips,
+                      sparse=False):
+    """A `DecoderLM` of laguna's head counts (8 K/V heads of 128) at a
+    small width and a short sequence, stepped by
+    `DataParallelTrainer(remat=True)` in bf16, two sequences a chip:
+    the whole step compiled for the described v5e chips.  Nothing is put
+    on a device: the
+    trainer's `global_put` hands back shapes (and plain SGD has no
+    state to make there)."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.models import decoder_lm
+    from mxnet_tpu.parallel import data_parallel, mesh as mesh_mod
+
+    rope = dict(rope_type="default", rope_theta=10000,
+                partial_rotary_factor=1)
+    config = dict(
+        vocab_size=1024, hidden_size=256, head_dim=128,
+        num_key_value_heads=8, intermediate_size=512, sliding_window=256,
+        num_hidden_layers=len(layer_types), layer_types=layer_types,
+        num_attention_heads_per_layer=heads,
+        mlp_layer_types=["sparse" if sparse else "dense"] * len(layer_types),
+        moe_intermediate_size=128, shared_expert_intermediate_size=128,
+        num_experts=4, router_width=16, num_experts_per_tok=2,
+        rope_parameters={"full_attention": rope, "sliding_attention": rope})
+    net = decoder_lm.DecoderLM(config)
+    net.initialize(mx.init.Zero())
+    monkeypatch.setattr(
+        mesh_mod, "global_put", lambda value, sharding: jax.ShapeDtypeStruct(
+            value.shape, value.dtype, sharding=sharding))
+    trainer = data_parallel.DataParallelTrainer(
+        net, lambda out, _: out, "sgd", {"learning_rate": 1e-3},
+        mesh=Mesh(np.array(topo.devices[:chips]), ("dp",)),
+        compute_dtype="bfloat16", remat=True)
+    ids = np.zeros((2 * chips, seq), np.int32)
+    trainer.build((ids, ids))
+    scalar = jax.ShapeDtypeStruct((), jnp.float32)
+    return trainer._step_fn.lower(
+        trainer._params, trainer._states, (ids, ids),
+        np.zeros((len(ids),), np.float32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32), scalar, scalar).compile()
+
+
+@pytest.mark.parametrize("layer_types,heads,chips", [
+    (["full_attention"] * 2, [48, 48], 1),
+    (["sliding_attention"] * 2, [64, 64], 1),
+    (["full_attention", "sliding_attention"], [48, 64], 1),
+    (["full_attention", "sliding_attention"], [48, 64], 4),
+], ids=["full", "window", "mixed", "mixed-dp4"])
+def test_remat_step_runs_the_forward_kernel_once_a_layer_aot(
+        topo, monkeypatch, layer_types, heads, chips):
+    """`DataParallelTrainer(remat=True)` keeps the flash kernels' named
+    output and row statistic: the compiled step holds three attention
+    kernels a layer (forward, dQ, dK/dV), four under a bare
+    `jax.checkpoint`, whatever the layer's kind, and on four chips too,
+    where the kernels and their names sit inside `per_batch_shard`'s
+    `shard_map`; and a chip's temporaries grow by no more than the
+    bytes named there, plus a tenth."""
+    from mxnet_tpu.parallel import data_parallel
+
+    seq = 512
+    args = topo, monkeypatch, layer_types, heads, seq, chips
+    kept = _decoder_step_aot(*args)
+    monkeypatch.setattr(data_parallel, "_remat_policy", lambda: None)
+    bare = _decoder_step_aot(*args)
+    assert kept.as_text().count("tpu_custom_call") == 3 * len(heads)
+    assert bare.as_text().count("tpu_custom_call") == 4 * len(heads)
+    # a layer keeps b*s*h*d x itemsize + 4*b*h*s bytes
+    named = sum(2 * seq * h * (128 * 2 + 4) for h in heads)
+    grown = kept.memory_analysis().temp_size_in_bytes \
+        - bare.memory_analysis().temp_size_in_bytes
+    assert 0 < grown <= 1.1 * named, (grown, named)
+
+
+def test_remat_step_names_the_grouped_products_by_phase_aot(topo,
+                                                           monkeypatch):
+    """The TPU compiler names the expert layer's grouped products
+    `<the call's op_name>/ragged-dot-...` only where the function that
+    holds them is SHARED by the layers (one it can inline early leaves
+    a bare `ragged-dot-none`, and the device trace's readers find no
+    phase).  The layers share their lowered functions as long as every
+    checkpoint has the SAME policy object: JAX caches the partial
+    evaluation by its identity."""
+    import re
+
+    from mxnet_tpu.parallel import data_parallel
+
+    assert data_parallel._remat_policy() is data_parallel._remat_policy()
+    text = _decoder_step_aot(
+        topo, monkeypatch, ["sliding_attention"] * 2, [64, 64], 512, 1,
+        sparse=True).as_text()
+    names = re.findall(r'op_name="([^"]*ragged-dot-[^"]*)"', text)
+    assert names and all(
+        re.match(r"jit\(step_phases\)/.*jvp\(forward\).*/ragged-dot-", name)
+        for name in names), sorted(set(names))
+
+
 @pytest.mark.parametrize("rotated", [128, 64], ids=["whole", "half"])
 def test_rotary_embedding_float32_aot(one_chip, rotated):
     """The rotary op in float32 at the cell's head shapes (what the

@@ -559,6 +559,10 @@ def test_heads_per_step_rule():
     assert (q.shape[0] * q.shape[1]) % rule(12, 128, 128, 64, 2) == 0
 
 
+_NOTHING_NAMED = {"residuals_named": 0, "residual_pairs": {},
+                  "residual_bytes": {}}
+
+
 def test_flash_attention_profiler_section(interpret_pallas):
     """The `flashAttention` section: every kernel built while a program
     is TRACED is counted once, with its variant, shapes, heads a grid
@@ -578,7 +582,7 @@ def test_flash_attention_profiler_section(interpret_pallas):
     profiler.sections(reset=True)
     assert profiler.sections()["flashAttention"] == {
         "kernels": 0, "resident": 0, "streamed": 0, "grouped": 0,
-        "built": {}}
+        "built": {}, **_NOTHING_NAMED}
 
     q, k, v, mask, w = _grouped_case(2, 4, 128, 128, 64, "float32", True)
     step = jax.jit(jax.grad(
@@ -588,29 +592,42 @@ def test_flash_attention_profiler_section(interpret_pallas):
     rows = {
         f"resident {kernel} b2 h4 sq128 sk128 d64 float32 heads4 grid2x1": 1
         for kernel in ("fwd", "dq", "dkv")}
+    # differentiation ran the fwd rule, which named its (out, lse)
+    # pair: 2*4*128*64 and 2*4*128 float32
+    named = {"residuals_named": 1,
+             "residual_pairs": {"resident b2 h4 sq128 sk128 d64 float32": 1},
+             "residual_bytes": {
+                 "resident b2 h4 sq128 sk128 d64 float32": 4 * 1024 * 65}}
     assert profiler.sections()["flashAttention"] == {
         "kernels": 3, "resident": 3, "streamed": 0, "grouped": 0,
-        "built": rows}
+        "built": rows, **named}
     assert fa._heads_per_step(4, 128, 128, 64, 4) == 4
 
     # a second program is traced (another batch): its kernels are rows
-    # of their own
+    # of their own; without differentiation no fwd rule runs and
+    # nothing is named
     jax.jit(lambda q: fa.flash_attention(q, q, q))(
         jnp.concatenate([q, q]))
     stats = profiler.sections()["flashAttention"]
     assert stats["kernels"] == 4 and stats["built"][
         "resident fwd b4 h4 sq128 sk128 d64 float32 heads4 grid4x1"] == 1
+    assert {k: stats[k] for k in named} == named
 
     assert json.loads(profiler.dumps())["flashAttention"] == stats
     table = "\n".join(profiler._section_tables())
     assert "Flash Attention (kernels built at trace time):" in table
     assert "resident dkv b2 h4 sq128 sk128 d64 float32 heads4 grid2x1  x1" \
         in table
+    assert ("  named resident b2 h4 sq128 sk128 d64 float32  x1  "
+            "266240 bytes") in table
     text = metrics.default_registry().render()
     assert "mxtpu_flash_attention_kernels 4" in text
     assert "mxtpu_flash_attention_resident 4" in text
     assert ('mxtpu_flash_attention_built{key="resident dq b2 h4 sq128 '
             'sk128 d64 float32 heads4 grid2x1"} 1') in text
+    assert "mxtpu_flash_attention_residuals_named 1" in text
+    assert ('mxtpu_flash_attention_residual_bytes{key="resident b2 h4 '
+            'sq128 sk128 d64 float32"} 266240') in text
 
     assert json.loads(profiler.dumps(reset=True))[
         "flashAttention"]["kernels"] == 4
@@ -629,7 +646,45 @@ def test_flash_attention_section_counts_streamed(interpret_pallas,
     assert profiler.sections()["flashAttention"] == {
         "kernels": 1, "resident": 0, "streamed": 1, "grouped": 0, "built": {
             "streamed fwd b1 h2 sq128 sk256 d64 float32 heads1 "
-            "grid2x1x2": 1}}
+            "grid2x1x2": 1}, **_NOTHING_NAMED}
+
+
+@pytest.mark.parametrize("window", [None, 128])
+def test_flash_attention_section_counts_named_residuals(window,
+                                                        interpret_pallas):
+    """A grouped kernel's fwd rule names its output and row statistic
+    (`RESIDUAL_NAMES`): the section has the pair and its bytes under
+    the variant's shape, its K/V heads and window; a plain forward
+    (no fwd rule) names nothing; and under a `jax.checkpoint` whose
+    policy saves the names the gradient is the bare checkpoint's."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import profiler
+    from mxnet_tpu.ops.pallas import flash_attention as fa
+
+    q, k, v, w = _shared_kv_case(3, 256, 64)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True, window=window)
+        return (out * w).sum()
+
+    jax.clear_caches()
+    profiler.sections(reset=True)
+    loss(q, k, v)
+    assert profiler.sections()["flashAttention"]["residuals_named"] == 0
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *fa.RESIDUAL_NAMES)
+    kept = jax.grad(jax.checkpoint(loss, policy=policy),
+                    argnums=(0, 1, 2))(q, k, v)
+    row = f"grouped b1 h6 sq256 sk256 d64 kv2 window{window or 0} float32"
+    stats = profiler.sections()["flashAttention"]
+    assert stats["residuals_named"] == 1
+    assert stats["residual_pairs"] == {row: 1}
+    assert stats["residual_bytes"] == {row: 4 * 6 * 256 * (64 + 1)}
+    bare = jax.grad(jax.checkpoint(loss), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(kept, bare):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # -- grouped variant: shared K/V heads and / or a sliding window -------------

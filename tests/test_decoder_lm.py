@@ -144,6 +144,65 @@ def test_trainer_in_bfloat16_with_remat_learns(reference):
     assert np.isfinite(losses).all() and losses[-1] < losses[0] - 0.05
 
 
+def _sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for v in value if isinstance(value, (tuple, list)) else (value,):
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _kernels(jaxpr, inside_remat=False):
+    """(name of the kernel's function, whether a `remat2` body holds
+    it) of every `pallas_call` of a jaxpr, at any depth."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append((eqn.params["jaxpr"].debug_info.func_name,
+                        inside_remat))
+        for inner in _sub_jaxprs(eqn):
+            out += _kernels(inner,
+                            inside_remat or eqn.primitive.name == "remat2")
+    return out
+
+
+@pytest.mark.parametrize("policy", ["kept", "bare"])
+def test_remat_recomputes_a_layer_without_its_forward_kernel(
+        reference, policy, monkeypatch):
+    """The step's jaxpr (the dispatch traces the TPU branch whatever
+    the platform): the forward attention kernel once a layer, in the
+    forward pass, and none inside a `remat2` body, which holds the two
+    backward kernels and takes the named output and row statistic as
+    inputs; under a bare `jax.checkpoint` every body runs it again."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.parallel import data_parallel
+
+    if policy == "bare":
+        monkeypatch.setattr(data_parallel, "_remat_policy", lambda: None)
+    config = copy.deepcopy(SMALL)
+    config.update(head_dim=64, sliding_window=32)
+    net = _filled(config, _weights(reference, config))
+    trainer = data_parallel.DataParallelTrainer(
+        net, lambda out, _: out, "adamw", {"learning_rate": 1e-3},
+        mesh=_one_device_mesh(), compute_dtype="bfloat16", remat=True)
+    x, y = _batch(config, rows=2, seq=128), np.zeros((2,), np.float32)
+    trainer.build(x)
+    scalar = jnp.zeros((), jnp.float32)
+    jaxpr = jax.make_jaxpr(trainer._step_core)(
+        trainer._params, trainer._states, x, y, jax.random.key_data(
+            jax.random.key(0)), scalar, scalar).jaxpr
+    kernels = _kernels(jaxpr)
+    layers = config["num_hidden_layers"]
+    again = layers if policy == "bare" else 0
+    assert sorted(kernels) == sorted(
+        [("_grouped_fwd_kernel", False)] * layers
+        + [("_grouped_fwd_kernel", True)] * again
+        + [("_grouped_dq_kernel", True), ("_grouped_dkv_kernel", True)]
+        * layers)
+
+
 def _moe_inputs(tokens=48, h=64, width=32, router_width=16, seed=0):
     rng = np.random.RandomState(seed)
     import jax.numpy as jnp

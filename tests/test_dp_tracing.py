@@ -35,14 +35,14 @@ def _batch(n=16):
             rng.randint(0, 4, (n,)).astype("float32"))
 
 
-def _trainer(optimizer="adamw"):
+def _trainer(optimizer="adamw", **kwargs):
     mx.random.seed(5)
     net = gluon.nn.HybridSequential()
     net.add(gluon.nn.Dense(16, activation="relu"), gluon.nn.Dense(4))
     net.initialize(mx.init.Xavier())
     return dp.DataParallelTrainer(
         net, gluon.loss.SoftmaxCrossEntropyLoss(), optimizer,
-        {"learning_rate": 1e-2})
+        {"learning_rate": 1e-2}, **kwargs)
 
 
 def _host_events(trace_dir):
@@ -194,7 +194,8 @@ def test_section_is_summed_from_the_log_and_resets():
         "dataParallelStep"]["steps"] == 3
     assert profiler.sections()["dataParallelStep"] == {
         "steps": 0, "builds": 0, "put_ms": 0, "args_ms": 0,
-        "enqueue_ms": 0, "put_bytes": 0}
+        "enqueue_ms": 0, "put_bytes": 0, "remat_children": 0,
+        "remat_saves": {}}
     trainer.step(x, y)
     after = profiler.sections()["dataParallelStep"]
     assert after["steps"] == 1 and after["builds"] == 0
@@ -203,6 +204,50 @@ def test_section_is_summed_from_the_log_and_resets():
     text = metrics.default_registry().render()
     assert "mxtpu_data_parallel_step_steps 1" in text
     assert "mxtpu_data_parallel_step_put_bytes" in text
+
+
+def test_section_says_what_remat_wrapped_and_keeps():
+    """`remat_children`: the child blocks the trainers built in the
+    window wrapped in a `jax.checkpoint` (none by a trainer without
+    `remat`, none by one whose model is flat: its whole forward is one
+    checkpoint); `remat_saves`: the names that checkpoint's policy
+    keeps, the flash kernels' own, each with the trainers under it.
+    On /metrics and in the table as the other rows, and window-scoped."""
+    from mxnet_tpu.ops.pallas.flash_attention import RESIDUAL_NAMES
+    from mxnet_tpu.telemetry import metrics
+
+    assert RESIDUAL_NAMES == ("flash_out", "flash_lse")
+    profiler.sections(reset=True)
+    x, y = _batch()
+    _trainer("sgd").step(x, y)
+    stats = profiler.sections()["dataParallelStep"]
+    assert stats["remat_children"] == 0 and stats["remat_saves"] == {}
+    _trainer("sgd", remat=True).step(x, y)
+    stats = profiler.sections()["dataParallelStep"]
+    assert stats["builds"] == 2 and stats["remat_children"] == 2
+    assert stats["remat_saves"] == {"flash_out": 1, "flash_lse": 1}
+
+    flat = gluon.nn.Dense(4)
+    flat.initialize(mx.init.Xavier())
+    trainer = dp.DataParallelTrainer(
+        flat, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+        {"learning_rate": 1e-2}, remat=True)
+    trainer.step(x, y)
+    trainer.build(x)        # built once: counted once
+    stats = profiler.sections()["dataParallelStep"]
+    assert stats["remat_children"] == 2
+    assert stats["remat_saves"] == {"flash_out": 2, "flash_lse": 2}
+
+    table = profiler._section_tables()
+    assert f"{'remat: children checkpointed':<40}{2:>12}" in table
+    assert f"{'remat keeps[flash_lse] (trainers)':<40}{2:>12}" in table
+    text = metrics.default_registry().render()
+    assert "mxtpu_data_parallel_step_remat_children 2" in text
+    assert 'mxtpu_data_parallel_step_remat_saves{key="flash_out"} 2' in text
+    assert json.loads(profiler.dumps(reset=True))[
+        "dataParallelStep"]["remat_children"] == 2
+    stats = profiler.sections()["dataParallelStep"]
+    assert stats["remat_children"] == 0 and stats["remat_saves"] == {}
 
 
 def test_section_and_span_names_pass_the_invariant_passes():

@@ -282,40 +282,89 @@ def test_gradient_compression_validation():
     assert kv._compression is None
 
 
-def test_spmd_remat_matches_exact():
+def _dense_case():
+    from mxnet_tpu import gluon
+
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(32, activation="relu"),
+            gluon.nn.Dense(32, activation="relu"),
+            gluon.nn.Dense(4))
+    rng = np.random.RandomState(0)
+    return (net, rng.rand(16, 10).astype(np.float32),
+            rng.randint(0, 4, 16).astype(np.float32))
+
+
+def _attention_case():
+    """Two encoder layers (attention at a shape the flash kernels
+    take: sequence 128, two heads of 64) between two projections."""
+    from mxnet_tpu import gluon
+    from mxnet_tpu.models.bert import BERTEncoderLayer
+
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(128, flatten=False),
+            BERTEncoderLayer(128, 256, 2, dropout=0.0),
+            BERTEncoderLayer(128, 256, 2, dropout=0.0),
+            gluon.nn.Dense(4, flatten=False))
+    rng = np.random.RandomState(0)
+    return (net, rng.rand(4, 128, 10).astype(np.float32),
+            rng.randint(0, 4, (4, 128)).astype(np.float32))
+
+
+@pytest.mark.parametrize("case", ["dense", "attention", "flash_kernels"])
+def test_spmd_remat_matches_exact(case, monkeypatch, request):
     """remat=True must change only the memory/FLOP schedule, not the
-    math: identical loss trajectory and final params vs remat=False."""
+    math: identical loss trajectory and final params vs remat=False
+    (to an ulp or two: XLA fuses a recomputed pass otherwise than a
+    stored one).  On the flash kernels the output and row statistic
+    that remat keeps are the values a second run of the forward kernel
+    would produce: BIT FOR BIT the bare `jax.checkpoint`'s result, as
+    on the XLA form of attention (what a CPU lowers), where the names
+    sit in the branch the lowering drops."""
     import jax
 
     import mxnet_tpu as mx
-    from mxnet_tpu import gluon
+    from mxnet_tpu import gluon, profiler
     from mxnet_tpu.parallel import data_parallel, mesh as mesh_mod
 
-    def build(remat):
+    if case == "flash_kernels":
+        # the TPU branch of the attention dispatch, its kernels run by
+        # Pallas's interpreter: the residual names are live, as on a chip
+        request.getfixturevalue("interpret_pallas")
+        monkeypatch.setattr(jax.lax, "platform_dependent",
+                            lambda *args, tpu, default: tpu(*args))
+    jax.clear_caches()      # the section counts traces: start from none
+    profiler.sections(reset=True)
+
+    def run(remat):
         mx.random.seed(11)
-        net = gluon.nn.HybridSequential()
-        net.add(gluon.nn.Dense(32, activation="relu"),
-                gluon.nn.Dense(32, activation="relu"),
-                gluon.nn.Dense(4))
+        net, x, y = _dense_case() if case == "dense" else _attention_case()
         net.initialize(mx.init.Xavier())
         mesh = mesh_mod.make_mesh({"dp": 2}, devices=jax.devices()[:2])
-        return net, data_parallel.DataParallelTrainer(
+        tr = data_parallel.DataParallelTrainer(
             net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
-            {"learning_rate": 0.1}, mesh=mesh, remat=remat)
+            {"learning_rate": 0.1 if case == "dense" else 0.01},
+            mesh=mesh, remat=remat)
+        losses = [float(tr.step(x, y).asscalar()) for _ in range(5)]
+        return losses, [np.asarray(p) for p in tr._params]
 
-    rng = np.random.RandomState(0)
-    x = rng.rand(16, 10).astype(np.float32)
-    y = rng.randint(0, 4, 16).astype(np.float32)
-    losses = {}
-    params = {}
-    for remat in (False, True):
-        _, tr = build(remat)
-        losses[remat] = [float(tr.step(x, y).asscalar()) for _ in range(5)]
-        params[remat] = [np.asarray(p) for p in tr._params]
-    assert np.allclose(losses[False], losses[True], atol=1e-6), losses
-    for a, b in zip(params[False], params[True]):
+    stored, kept = run(False), run(True)
+    assert np.allclose(stored[0], kept[0], atol=1e-6), (stored[0], kept[0])
+    for a, b in zip(stored[1], kept[1]):
         assert np.allclose(a, b, atol=1e-6)
-    assert losses[True][-1] < losses[True][0]
+    assert kept[0][-1] < kept[0][0]
+    if case == "dense":
+        return
+    # one trace of the fwd rule serves both layers and both trainers
+    # (the dispatch traces its TPU branch whatever the platform): the
+    # pair it named is the (b, h, s, d) output and the (b, h, s) lse
+    stats = profiler.sections()["flashAttention"]
+    assert stats["residuals_named"] == 1 and stats["residual_bytes"] == {
+        "resident b2 h2 sq128 sk128 d64 float32": 4 * 2 * 2 * 128 * (64 + 1)}
+    monkeypatch.setattr(data_parallel, "_remat_policy", lambda: None)
+    bare = run(True)
+    assert bare[0] == kept[0]
+    for a, b in zip(bare[1], kept[1]):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_step_many_matches_stepwise():
