@@ -164,7 +164,7 @@ health-smoke:
 # aggregate_num=1, no prefetch, zero linger, one giant serve bucket)
 # the closed loop must escape by a gated margin on a real
 # training+serving rehearsal, beat-or-tie the hand-tuned defaults,
-# leave a bench_diff-readable evidence trail, and settle on a config
+# leave a replayable evidence trail on disk, and settle on a config
 # whose serving surface is closed (zero post-warmup compiles) — see
 # tools/tune_smoke.py / docs/tuning.md
 tune-smoke:

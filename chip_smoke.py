@@ -19,8 +19,9 @@ Phases (one JSON object per phase on stdout, then the result line):
    ``backward`` / ``Trainer("adamw").step``, then ``Trainer.whole_step``,
    and the first forward against an ``mx.cpu()`` copy of the weights.
 3. ``spmd``    — ``DataParallelTrainer(compute_dtype="bfloat16")``, the
-   path bench.py and examples/bert/pretrain_bert.py use, batch 64 x 128,
-   with the flash-attention kernel REQUIRED in the compiled step.
+   path the benchmark's cells and examples/bert/pretrain_bert.py use,
+   batch 64 x 128, with the flash-attention kernel REQUIRED in the
+   compiled step.
 4. ``serve``   — ResNet-50 v1 NHWC at 224x224 behind ``ModelServer``.
 5. ``kernels`` — every Pallas family compiled value-and-grad for the
    chip in bf16 and f32, and the flash kernel run against the reference.
@@ -45,7 +46,7 @@ for _p in (os.path.join(REPO, "examples"),
 SEED = 0
 VOCAB = 30522        # BERT-base (models/bert.py bert_base)
 SEQ = 128
-SPMD_BATCH = 64      # the bench.py / pretrain_bert.py batch
+SPMD_BATCH = 64      # the pretrain_bert.py batch
 GLUON_BATCH = 8
 ORACLE_BATCH = 2     # the mx.cpu() comparison forward
 IMAGE = 224          # ResNet-50 v1 serving resolution
@@ -178,7 +179,7 @@ def phase_context(chip):
     lstm = gluon.rnn.LSTM(8)
     lstm.initialize(ctx=mx.xla(0))
     assert on_device(lstm(nd.ones((3, 2, 4), ctx=mx.xla(0))), chip)
-    # the examples and bench.py initialise on the DEFAULT context (the
+    # the examples and the benchmark initialise on the DEFAULT context (the
     # host) and hand the block to DataParallelTrainer: its parameters
     # and its step must still end up on the chip
     mx.random.seed(SEED)

@@ -120,10 +120,10 @@ class AnalysisConfig:
     getenv_fns: tuple = ("getenv",)
     fault_point_fns: tuple = ("fault_point",)
     # telemetry catalog (MXA403/MXA405): how sections register, which
-    # helpers the output paths iterate them through, where span/metric
+    # helpers the output paths read them through, where span/metric
     # names must be documented, and which call names define them
     section_register_fns: tuple = ("register_section",)
-    section_iter_fns: tuple = ("_section_data", "_section_tables")
+    section_iter_fns: tuple = ("sections", "_section_tables")
     observability_doc: str = "docs/observability.md"
     span_site_fns: tuple = ("op_scope", "span_begin", "instant",
                             "request_begin")

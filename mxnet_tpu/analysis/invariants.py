@@ -12,15 +12,13 @@ MXA401  raw environment read — ``os.environ``/``os.getenv`` outside
 MXA402  undocumented env knob — a ``base.getenv("NAME")`` read whose
         ``MXTPU_NAME`` spelling (or a raw read whose literal name) does
         not appear in docs/ENV_VARS.md.
-MXA403  profiler section registry violation — a ``_*_counters``
-        provider in the profiler module that is not registered via
-        ``register_section`` (the registry is what ``dumps()`` and
-        ``_aggregate_table()`` iterate, so an unregistered section
-        silently vanishes from both output paths), a registered
-        provider that ignores its ``reset`` flag, or an output path
-        calling a provider / the registry iterator without forwarding
-        ``reset`` (the "reset dump must scope EVERY section" rule PRs
-        2-5 each re-fixed by hand before the registry existed).
+MXA403  profiler output path outside the section registry —
+        ``dumps()`` or ``_aggregate_table()`` not reading the registry
+        at all, or reading it without forwarding ``reset`` (the
+        registry zeroes every section it reads under ``reset``; an
+        output path that drops the flag window-scopes none).  What a
+        section registers (``register_section(name, stats, reset)``)
+        needs no check: the registry, not the owner, calls ``reset``.
 MXA404  uncataloged fault point — an ``engine.fault_point("site")``
         whose site name is missing from the docs/resilience.md catalog
         (chaos plans target sites by name; an uncataloged site is
@@ -169,72 +167,13 @@ def _passes_reset(node):
 
 
 def _profiler_findings(index, findings):
+    """MXA403: both output paths of the profiler module read the
+    section registry through a ``section_iter_fns`` helper, and hand it
+    their ``reset``."""
     cfg = index.cfg
     mod = index.modules.get(cfg.profiler_module)
     if mod is None:
         return
-    # provider functions by the naming convention ...
-    pattern_providers = {}
-    for key, func in index.funcs.items():
-        if func.module is mod and func.cls is None and \
-                re.fullmatch(r"_[a-z0-9_]+_counters", func.name):
-            pattern_providers[func.name] = func
-    # ... and what the section registry actually holds:
-    # register_section("name", provider_fn) calls in the module
-    registered = {}   # local provider name -> (section name, call node)
-    for node in ast.walk(mod.tree):
-        if (isinstance(node, ast.Call)
-                and _fname(node.func) in cfg.section_register_fns
-                and len(node.args) >= 2
-                and isinstance(node.args[1], ast.Name)):
-            registered[node.args[1].id] = (_literal(node.args[0]), node)
-
-    # membership: a conventionally-named provider that never reaches
-    # the registry silently vanishes from BOTH output paths
-    for name, func in sorted(pattern_providers.items()):
-        if name not in registered:
-            findings.append(Finding(
-                "MXA403", mod.relpath, func.node.lineno, name,
-                f"profiler section provider {name} is not registered "
-                f"via register_section — dumps()/_aggregate_table() "
-                f"iterate the registry, so this section would silently "
-                f"vanish from both output paths"))
-
-    # reset scoping: every provider (registered or convention-named)
-    # must take reset and zero its counters under `if reset:`
-    checkable = dict(pattern_providers)
-    for name in registered:
-        func = index.funcs.get((mod.modname, name))
-        if func is not None:
-            checkable.setdefault(name, func)
-    for name, func in sorted(checkable.items()):
-        argnames = [a.arg for a in func.node.args.args]
-        if "reset" not in argnames:
-            findings.append(Finding(
-                "MXA403", mod.relpath, func.node.lineno, name,
-                f"profiler section provider {name} takes no reset "
-                f"parameter — sections must be window-scopable"))
-            continue
-        resets = False
-        for node in ast.walk(func.node):
-            if isinstance(node, ast.If):
-                test_names = {n.id for n in ast.walk(node.test)
-                              if isinstance(n, ast.Name)}
-                if "reset" in test_names:
-                    for sub in ast.walk(node):
-                        if (isinstance(sub, ast.Call)
-                                and "reset" in ast.dump(sub.func).lower()):
-                            resets = True
-        if not resets:
-            findings.append(Finding(
-                "MXA403", mod.relpath, func.node.lineno, name,
-                f"profiler section provider {name} never resets its "
-                f"counters under `if reset:` — dumps(reset=True) would "
-                f"mix window events with forever-cumulative counts"))
-
-    # both output paths must forward reset — whether they call a
-    # provider directly (legacy style) or iterate the registry through
-    # a section_iter_fns helper
     for caller_name in ("dumps", "_aggregate_table"):
         caller = index.funcs.get((mod.modname, caller_name))
         if caller is None:
@@ -244,32 +183,24 @@ def _profiler_findings(index, findings):
             if not isinstance(node, ast.Call):
                 continue
             fn = _fname(node.func)
-            if fn in checkable:
-                touched = True
-                if not _passes_reset(node):
-                    findings.append(Finding(
-                        "MXA403", mod.relpath, node.lineno,
-                        f"{caller_name}:{fn}",
-                        f"{caller_name}() calls {fn} without forwarding "
-                        f"reset — this output path would not "
-                        f"window-scope the section"))
-            elif fn in cfg.section_iter_fns:
-                touched = True
-                if not _passes_reset(node):
-                    findings.append(Finding(
-                        "MXA403", mod.relpath, node.lineno,
-                        f"{caller_name}:{fn}",
-                        f"{caller_name}() iterates the section "
-                        f"registry via {fn} without forwarding reset — "
-                        f"this output path would not window-scope ANY "
-                        f"section"))
-        if not touched and (registered or pattern_providers):
+            if fn not in cfg.section_iter_fns:
+                continue
+            touched = True
+            if not _passes_reset(node):
+                findings.append(Finding(
+                    "MXA403", mod.relpath, node.lineno,
+                    f"{caller_name}:{fn}",
+                    f"{caller_name}() iterates the section "
+                    f"registry via {fn} without forwarding reset — "
+                    f"this output path would not window-scope ANY "
+                    f"section"))
+        if not touched:
             findings.append(Finding(
                 "MXA403", mod.relpath, caller.node.lineno,
                 f"{caller_name}:<no-sections>",
-                f"{caller_name}() neither iterates the section "
-                f"registry nor calls a provider — counter sections "
-                f"are missing from this output path"))
+                f"{caller_name}() does not iterate the section "
+                f"registry — counter sections are missing from this "
+                f"output path"))
 
 
 # -- fault-point catalog ----------------------------------------------------

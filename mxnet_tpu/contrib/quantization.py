@@ -51,7 +51,7 @@ _NUM_BINS = 8001
 
 # ---------------------------------------------------------------------------
 # window-scoped module counters: the profiler's `quantize` section
-# (provider: profiler._quantize_counters; exported to /metrics as
+# (registered below; exported to /metrics as
 # mxtpu_quantize_* gauges by the section collector)
 
 _sec_lock = threading.Lock()
@@ -79,6 +79,16 @@ def reset_quantize_stats():
     with _sec_lock:
         for k in _sec:
             _sec[k] = 0.0 if k == "calib_ms" else 0
+
+
+profiler.register_section(
+    "quantize", quantize_stats, reset_quantize_stats, profiler.rows_table(
+        "INT8 Quantization",
+        (("layers quantized", "layers_quantized"),
+         ("calibration batches", "calib_batches"),
+         ("calibration time (ms)", "calib_ms"),
+         ("requantize folds", "requant_folds"),
+         ("int8 serve batches", "int8_serve_batches"))))
 
 
 def note_int8_serve_batch(n=1):

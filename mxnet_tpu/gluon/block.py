@@ -23,6 +23,7 @@ import re
 import threading
 
 from .. import autograd
+from .. import profiler as _profiler
 from .. import random as _random
 from .._imperative import invoke
 from ..base import MXNetError
@@ -306,6 +307,14 @@ def reset_cached_graph_stats():
         _graph_stats["reuses"] = 0
 
 
+_profiler.register_section(
+    "cachedGraph", cached_graph_stats, reset_cached_graph_stats,
+    _profiler.rows_table(
+        "Compiled-Graph Cache (CachedOp)",
+        (("graph compiles (new signature)", "compiles"),
+         ("graph reuses (cache hit)", "reuses"))))
+
+
 def traced_apply(block, param_raws, input_raws, key, train=True,
                  static_kwargs=None):
     """Run ``block.forward`` under graph capture: every Parameter's
@@ -440,10 +449,8 @@ class CachedOp:
                 _graph_stats["reuses"] += 1
         key_nd = _wrap(_random.next_key())
         if fresh_compile:
-            from .. import profiler
-
-            with profiler.op_scope(f"cached_op.compile.{self.block.name}",
-                                   cat="cached_op"):
+            with _profiler.op_scope(f"cached_op.compile.{self.block.name}",
+                                    cat="cached_op"):
                 res = invoke(fn, key_nd, *param_nds, *inputs,
                              _n_params=len(param_nds))
         else:
@@ -566,10 +573,8 @@ class CachedStepOp:
             donate_argnums=donate)
         _imperative.count_dispatch()
         if fresh:
-            from .. import profiler
-
-            with profiler.op_scope(f"cached_op.compile.{self.block.name}",
-                                   cat="cached_op"):
+            with _profiler.op_scope(f"cached_op.compile.{self.block.name}",
+                                    cat="cached_op"):
                 outs = jitted(_random.next_key(), *param_raws, *input_raws)
         else:
             outs = jitted(_random.next_key(), *param_raws, *input_raws)

@@ -52,6 +52,22 @@ def reset_trainer_step_stats():
         _step_stats[k] = 0
 
 
+_profiler.register_section(
+    "trainerStep", trainer_step_stats, reset_trainer_step_stats,
+    _profiler.rows_table(
+        "Trainer Step Fusion",
+        (("steps", "steps"),
+         ("params fused", "params_fused"),
+         ("allreduce buckets built", "buckets_built"),
+         ("dispatches per step", "dispatches_per_step"),
+         ("whole-step compiled steps", "whole_step_steps"),
+         ("whole-step compiles", "whole_step_compiles"),
+         ("whole-step fallbacks", "whole_step_fallbacks"),
+         ("zero-sharded steps", "zero_steps"),
+         ("zero-shard fallbacks", "zero_fallbacks"),
+         ("spmd mesh steps", "spmd_steps"))))
+
+
 class Trainer:
     def __init__(self, params, optimizer, optimizer_params=None,
                  kvstore="device", compression_params=None,

@@ -39,6 +39,7 @@ import time
 import jax
 import numpy as np
 
+from .. import profiler
 from ..gluon.block import HybridBlock
 from ..ops.nn import rotary_frequencies
 
@@ -271,3 +272,17 @@ def reset_moe_routing_stats():
     `moe_routing_stats(window=True)` (its log is an older window's)."""
     global _opened_ns
     _opened_ns = time.perf_counter_ns()
+
+
+def _routing_table(stats):
+    out = ["MoE Routing (newest step, by expert layer):"]
+    for key in sorted(stats["rows_here"]):
+        out.append(f"  {key}: rows here {stats['rows_here'][key]}, share "
+                   f"{stats['share_here'][key]:.4f}, max/mean "
+                   f"{stats['max_over_mean'][key]:.3f}")
+    return out
+
+
+profiler.register_section(
+    "moeRouting", lambda: moe_routing_stats(window=True),
+    reset_moe_routing_stats, _routing_table)

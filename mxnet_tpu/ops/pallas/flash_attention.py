@@ -64,6 +64,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ... import profiler
 from . import pallas_call
 
 _NEG_INF = -1e9
@@ -155,6 +156,30 @@ def flash_attention_stats():
 def reset_flash_attention_stats():
     _built.clear()
     _named.clear()
+
+
+_stats_rows = profiler.rows_table(
+    "Flash Attention (kernels built at trace time)",
+    (("kernels", "kernels"),
+     ("resident (K/V in VMEM)", "resident"),
+     ("streamed (K/V swept by the grid)", "streamed"),
+     ("grouped (shared K/V heads, window)", "grouped")))
+
+
+def _stats_table(stats):
+    out = _stats_rows(stats)
+    for row in sorted(stats["built"]):
+        out.append(f"  {row}  x{stats['built'][row]}")
+    out.append(f"{'(out, lse) pairs named for remat':<40}"
+               f"{stats['residuals_named']:>12}")
+    for row in sorted(stats["residual_pairs"]):
+        out.append(f"  named {row}  x{stats['residual_pairs'][row]}  "
+                   f"{stats['residual_bytes'][row]} bytes")
+    return out
+
+
+profiler.register_section("flashAttention", flash_attention_stats,
+                          reset_flash_attention_stats, _stats_table)
 
 
 def _heads_per_step(h, sq, sk, d, itemsize, block=128):

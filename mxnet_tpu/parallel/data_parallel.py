@@ -96,6 +96,30 @@ def reset_data_parallel_step_stats():
     _window = (time.perf_counter_ns(), _built, tuple(_remat_built))
 
 
+_step_rows = _profiler.rows_table(
+    "Data-Parallel Step (host side)",
+    (("steps", "steps"),
+     ("trainers built", "builds"),
+     ("batch put (ms)", "put_ms"),
+     ("key and scalars (ms)", "args_ms"),
+     ("step enqueue (ms)", "enqueue_ms"),
+     ("bytes put", "put_bytes"),
+     ("remat: children checkpointed", "remat_children")))
+
+
+def _step_table(stats):
+    out = _step_rows(stats)
+    for name in sorted(stats["remat_saves"]):
+        out.append(f"{'remat keeps[' + name + '] (trainers)':<40}"
+                   f"{stats['remat_saves'][name]:>12}")
+    return out
+
+
+_profiler.register_section(
+    "dataParallelStep", data_parallel_step_stats,
+    reset_data_parallel_step_stats, _step_table)
+
+
 def _remat_saves():
     """The names `remat=True` keeps across a checkpoint: the flash
     kernels' output and row statistic, as their fwd rules name them."""

@@ -16,6 +16,8 @@ from __future__ import annotations
 
 import threading
 
+from .. import profiler
+
 _lock = threading.Lock()
 _stats = {
     "batches": 0,           # batches delivered to the consumer
@@ -47,3 +49,15 @@ def reset_pipeline_stats():
     with _lock:
         for k in _stats:
             _stats[k] = 0.0 if isinstance(_stats[k], float) else 0
+
+
+profiler.register_section(
+    "dataPipeline", pipeline_stats, reset_pipeline_stats,
+    profiler.rows_table(
+        "Data Pipeline",
+        (("batches delivered", "batches"),
+         ("host build (ms)", "host_build_ms"),
+         ("h2d staging (ms)", "h2d_ms"),
+         ("step wait-on-input (ms)", "wait_ms"),
+         ("prefetch hits", "prefetch_hits"),
+         ("prefetch misses", "prefetch_misses"))))

@@ -224,305 +224,72 @@ def op_scope(name, cat="operator", **attrs):
     return _OpScope(name, cat, attrs)
 
 
-def _graph_cache_counters(reset=False):
-    """Compiled-graph cache compile/reuse split (gluon CachedOp) — only
-    when the gluon tier is actually loaded; importing it from here would
-    drag the whole frontend in for a profiler dump."""
-    import sys
-
-    block = sys.modules.get(__package__ + ".gluon.block")
-    if block is None:
-        return None
-    stats = block.cached_graph_stats()
-    if reset:
-        # a reset dump must scope EVERY section to the window, not mix
-        # per-window events with forever-cumulative compile counts
-        block.reset_cached_graph_stats()
-    return stats
-
-
-def _trainer_step_counters(reset=False):
-    """Step-fusion counters from gluon.Trainer (params_fused,
-    buckets_built, dispatches_per_step) — window-scoped under reset=True
-    exactly like cachedGraph; only present when the gluon tier is
-    loaded."""
-    import sys
-
-    trainer = sys.modules.get(__package__ + ".gluon.trainer")
-    if trainer is None:
-        return None
-    stats = trainer.trainer_step_stats()
-    if reset:
-        trainer.reset_trainer_step_stats()
-    return stats
-
-
-def _data_pipeline_counters(reset=False):
-    """Input-pipeline counters (batches, host-build/h2d/wait ms,
-    prefetch hit/miss) — window-scoped under reset=True exactly like
-    cachedGraph/trainerStep; only present when the pipeline tier is
-    loaded."""
-    import sys
-
-    pstats = sys.modules.get(__package__ + ".pipeline.stats")
-    if pstats is None:
-        return None
-    stats = pstats.pipeline_stats()
-    if reset:
-        pstats.reset_pipeline_stats()
-    return stats
-
-
-def _resilience_counters(reset=False):
-    """Supervisor/fault-recovery counters (restarts, retries by fault
-    class, fallback_restores, watchdog_fires, time_lost_ms, and the
-    elastic-resize trio resizes/ranks_lost/reshard_ms) — window-scoped
-    under reset=True exactly like cachedGraph/trainerStep/
-    dataPipeline; only present when the resilience tier is loaded."""
-    import sys
-
-    rstats = sys.modules.get(__package__ + ".resilience.stats")
-    if rstats is None:
-        return None
-    stats = rstats.resilience_stats()
-    if reset:
-        rstats.reset_resilience_stats()
-    return stats
-
-
-def _decode_serve_counters(reset=False):
-    """Continuous-batching decode counters (token steps, tokens,
-    prefill batches, admissions, finishes, deadline expiries, slot
-    occupancy) — window-scoped under reset=True exactly like every
-    other section; only present when the decode serving tier is
-    loaded."""
-    import sys
-
-    dec = sys.modules.get(__package__ + ".serve.decode")
-    if dec is None:
-        return None
-    stats = dec.decode_serve_stats()
-    if reset:
-        dec.reset_decode_serve_stats()
-    return stats
-
-
-def _router_counters(reset=False):
-    """Serve-router replica-pool counters (dispatches, retries, hedges,
-    evictions/replacements, health probes, rolling reloads) —
-    window-scoped under reset=True exactly like every other section;
-    only present when the routing tier is loaded."""
-    import sys
-
-    rt = sys.modules.get(__package__ + ".serve.router")
-    if rt is None:
-        return None
-    stats = rt.router_stats()
-    if reset:
-        rt.reset_router_stats()
-    return stats
-
-
-def _ctrl_counters(reset=False):
-    """Serving control-plane counters (RPC traffic, replica spawn and
-    retire churn, autoscaler decisions and the blocked-action tallies)
-    — window-scoped under reset=True like every other section; only
-    present when the control plane is loaded."""
-    import sys
-
-    cp = sys.modules.get(__package__ + ".serve.control_plane")
-    if cp is None:
-        return None
-    stats = cp.ctrl_stats()
-    if reset:
-        cp.reset_ctrl_stats()
-    return stats
-
-
-def _quantize_counters(reset=False):
-    """INT8 quantization counters (layers quantized, calibration
-    batches + wall time, requantize folds, compiled int8 serve
-    batches) — window-scoped under reset=True exactly like every other
-    section; only present when the quantization tier is loaded."""
-    import sys
-
-    qz = sys.modules.get(__package__ + ".contrib.quantization")
-    if qz is None:
-        return None
-    stats = qz.quantize_stats()
-    if reset:
-        qz.reset_quantize_stats()
-    return stats
-
-
-def _health_counters(reset=False):
-    """Health-monitor counters (per-step phase breakdown ms, goodput/
-    MFU gauges, SLO alerts, straggler flags) — window-scoped under
-    reset=True exactly like every other section; only present once a
-    HealthMonitor has been armed (telemetry.health)."""
-    stats = _health.health_stats()
-    if stats is None:
-        return None
-    if reset:
-        _health.reset_health_stats()
-    return stats
-
-
-def _tune_counters(reset=False):
-    """Autotuner counters (trials run, recompiles spent, blocked
-    restart-class moves, best/baseline ratio) — window-scoped under
-    reset=True exactly like every other section; only present when the
-    tune subsystem is loaded."""
-    import sys
-
-    tune = sys.modules.get(__package__ + ".tune")
-    if tune is None:
-        return None
-    stats = tune.tune_stats()
-    if reset:
-        tune.reset_tune_stats()
-    return stats
-
-
-def _data_parallel_step_counters(reset=False):
-    """`parallel.DataParallelTrainer` host-side step split (steps,
-    builds, put/args/enqueue ms, bytes put), summed from its step log,
-    and what `remat=True` wrapped and keeps in the trainers built
-    -- window-scoped under reset=True exactly like every other section;
-    only present when the data-parallel tier is loaded."""
-    import sys
-
-    dp = sys.modules.get(__package__ + ".parallel.data_parallel")
-    if dp is None:
-        return None
-    stats = dp.data_parallel_step_stats()
-    if reset:
-        dp.reset_data_parallel_step_stats()
-    return stats
-
-
-def _flash_attention_counters(reset=False):
-    """How the flash-attention kernels engaged in the programs traced
-    in the window: kernels built, resident / streamed, and a row for
-    each distinct kernel with its shapes, the heads a grid step works
-    on and the grid; and the (out, lse) pairs their fwd rules named for
-    a `jax.checkpoint` to keep, with their bytes.  Counted when a
-    program is traced, never when it runs; only present once the
-    kernels' module is loaded."""
-    import sys
-
-    fa = sys.modules.get(__package__ + ".ops.pallas.flash_attention")
-    if fa is None:
-        return None
-    stats = fa.flash_attention_stats()
-    if reset:
-        fa.reset_flash_attention_stats()
-    return stats
-
-
-def _moe_routing_counters(reset=False):
-    """Routing of the expert layers of every live trainer's decoder
-    model in its newest step (models/decoder_lm.py): rows each held
-    expert got, rows here, the share of all assignments that landed
-    here, max over mean.  Read from the trainers' routing logs on
-    demand; window-scoped like every section: after a reset dump a
-    trainer is back once it has taken a step; only present once the
-    model's module is loaded."""
-    import sys
-
-    lm = sys.modules.get(__package__ + ".models.decoder_lm")
-    if lm is None:
-        return None
-    stats = lm.moe_routing_stats(window=True)
-    if reset:
-        lm.reset_moe_routing_stats()
-    return stats
-
-
-def _moe_routing_table(stats):
-    out = ["MoE Routing (newest step, by expert layer):"]
-    for key in sorted(stats["rows_here"]):
-        out.append(f"  {key}: rows here {stats['rows_here'][key]}, share "
-                   f"{stats['share_here'][key]:.4f}, max/mean "
-                   f"{stats['max_over_mean'][key]:.3f}")
-    return out
-
-
-def _telemetry_counters(reset=False):
-    """Telemetry-subsystem counters (spans/instants/requests recorded,
-    drops, flight dumps, scrapes, aggregations) — window-scoped under
-    reset=True exactly like every other section."""
-    stats = _tracer.telemetry_stats()
-    if reset:
-        _tracer.reset_telemetry_stats()
-    return stats
-
-
 # ---------------------------------------------------------------------------
-# Section registry: every counter section a subsystem contributes to
-# dumps()/the aggregate table is one (provider, table renderer) entry
-# here.  PRs 2-5 each hand-wired a provider call into BOTH output
-# paths and re-fixed the reset forwarding by hand; now both paths
-# iterate this registry and the MXA403 invariant pass checks
-# membership + reset scoping mechanically.
+# Section registry: a counter section belongs to the module that owns
+# its counters, and that module registers it here when it is imported
+# (docs/observability.md, "section registry").  This module names none
+# of them: dumps(), the aggregate table, sections() and /metrics read
+# whatever is registered, in name order, so output does not follow
+# import order.
 
 
-_sections = []   # [(name, provider, table_fn)] in registration order
+_sections = {}   # name -> (stats, reset, table)
 
 
-def register_section(name, provider, table=None):
-    """Register a counter section.
+def register_section(name, stats, reset, table=None):
+    """Register a counter section, from the module that owns its
+    counters.
 
-    ``provider(reset=False)`` returns the section's stats dict (or
-    None while its subsystem is not loaded) and MUST zero its counters
-    under ``reset=True`` — every section is window-scoped, so a reset
-    dump never mixes per-window events with forever-cumulative counts.
-    ``table(stats)`` (optional) returns the section's lines for
-    ``dumps(format="table")``.  Re-registering a name replaces it.
+    ``stats()`` returns the section's dict (or None while it has
+    nothing to say) and ``reset()`` zeroes it.  The registry, not the
+    owner, decides when to call ``reset``: every section is
+    window-scoped, so a reset dump never mixes per-window events with
+    forever-cumulative counts.  ``table(stats)`` (optional) returns the
+    section's lines for ``dumps(format="table")``; ``rows_table`` makes
+    the usual one.  Re-registering a name replaces it.
     """
-    for i, (n, _p, _t) in enumerate(_sections):
-        if n == name:
-            _sections[i] = (name, provider, table)
-            return
-    _sections.append((name, provider, table))
+    _sections[name] = (stats, reset, table)
 
 
 def unregister_section(name):
     """Drop a registered section (tests / unloading subsystems)."""
-    _sections[:] = [s for s in _sections if s[0] != name]
+    _sections.pop(name, None)
 
 
 def section_names():
-    return [n for n, _p, _t in _sections]
+    return sorted(_sections)
+
+
+def _read_sections(reset):
+    """[(name, stats, table)] of every section that has something to
+    say, in name order; under ``reset`` each is zeroed once read."""
+    out = []
+    for name in section_names():
+        stats_fn, reset_fn, table = _sections[name]
+        stats = stats_fn()
+        if reset:
+            reset_fn()
+        if stats is not None:
+            out.append((name, stats, table))
+    return out
 
 
 def sections(reset=False):
-    """Public snapshot of every loaded section: ``{name: stats}`` —
+    """Public snapshot of every registered section: ``{name: stats}`` —
     the dict ``dumps()`` embeds and the /metrics collector exports."""
-    return _section_data(reset)
-
-
-def _section_data(reset=False):
-    out = {}
-    for name, provider, _table in list(_sections):
-        stats = provider(reset)
-        if stats is not None:
-            out[name] = stats
-    return out
+    return {name: stats for name, stats, _table in _read_sections(reset)}
 
 
 def _section_tables(reset=False):
     lines = []
-    for _name, provider, table in list(_sections):
-        stats = provider(reset)
-        if stats is None or table is None:
-            continue
-        lines.append("")
-        lines.extend(table(stats))
+    for _name, stats, table in _read_sections(reset):
+        if table is not None:
+            lines.append("")
+            lines.extend(table(stats))
     return lines
 
 
-def _rows_table(title, rows):
+def rows_table(title, rows):
     """Standard section renderer: a title plus label/value rows."""
     def render(stats):
         out = [title + ":"]
@@ -532,177 +299,41 @@ def _rows_table(title, rows):
     return render
 
 
-def _flash_attention_table(stats):
-    out = ["Flash Attention (kernels built at trace time):"]
-    for label, key in (("kernels", "kernels"),
-                       ("resident (K/V in VMEM)", "resident"),
-                       ("streamed (K/V swept by the grid)", "streamed"),
-                       ("grouped (shared K/V heads, window)", "grouped")):
-        out.append(f"{label:<40}{stats[key]:>12}")
-    for row in sorted(stats["built"]):
-        out.append(f"  {row}  x{stats['built'][row]}")
-    out.append(f"{'(out, lse) pairs named for remat':<40}"
-               f"{stats['residuals_named']:>12}")
-    for row in sorted(stats["residual_pairs"]):
-        out.append(f"  named {row}  x{stats['residual_pairs'][row]}  "
-                   f"{stats['residual_bytes'][row]} bytes")
-    return out
-
-
-_data_parallel_step_rows = _rows_table(
-    "Data-Parallel Step (host side)",
-    (("steps", "steps"),
-     ("trainers built", "builds"),
-     ("batch put (ms)", "put_ms"),
-     ("key and scalars (ms)", "args_ms"),
-     ("step enqueue (ms)", "enqueue_ms"),
-     ("bytes put", "put_bytes"),
-     ("remat: children checkpointed", "remat_children")))
-
-
-def _data_parallel_step_table(stats):
-    out = _data_parallel_step_rows(stats)
-    for name in sorted(stats["remat_saves"]):
-        out.append(f"{'remat keeps[' + name + '] (trainers)':<40}"
-                   f"{stats['remat_saves'][name]:>12}")
-    return out
-
-
-def _resilience_table(stats):
-    out = ["Resilience (supervisor):"]
-    for label, key in (("restarts", "restarts"),
-                       ("fallback restores", "fallback_restores"),
-                       ("watchdog fires", "watchdog_fires"),
-                       ("time lost (ms)", "time_lost_ms"),
-                       ("elastic resizes", "resizes"),
-                       ("ranks lost", "ranks_lost"),
-                       ("reshard (ms)", "reshard_ms")):
-        out.append(f"{label:<40}{stats[key]:>12}")
-    for cls in sorted(stats["retries"]):
-        out.append(f"{'retries[' + cls + ']':<40}"
-                   f"{stats['retries'][cls]:>12}")
-    return out
-
-
-register_section("cachedGraph", _graph_cache_counters, _rows_table(
-    "Compiled-Graph Cache (CachedOp)",
-    (("graph compiles (new signature)", "compiles"),
-     ("graph reuses (cache hit)", "reuses"))))
-register_section("trainerStep", _trainer_step_counters, _rows_table(
-    "Trainer Step Fusion",
-    (("steps", "steps"),
-     ("params fused", "params_fused"),
-     ("allreduce buckets built", "buckets_built"),
-     ("dispatches per step", "dispatches_per_step"),
-     ("whole-step compiled steps", "whole_step_steps"),
-     ("whole-step compiles", "whole_step_compiles"),
-     ("whole-step fallbacks", "whole_step_fallbacks"),
-     ("zero-sharded steps", "zero_steps"),
-     ("zero-shard fallbacks", "zero_fallbacks"),
-     ("spmd mesh steps", "spmd_steps"))))
-register_section("dataParallelStep", _data_parallel_step_counters,
-                 _data_parallel_step_table)
-register_section("flashAttention", _flash_attention_counters,
-                 _flash_attention_table)
-register_section("moeRouting", _moe_routing_counters, _moe_routing_table)
-register_section("dataPipeline", _data_pipeline_counters, _rows_table(
-    "Data Pipeline",
-    (("batches delivered", "batches"),
-     ("host build (ms)", "host_build_ms"),
-     ("h2d staging (ms)", "h2d_ms"),
-     ("step wait-on-input (ms)", "wait_ms"),
-     ("prefetch hits", "prefetch_hits"),
-     ("prefetch misses", "prefetch_misses"))))
-register_section("resilience", _resilience_counters, _resilience_table)
-register_section("decodeServe", _decode_serve_counters, _rows_table(
-    "Decode Serving (continuous batching)",
-    (("decode steps", "steps"),
-     ("tokens generated", "tokens"),
-     ("prefill batches", "prefill_batches"),
-     ("requests admitted", "admitted"),
-     ("requests finished", "finished"),
-     ("deadline expiries", "expired_deadlines"),
-     ("slot occupancy (mean live/max)", "slot_occupancy"),
-     ("pages in flight", "pages_in_flight"),
-     ("copy-on-write page copies", "cow_copies"),
-     ("prefix pages shared (hits)", "prefix_hit_pages"),
-     ("draft proposal steps", "draft_steps"),
-     ("draft tokens proposed", "spec_proposed"),
-     ("draft tokens accepted", "spec_accepted"))))
-register_section("router", _router_counters, _rows_table(
-    "Serve Router (replica pool)",
-    (("requests dispatched", "dispatched"),
-     ("re-dispatches (retries)", "retries"),
-     ("hedged dispatches", "hedges"),
-     ("hedge wins", "hedge_wins"),
-     ("replica evictions", "evictions"),
-     ("warm replacements admitted", "replacements"),
-     ("health probes", "probes"),
-     ("health probe failures", "probe_failures"),
-     ("rolling-reload legs", "reloads"))))
-register_section("ctrl", _ctrl_counters, _rows_table(
-    "Serving Control Plane",
-    (("autoscaler ticks", "ticks"),
-     ("scale-ups", "scale_ups"),
-     ("scale-downs", "scale_downs"),
-     ("actions blocked by cooldown", "blocked_cooldown"),
-     ("actions blocked by bounds", "blocked_bounds"),
-     ("replica processes spawned", "spawns"),
-     ("replica spawn failures", "spawn_failures"),
-     ("replicas drained and retired", "retired"),
-     ("rpc requests served", "rpc_requests"),
-     ("rpc streams opened", "rpc_streams"),
-     ("rpc errors", "rpc_errors"),
-     ("stale leases rejected", "stale_leases_rejected"),
-     ("pool size (last tick)", "replicas"),
-     ("mean occupancy (last tick)", "load"))))
-register_section("quantize", _quantize_counters, _rows_table(
-    "INT8 Quantization",
-    (("layers quantized", "layers_quantized"),
-     ("calibration batches", "calib_batches"),
-     ("calibration time (ms)", "calib_ms"),
-     ("requantize folds", "requant_folds"),
-     ("int8 serve batches", "int8_serve_batches"))))
-register_section("health", _health_counters, _rows_table(
-    "Health Monitor",
-    (("steps observed", "steps"),
-     ("step time (ms)", "step_ms"),
-     ("input wait (ms)", "input_wait_ms"),
-     ("h2d staging (ms)", "h2d_ms"),
-     ("compute (ms)", "compute_ms"),
-     ("collective (ms)", "collective_ms"),
-     ("optimizer (ms)", "optimizer_ms"),
-     ("checkpoint stall (ms)", "checkpoint_ms"),
-     ("compile (ms)", "compile_ms"),
-     ("lost to recovery (ms)", "lost_ms"),
-     ("monitor ticks", "ticks"),
-     ("SLO alerts fired", "alerts"),
-     ("stragglers flagged", "stragglers"),
-     ("rules firing now", "rules_firing"),
-     ("goodput (last window)", "goodput"),
-     ("MFU (last window)", "mfu"),
-     ("FLOPs per step", "flops_per_step"),
-     ("step p95 (ms)", "step_p95_ms"))))
-register_section("tune", _tune_counters, _rows_table(
-    "Autotuner",
-    (("trials run", "trials"),
-     ("measurement windows", "measurements"),
-     ("recompiles spent", "recompiles_spent"),
-     ("candidates cost-model ranked", "candidates_ranked"),
-     ("restart-class moves blocked", "blocked_moves"),
-     ("knobs moved", "knobs_moved"),
-     ("baseline score", "baseline_score"),
-     ("best score", "best_score"),
-     ("best/baseline ratio", "best_over_baseline"))))
-register_section("telemetry", _telemetry_counters, _rows_table(
-    "Telemetry (tracer / flight recorder / metrics)",
-    (("spans recorded", "spans"),
-     ("instant events", "instants"),
-     ("request spans opened", "requests"),
-     ("events dropped (lane cap)", "dropped"),
-     ("flight-recorder dumps", "flight_dumps"),
-     ("/metrics scrapes", "scrapes"),
-     ("aggregate() calls", "aggregations"))))
+# the two sections whose counters live BELOW this module (it imports
+# telemetry.health and telemetry.tracer for its hooks; they cannot
+# import it back): registered here, a downward edge
+register_section(
+    "health", _health.health_stats, _health.reset_health_stats, rows_table(
+        "Health Monitor",
+        (("steps observed", "steps"),
+         ("step time (ms)", "step_ms"),
+         ("input wait (ms)", "input_wait_ms"),
+         ("h2d staging (ms)", "h2d_ms"),
+         ("compute (ms)", "compute_ms"),
+         ("collective (ms)", "collective_ms"),
+         ("optimizer (ms)", "optimizer_ms"),
+         ("checkpoint stall (ms)", "checkpoint_ms"),
+         ("compile (ms)", "compile_ms"),
+         ("lost to recovery (ms)", "lost_ms"),
+         ("monitor ticks", "ticks"),
+         ("SLO alerts fired", "alerts"),
+         ("stragglers flagged", "stragglers"),
+         ("rules firing now", "rules_firing"),
+         ("goodput (last window)", "goodput"),
+         ("MFU (last window)", "mfu"),
+         ("FLOPs per step", "flops_per_step"),
+         ("step p95 (ms)", "step_p95_ms"))))
+register_section(
+    "telemetry", _tracer.telemetry_stats, _tracer.reset_telemetry_stats,
+    rows_table(
+        "Telemetry (tracer / flight recorder / metrics)",
+        (("spans recorded", "spans"),
+         ("instant events", "instants"),
+         ("request spans opened", "requests"),
+         ("events dropped (lane cap)", "dropped"),
+         ("flight-recorder dumps", "flight_dumps"),
+         ("/metrics scrapes", "scrapes"),
+         ("aggregate() calls", "aggregations"))))
 
 
 def dumps(reset=False, format="json"):
@@ -727,9 +358,8 @@ def dumps(reset=False, format="json"):
             data["memoryPeaks"] = dict(_mem_peak)
         if reset:
             _events.clear()
-    # every registered counter section, reset forwarded so a reset
-    # dump window-scopes ALL of them (MXA403 checks this mechanically)
-    data.update(_section_data(reset))
+    # every registered counter section, window-scoped with the events
+    data.update(sections(reset))
     return json.dumps(data)
 
 

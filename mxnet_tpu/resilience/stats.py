@@ -14,6 +14,8 @@ from __future__ import annotations
 
 import threading
 
+from .. import profiler
+
 _lock = threading.Lock()
 _stats = {
     "restarts": 0,          # train_fn re-invocations (any fault class)
@@ -57,3 +59,26 @@ def reset_resilience_stats():
                 _stats[k] = {}
             else:
                 _stats[k] = 0.0 if isinstance(_stats[k], float) else 0
+
+
+_rows = profiler.rows_table(
+    "Resilience (supervisor)",
+    (("restarts", "restarts"),
+     ("fallback restores", "fallback_restores"),
+     ("watchdog fires", "watchdog_fires"),
+     ("time lost (ms)", "time_lost_ms"),
+     ("elastic resizes", "resizes"),
+     ("ranks lost", "ranks_lost"),
+     ("reshard (ms)", "reshard_ms")))
+
+
+def _table(stats):
+    out = _rows(stats)
+    for cls in sorted(stats["retries"]):
+        out.append(f"{'retries[' + cls + ']':<40}"
+                   f"{stats['retries'][cls]:>12}")
+    return out
+
+
+profiler.register_section("resilience", resilience_stats,
+                          reset_resilience_stats, _table)

@@ -38,9 +38,11 @@ from __future__ import annotations
 
 import threading
 
+from ... import profiler
+
 # ---------------------------------------------------------------------------
 # window-scoped module counters: the profiler's `ctrl` section
-# (provider: profiler._ctrl_counters; exported to /metrics as
+# (registered below; exported to /metrics as
 # mxtpu_ctrl_* gauges by the section collector)
 
 _sec_lock = threading.Lock()
@@ -76,6 +78,25 @@ def reset_ctrl_stats():
     with _sec_lock:
         for k in _sec:
             _sec[k] = 0.0 if k == "load" else 0
+
+
+profiler.register_section(
+    "ctrl", ctrl_stats, reset_ctrl_stats, profiler.rows_table(
+        "Serving Control Plane",
+        (("autoscaler ticks", "ticks"),
+         ("scale-ups", "scale_ups"),
+         ("scale-downs", "scale_downs"),
+         ("actions blocked by cooldown", "blocked_cooldown"),
+         ("actions blocked by bounds", "blocked_bounds"),
+         ("replica processes spawned", "spawns"),
+         ("replica spawn failures", "spawn_failures"),
+         ("replicas drained and retired", "retired"),
+         ("rpc requests served", "rpc_requests"),
+         ("rpc streams opened", "rpc_streams"),
+         ("rpc errors", "rpc_errors"),
+         ("stale leases rejected", "stale_leases_rejected"),
+         ("pool size (last tick)", "replicas"),
+         ("mean occupancy (last tick)", "load"))))
 
 
 from .autoscale import Autoscaler                          # noqa: E402

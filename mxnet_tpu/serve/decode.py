@@ -132,7 +132,7 @@ STREAM_DONE = _DONE
 
 # ---------------------------------------------------------------------------
 # window-scoped module counters: the profiler's `decodeServe` section
-# (provider: profiler._decode_serve_counters; exported to /metrics as
+# (registered below; exported to /metrics as
 # mxtpu_decode_serve_* gauges by the section collector)
 
 _sec_lock = threading.Lock()
@@ -172,6 +172,25 @@ def reset_decode_serve_stats():
     with _sec_lock:
         for k in _sec:
             _sec[k] = 0.0 if k == "occ_ratio_sum" else 0
+
+
+profiler.register_section(
+    "decodeServe", decode_serve_stats, reset_decode_serve_stats,
+    profiler.rows_table(
+        "Decode Serving (continuous batching)",
+        (("decode steps", "steps"),
+         ("tokens generated", "tokens"),
+         ("prefill batches", "prefill_batches"),
+         ("requests admitted", "admitted"),
+         ("requests finished", "finished"),
+         ("deadline expiries", "expired_deadlines"),
+         ("slot occupancy (mean live/max)", "slot_occupancy"),
+         ("pages in flight", "pages_in_flight"),
+         ("copy-on-write page copies", "cow_copies"),
+         ("prefix pages shared (hits)", "prefix_hit_pages"),
+         ("draft proposal steps", "draft_steps"),
+         ("draft tokens proposed", "spec_proposed"),
+         ("draft tokens accepted", "spec_accepted"))))
 
 
 _donate_ok = None
@@ -565,8 +584,8 @@ class DecodeServer:
         ``"continuous"`` (the point of this class) backfills free slots
         between tokens.  ``"batch"`` only admits when the arena is
         EMPTY — whole-batch decode semantics, every sequence waits for
-        the batch's straggler — kept as the honest A/B baseline for
-        ``bench.py serve_decode`` and the parity tests.
+        the batch's straggler — kept as the baseline of the parity
+        tests.
     ctx : Context, optional
     checkpoint : CheckpointManager or str, optional
         Source for ``reload_weights()``.
@@ -1775,9 +1794,9 @@ class TinyDecoder(Block):
     exercises the arena exactly like a transformer KV cache while
     staying a two-matmul CPU-friendly graph.
 
-    Used by tests/test_decode.py, tools/decode_smoke.py, and the
-    ``bench.py serve_decode`` leaf; it doubles as the executable
-    documentation of the decode model contract.  Math notes:
+    Used by tests/test_decode.py and tools/decode_smoke.py; it doubles
+    as the executable documentation of the decode model contract.  Math
+    notes:
 
     - every per-slot quantity depends only on that slot's row, so
       continuous vs whole-batch decode is bit-identical by construction

@@ -62,7 +62,7 @@ from concurrent.futures import TimeoutError as _FutureTimeout
 
 import numpy as np
 
-from .. import engine
+from .. import engine, profiler
 from ..base import MXNetError, getenv
 from ..log import get_logger
 from ..resilience.retry import RetryPolicy
@@ -101,7 +101,7 @@ EVICTED = "evicted"        # circuit breaker tripped; being replaced
 
 # ---------------------------------------------------------------------------
 # window-scoped module counters: the profiler's `router` section
-# (provider: profiler._router_counters; exported to /metrics as
+# (registered below; exported to /metrics as
 # mxtpu_router_* gauges by the section collector)
 
 _sec_lock = threading.Lock()
@@ -127,6 +127,20 @@ def reset_router_stats():
     with _sec_lock:
         for k in _sec:
             _sec[k] = 0
+
+
+profiler.register_section(
+    "router", router_stats, reset_router_stats, profiler.rows_table(
+        "Serve Router (replica pool)",
+        (("requests dispatched", "dispatched"),
+         ("re-dispatches (retries)", "retries"),
+         ("hedged dispatches", "hedges"),
+         ("hedge wins", "hedge_wins"),
+         ("replica evictions", "evictions"),
+         ("warm replacements admitted", "replacements"),
+         ("health probes", "probes"),
+         ("health probe failures", "probe_failures"),
+         ("rolling-reload legs", "reloads"))))
 
 
 # ---------------------------------------------------------------------------

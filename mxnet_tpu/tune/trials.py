@@ -11,8 +11,7 @@ trusts:
    step_p95_ms, tokens_per_s, fill ratio — whatever the objective
    reads),
 4. debit the recompiles the move triggered against the measured score,
-5. append a bit-replayable JSONL record (``BENCH_HISTORY.jsonl`` style,
-   readable by ``tools/bench_diff.py --file``).
+5. append a bit-replayable JSONL record (one object a line).
 
 Records carry no wallclock and every float is written as repr'd JSON
 with sorted keys, so re-running the same seed over the same surface
@@ -26,6 +25,7 @@ from __future__ import annotations
 
 import json
 
+from .. import profiler
 from ..base import MXNetError, getenv
 
 __all__ = ["TrialRunner", "default_objective", "profiler_compiles",
@@ -33,8 +33,8 @@ __all__ = ["TrialRunner", "default_objective", "profiler_compiles",
 
 
 # ---------------------------------------------------------------------------
-# tune section counters (window-scoped; profiler._tune_counters proxies
-# here, the /metrics section collector exports them as mxtpu_tune_*)
+# tune section counters (window-scoped, registered below; the /metrics
+# section collector exports them as mxtpu_tune_*)
 
 
 def _zero():
@@ -65,6 +65,20 @@ def reset_tune_stats():
     _counters.update(_zero())
 
 
+profiler.register_section(
+    "tune", tune_stats, reset_tune_stats, profiler.rows_table(
+        "Autotuner",
+        (("trials run", "trials"),
+         ("measurement windows", "measurements"),
+         ("recompiles spent", "recompiles_spent"),
+         ("candidates cost-model ranked", "candidates_ranked"),
+         ("restart-class moves blocked", "blocked_moves"),
+         ("knobs moved", "knobs_moved"),
+         ("baseline score", "baseline_score"),
+         ("best score", "best_score"),
+         ("best/baseline ratio", "best_over_baseline"))))
+
+
 def _note_scores(baseline, best):
     _counters["baseline_score"] = float(baseline)
     _counters["best_score"] = float(best)
@@ -81,8 +95,6 @@ def profiler_compiles():
     graph-cache compiles (CachedOp signatures) plus whole-step
     compiles.  The trial runner diffs this around each measurement
     window to debit what a knob move actually cost."""
-    from .. import profiler
-
     total = 0
     data = profiler.sections(reset=False)
     graph = data.get("cachedGraph")

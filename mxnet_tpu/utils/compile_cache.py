@@ -4,9 +4,10 @@ If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and the
 program sets no directory in code.  Otherwise the cache is
 ``<checkout>/.jax_cache`` — a fixed path, because the path is part of
 the cache key: a directory named after a pid, a time or a temp name
-never hits.  Every entry point that wants warm restarts (chip_smoke.py,
-bench.py, tools/bench_workloads.py) calls :func:`enable` once, before
-its first compile."""
+never hits.  An entry point that wants warm restarts (chip_smoke.py)
+calls :func:`enable` once, before its first compile;
+benchmarks/run.py takes the environment branch: it sets the variable to
+the same directory before it imports jax."""
 from __future__ import annotations
 
 import os

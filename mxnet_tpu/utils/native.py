@@ -69,6 +69,9 @@ def load():
         ctypes.POINTER(ctypes.c_float)]
     lib.MXTPUImagePipelineNumBatches.restype = ctypes.c_uint64
     lib.MXTPUImagePipelineNumBatches.argtypes = [ctypes.c_void_p]
+    lib.MXTPUImagePipelineDecodedBy.restype = ctypes.c_uint64
+    lib.MXTPUImagePipelineDecodedBy.argtypes = [ctypes.c_void_p,
+                                                ctypes.c_int]
     lib.MXTPUImagePipelineFree.argtypes = [ctypes.c_void_p]
     _lib = lib
     return _lib
@@ -105,6 +108,7 @@ class NativeImagePipeline:
             int(resize_short), mean_arr, std_arr, seed, aug)
         assert self._handle, f"failed to open {rec_path}"
         self._epoch = 0
+        self._num_threads = num_threads
         self._data_buf = np.empty(self._shape, np.float32)
         self._label_buf = np.empty(batch_size, np.float32)
 
@@ -120,6 +124,11 @@ class NativeImagePipeline:
         if n == 0:
             return None
         return self._data_buf.copy(), self._label_buf.copy()
+
+    def decoded_by(self):
+        """Records each worker of the decode pool has decoded so far."""
+        return [int(self._lib.MXTPUImagePipelineDecodedBy(self._handle, t))
+                for t in range(self._num_threads)]
 
     def __del__(self):
         try:

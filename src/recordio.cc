@@ -210,6 +210,9 @@ struct ImagePipeline {
   size_t max_queue = 4;
   std::atomic<bool> stop{false};
   std::atomic<int> active_workers{0};
+  // records each worker has decoded since the pipeline was made;
+  // guarded by mu
+  std::vector<uint64_t> decoded_by;
   uint64_t epoch_seed;
 
   ~ImagePipeline() { Shutdown(); }
@@ -514,6 +517,7 @@ struct ImagePipeline {
         break;
       }
       ready[b] = batch;
+      decoded_by[tid] += bs;
       cv_ready.notify_all();
     }
     if (active_workers.fetch_sub(1) == 1) cv_ready.notify_all();
@@ -523,6 +527,7 @@ struct ImagePipeline {
     stop.store(false);
     cursor.store(0);
     active_workers.store(cfg.num_threads);
+    decoded_by.resize(cfg.num_threads, 0);
     for (int t = 0; t < cfg.num_threads; ++t) {
       workers.emplace_back(&ImagePipeline::WorkerLoop, this, t);
     }
@@ -752,6 +757,14 @@ int MXTPUImagePipelineNext(void* handle, float* out_data,
 uint64_t MXTPUImagePipelineNumBatches(void* handle) {
   auto* p = static_cast<ImagePipeline*>(handle);
   return p->offsets.size() / p->cfg.batch_size;
+}
+
+// records worker `tid` of the decode pool has decoded so far
+uint64_t MXTPUImagePipelineDecodedBy(void* handle, int tid) {
+  auto* p = static_cast<ImagePipeline*>(handle);
+  std::lock_guard<std::mutex> lk(p->mu);
+  if (tid < 0 || static_cast<size_t>(tid) >= p->decoded_by.size()) return 0;
+  return p->decoded_by[tid];
 }
 
 void MXTPUImagePipelineFree(void* handle) {

@@ -312,63 +312,26 @@ def test_env_lints(tmp_path):
         "missing:MISSING", "protocol:DMLC_THING", "raw:MXTPU_RAW"]
 
 
-def test_profiler_window_scope_lint(tmp_path):
-    """Registry-era MXA403: an unregistered provider, a provider that
-    ignores reset, and an output path not forwarding reset into the
-    registry iterator each fire; the clean shapes stay silent."""
-    findings = _run(tmp_path, {"profiler.py": (
-        "_sections = []\n"
-        "def register_section(name, provider, table=None):\n"
-        "    _sections.append((name, provider, table))\n"
-        "def _section_data(reset=False):\n"
-        "    return {n: p(reset) for n, p, _t in _sections}\n"
-        "def _good_counters(reset=False):\n"
-        "    stats = {'n': 1}\n"
-        "    if reset:\n"
-        "        _reset_good()\n"
-        "    return stats\n"
-        "def _reset_good():\n"
-        "    pass\n"
-        "def _bad_counters(reset=False):\n"
-        "    return {'n': 2}\n"
-        "def _orphan_counters(reset=False):\n"
-        "    stats = {'n': 3}\n"
-        "    if reset:\n"
-        "        _reset_good()\n"
-        "    return stats\n"
-        "register_section('goodSection', _good_counters)\n"
-        "register_section('badSection', _bad_counters)\n"
-        "def dumps(reset=False):\n"
-        "    return _section_data(reset)\n"
-        "def _aggregate_table(reset=False):\n"
-        "    return (_section_data(True), _good_counters(reset))\n")},
-        docs={"observability.md": "goodSection badSection\n"},
-        passes=["invariants"])
-    assert _codes(findings) == ["MXA403", "MXA403", "MXA403"]
-    syms = sorted(f.symbol for f in findings)
-    assert syms == ["_aggregate_table:_section_data", "_bad_counters",
-                    "_orphan_counters"]
-
-
 def test_profiler_output_path_without_sections_flagged(tmp_path):
-    """dumps() that neither iterates the registry nor calls a provider
-    has silently lost every counter section."""
+    """MXA403: dumps() that never reads the registry has silently lost
+    every counter section, and an output path that reads it without
+    forwarding reset window-scopes none; the clean shape is silent."""
     findings = _run(tmp_path, {"profiler.py": (
-        "def register_section(name, provider, table=None):\n"
-        "    pass\n"
-        "def _good_counters(reset=False):\n"
-        "    if reset:\n"
-        "        _reset_good()\n"
+        "_sections = {}\n"
+        "def register_section(name, stats, reset, table=None):\n"
+        "    _sections[name] = (stats, reset, table)\n"
+        "def sections(reset=False):\n"
         "    return {}\n"
-        "def _reset_good():\n"
-        "    pass\n"
-        "register_section('goodSection', _good_counters)\n"
+        "def _section_tables(reset=False):\n"
+        "    return []\n"
         "def dumps(reset=False):\n"
-        "    return '{}'\n")},
-        docs={"observability.md": "goodSection\n"},
+        "    return '{}'\n"
+        "def _aggregate_table(reset=False):\n"
+        "    return (_section_tables(True), sections(reset))\n")},
         passes=["invariants"])
-    assert _codes(findings) == ["MXA403"]
-    assert findings[0].symbol == "dumps:<no-sections>"
+    assert _codes(findings) == ["MXA403", "MXA403"]
+    assert sorted(f.symbol for f in findings) == [
+        "_aggregate_table:_section_tables", "dumps:<no-sections>"]
 
 
 def test_fault_point_catalog_lint(tmp_path):
@@ -410,25 +373,30 @@ def test_telemetry_catalog_lint(tmp_path):
 
 
 def test_section_registration_catalog_lint(tmp_path):
+    """MXA405 finds a section where its owner registers it, in
+    whichever module of the package that is."""
     files = {"profiler.py": (
-        "def register_section(name, provider, table=None):\n"
-        "    pass\n"
-        "def _known_counters(reset=False):\n"
-        "    if reset:\n"
-        "        _reset()\n"
-        "    return {}\n"
-        "def _reset():\n"
+        "def register_section(name, stats, reset, table=None):\n"
         "    pass\n"
         "def dumps(reset=False):\n"
-        "    return _section_data(reset)\n"
-        "def _section_data(reset=False):\n"
+        "    return sections(reset)\n"
+        "def sections(reset=False):\n"
+        "    return {}\n"),
+        "owner.py": (
+        "from . import profiler\n"
+        "def owner_stats():\n"
         "    return {}\n"
-        "register_section('knownSection', _known_counters)\n"
-        "register_section('unknownSection', _known_counters)\n")}
+        "def reset_owner_stats():\n"
+        "    pass\n"
+        "profiler.register_section('knownSection', owner_stats,\n"
+        "                          reset_owner_stats)\n"
+        "profiler.register_section('unknownSection', owner_stats,\n"
+        "                          reset_owner_stats)\n")}
     docs = {"observability.md": "the `knownSection` section\n"}
     findings = _run(tmp_path, files, docs=docs, passes=["invariants"])
     assert _codes(findings) == ["MXA405"]
     assert findings[0].symbol == "<module>:unknownSection"
+    assert findings[0].path.endswith("owner.py")
 
 
 # ---------------------------------------------------------------------------

@@ -209,7 +209,7 @@ def test_whole_graph_aot(one_chip, family):
 # the main path's kernels at real widths
 # ---------------------------------------------------------------------------
 
-BERT_ATTN = (64, 12, 128, 64)   # bench/chip_smoke batch x BERT-base heads
+BERT_ATTN = (64, 12, 128, 64)   # chip_smoke's batch x BERT-base heads
 
 
 @pytest.mark.parametrize("shape,dt", [

@@ -1,8 +1,9 @@
 """The gates that keep a run from hiding the device (PR 22): the chip
-smoke and the bench fail without a TPU instead of switching to the CPU,
-unknown devices have no peak, the compile cache has one rule, a native
-build that fails says so, and arrays a model creates for itself follow
-its inputs rather than the default (host) context."""
+smoke fails without a TPU instead of switching to the CPU, unknown
+devices have no peak in the benchmark's table nor the health
+monitor's, the compile cache has one rule, a native build that fails
+says so, and arrays a model creates for itself follow its inputs rather
+than the default (host) context."""
 import importlib.util
 import os
 import subprocess
@@ -39,34 +40,35 @@ def test_chip_smoke_has_no_cpu_option():
     assert r.returncode != 0 and '"ok"' not in r.stdout
 
 
-def test_bench_leaf_fails_when_the_platform_is_not_what_was_asked():
-    """bench.py's platform is what the caller asked for: a tpu leaf on
-    a CPU-only process fails with the cause and prints no record."""
-    r = _run("bench.py", "--leaf", "tpu", "--model", "serve")
-    assert r.returncode != 0
-    assert "asked for platform 'tpu'" in r.stderr
-    assert "{" not in r.stdout
-
-
-def _bench():
+def _benchmark_device():
     spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(REPO, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    return bench
+        "benchmark_device",
+        os.path.join(REPO, "benchmarks", "harness", "device.py"))
+    device = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(device)
+    return device
 
 
 @pytest.mark.parametrize("kind", ["TPU v5", "TPU v7x", "Quantum9000"])
-def test_bench_peaks_reject_unknown_device_kind(kind):
-    """A bare 'v5' used to inherit the v5p peak; an unknown device is
-    an error, not a default."""
-    bench = _bench()
-    assert bench._peak_flops("TPU v5 lite") == 197e12
-    assert bench._hbm_bw("TPU v5 lite") == 819e9
-    with pytest.raises(ValueError, match="no published peak"):
-        bench._peak_flops(kind)
-    with pytest.raises(ValueError, match="no published peak"):
-        bench._hbm_bw(kind)
+def test_benchmark_peaks_reject_unknown_device_kind(kind, monkeypatch):
+    """The benchmark's table, the one the driver's numbers are divided
+    by: a bare 'v5' must not inherit another chip's peak; an unknown
+    device is an error, not a default."""
+    device = _benchmark_device()
+    table = device.peaks_table()
+    assert table["TPU v5 lite"]["peak_flops_bf16"] == 197e12
+    assert table["TPU v5 lite"]["peak_hbm_bytes_per_s"] == 819e9
+    assert kind not in table
+
+    class Dev:
+        platform = "tpu"
+        device_kind = kind
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [Dev()])
+    with pytest.raises(device.NoChip, match=r"peaks\.json"):
+        device.require_chips(1)
+    Dev.device_kind = "TPU v5 lite"
+    assert device.require_chips(1)["peaks"] == table["TPU v5 lite"]
 
 
 def test_health_peak_rejects_unknown_accelerator(monkeypatch):

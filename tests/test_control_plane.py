@@ -52,7 +52,7 @@ def decode_pair():
     bit-identical replica pool every cross-process test rides.  Tests
     must NOT shut the routers down (that would shut down the shared
     servers through the wire); they drop their client connections
-    instead."""
+    instead, and build their routers with `_router`."""
     pair = []
     for _ in range(2):
         srv = _decode_server(seed=4)
@@ -71,6 +71,19 @@ def _remotes(decode_pair):
 def _drop(replicas):
     for rr in replicas:
         rr._teardown(RPCConnectionError("test teardown"))
+
+
+def _router(replicas):
+    """A router over the SHARED pair.  An evicted `RemoteReplica` is
+    shut down through the wire, over a fresh connection, and that stops
+    the module's server for every later test (`ServerClosedError` in
+    `test_decode_handle_sink_replays_history`, whenever a cut wire
+    found three requests in flight on its replica): no burst here may
+    reach the eviction count."""
+    router = serve.Router(servers=replicas, health_sec=0.0,
+                          evict_after=10 ** 6)
+    router.start()
+    return router
 
 
 # ---------------------------------------------------------------------------
@@ -206,8 +219,7 @@ def test_midstream_connection_kill_fails_over(decode_pair):
     bit-identical to a single-server run."""
     srv0, _ = decode_pair[0]
     replicas = _remotes(decode_pair)
-    router = serve.Router(servers=replicas, health_sec=0.0)
-    router.start()
+    router = _router(replicas)
     try:
         prompt = np.array([1, 2, 3], np.int32)
         ref = [int(t) for t in srv0.generate(prompt, max_new_tokens=6,
@@ -247,8 +259,7 @@ def test_slow_consumer_does_not_block_others(decode_pair):
     ignoring stream A must not stall stream B's tokens (the demux
     drains the socket unconditionally into per-request queues)."""
     replicas = _remotes(decode_pair)[:1]
-    router = serve.Router(servers=replicas, health_sec=0.0)
-    router.start()
+    router = _router(replicas)
     try:
         slow = router.submit_stream(np.array([1, 2, 3], np.int32),
                                     max_new_tokens=8)
@@ -432,8 +443,7 @@ def test_requests_lost_zero_across_connection_kill(decode_pair):
     exactly zero, and survivors' results stay bit-identical."""
     srv0, _ = decode_pair[0]
     replicas = _remotes(decode_pair)
-    router = serve.Router(servers=replicas, health_sec=0.0)
-    router.start()
+    router = _router(replicas)
     try:
         rng = np.random.RandomState(11)
         prompts = [rng.randint(0, VOCAB, size=int(rng.randint(2, 7)))
@@ -461,6 +471,42 @@ def test_requests_lost_zero_across_connection_kill(decode_pair):
         # the books balance by construction, not by luck:
         assert s["submitted"] == 6
         assert s["failed"] == 0
+    finally:
+        _drop(replicas)
+
+
+def test_wire_cut_under_a_full_burst_leaves_the_shared_server_up(
+        decode_pair):
+    """Eight requests all in flight (the decode loops stalled) when
+    replica 0's wire is cut: four fail on it at once, more than the
+    default `evict_after`.  Every one is still served by re-dispatch,
+    none is lost, nothing is evicted, and the shared server takes the
+    next request: what `_router` is for."""
+    srv0, _ = decode_pair[0]
+    replicas = _remotes(decode_pair)
+    router = _router(replicas)
+    try:
+        rng = np.random.RandomState(12)
+        prompts = [rng.randint(0, VOCAB, size=int(rng.randint(2, 7)))
+                   .astype(np.int32) for _ in range(8)]
+        stall = faults.FaultPlan([{"site": "serve.decode",
+                                   "action": "stall", "delay_s": 0.2,
+                                   "times": None}])
+        with faults.armed(stall):
+            futs = [router.submit(p, max_new_tokens=4) for p in prompts]
+            assert len(replicas[0]._pending) >= 3
+            plan = faults.FaultPlan([{"site": "serve.rpc.send",
+                                      "action": "raise",
+                                      "match": {"replica": 0}}])
+            with faults.armed(plan):
+                with pytest.raises(mx.MXNetError):
+                    replicas[0].ping()
+        assert all(len(f.result(timeout=120)) == 4 for f in futs)
+        s = router.stats()
+        assert s["served"] == 8 and s["requests_lost"] == 0
+        assert s["retries"] >= 3 and s["evictions"] == 0
+        assert len(srv0.generate(prompts[0], max_new_tokens=2,
+                                 timeout=60)) == 2
     finally:
         _drop(replicas)
 

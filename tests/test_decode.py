@@ -77,9 +77,9 @@ def test_parity_continuous_vs_whole_batch_decode():
     assert all(len(seq) == m for seq, m in zip(cont, budgets))
     # (the scheduling win itself — fewer step dispatches per token —
     # is asserted under saturated load in
-    # test_staggered_admission_zero_compiles_exact_dispatches and
-    # A/B-measured by `bench.py serve_decode`; at this trickle rate the
-    # arena runs far below capacity and step counts are arrival-bound)
+    # test_staggered_admission_zero_compiles_exact_dispatches; at this
+    # trickle rate the arena runs far below capacity and step counts
+    # are arrival-bound)
     assert s_cont["graph"]["post_warmup_compiles"] == 0
     assert s_whole["graph"]["post_warmup_compiles"] == 0
 

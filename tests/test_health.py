@@ -639,56 +639,3 @@ def test_watchdog_diagnostic_includes_health_snapshot():
         mon.disarm()
     # disarmed: the diagnostic stays the plain scope report
     assert "Last health window" not in sup._diagnose(1.0)
-
-
-# ---------------------------------------------------------------------------
-# bench trajectory differ
-
-
-def test_bench_diff_flags_regressions(tmp_path):
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_diff", os.path.join(os.path.dirname(__file__), "..",
-                                   "tools", "bench_diff.py"))
-    bd = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bd)
-
-    prev = {"records": {"serve": {"value": 100.0, "p99_ms": 10.0},
-                        "bert": {"value": 50.0}}}
-    new = {"records": {"serve": {"value": 50.0, "p99_ms": 30.0},
-                       "bert": {"value": 51.0}}}
-    hist = tmp_path / "BENCH_HISTORY.jsonl"
-    with open(hist, "w") as f:
-        f.write(json.dumps(prev) + "\n")
-        f.write(json.dumps(new) + "\n")
-    report = bd.diff_records(*bd.load_last_two(str(hist)),
-                             tolerance=0.10)
-    verdicts = {r["leaf"]: r["verdict"] for r in report}
-    assert verdicts["records.serve.value"] == "REGRESSED"    # halved rps
-    assert verdicts["records.serve.p99_ms"] == "REGRESSED"   # 3x p99
-    assert verdicts["records.bert.value"] == "ok"            # +2%
-    assert bd.has_regression(report)
-    # within tolerance both ways -> clean
-    report = bd.diff_records(prev, prev, tolerance=0.10)
-    assert not bd.has_regression(report)
-
-
-def test_bench_diff_falls_back_to_bench_r_files(tmp_path):
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_diff", os.path.join(os.path.dirname(__file__), "..",
-                                   "tools", "bench_diff.py"))
-    bd = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bd)
-    for i, v in ((1, 100.0), (2, 90.0)):
-        with open(tmp_path / f"BENCH_r0{i}.json", "w") as f:
-            json.dump({"n": i, "parsed": {
-                "records": {"serve": {"value": v}}}}, f)
-    prev, new = bd.load_last_two(str(tmp_path / "missing.jsonl"),
-                                 fallback_dir=str(tmp_path))
-    assert prev["records"]["serve"]["value"] == 100.0
-    assert new["records"]["serve"]["value"] == 90.0

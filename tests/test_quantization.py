@@ -177,7 +177,7 @@ def test_quantize_model_symbolic_conv_no_bias():
 
 def test_quantize_model_full_cnn_end_to_end(tmp_path):
     """A whole model-zoo CNN (export -> symbol -> quantize -> bind ->
-    forward), the bench_workloads quantized-leaf path in miniature."""
+    forward)."""
     from mxnet_tpu.gluon.model_zoo import vision
     from mxnet_tpu.symbol import load as sym_load
 

@@ -196,7 +196,7 @@ def test_tree_reduce_multi_device():
 def test_eager_dispatch_overhead_bounded():
     """SURVEY §3.1 names the per-op eager path THE overhead risk; the
     executable cache must keep cached dispatch under a loose wall-clock
-    bound (bench.py reports the precise figure per round)."""
+    bound."""
     import time
 
     a, b = nd.ones((8, 8)), nd.ones((8, 8))
@@ -339,38 +339,6 @@ def test_bucketing_repeat_bucket_no_recompile():
     assert after == baseline, (
         f"revisiting warm buckets compiled {after - baseline} new "
         f"executables (cache keying broke)")
-
-
-def test_bench_roofline_bound_computed():
-    """bench.py's roofline_mfu_bound must be COMPUTED from the step's
-    cost analysis (VERDICT r2 weak #3: the hardcoded 0.20 was silently
-    None for any other config and wrong if the model changed)."""
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(__file__), "..", "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    class Dev:
-        platform = "tpu"
-        device_kind = "TPU v5 lite"
-
-    # v5e: 819e9 B/s, 197e12 FLOP/s. AI = flops/bytes.
-    # flops=1.57e12, bytes=32e9 -> AI~49 -> bound ~49*819e9/197e12 ~ 0.204
-    b = bench._roofline_bound(1.57e12, 32e9, Dev())
-    assert b is not None and abs(b - 0.2040) < 0.002, b
-    # compute-bound case caps at 1.0
-    assert bench._roofline_bound(1e15, 1e9, Dev()) == 1.0
-    # CPU or unknown chip -> None
-
-    class Cpu:
-        platform = "cpu"
-        device_kind = "cpu"
-
-    assert bench._roofline_bound(1e12, 1e9, Cpu()) is None
-    assert bench._roofline_bound(None, 1e9, Dev()) is None
 
 
 def test_deferred_init_multictx_uses_input_context():

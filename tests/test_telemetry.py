@@ -296,25 +296,21 @@ def test_flight_dump_on_injected_sigterm(tmp_path):
 
 def test_section_registry_window_scoping_and_table():
     counters = {"hits": 3}
-    seen = []
 
-    def provider(reset=False):
-        seen.append(reset)
-        out = dict(counters)
-        if reset:
-            counters["hits"] = 0
-        return out
-
-    profiler.register_section("customSection", provider,
-                              lambda s: ["Custom:", f"hits {s['hits']}"])
+    profiler.register_section(
+        "customSection", lambda: dict(counters),
+        lambda: counters.update(hits=0),
+        lambda s: ["Custom:", f"hits {s['hits']}"])
     try:
         assert "customSection" in profiler.section_names()
+        # the registry, not the owner, zeroes the section it has read
         d = json.loads(profiler.dumps(reset=True))
         assert d["customSection"] == {"hits": 3}
-        assert True in seen
         assert json.loads(profiler.dumps())["customSection"] == \
             {"hits": 0}
+        counters["hits"] = 5
         profiler.set_config(aggregate_stats=True)
+        assert "hits 5" in profiler.dumps(format="table", reset=True)
         table = profiler.dumps(format="table")
         assert "Custom:" in table and "hits 0" in table
     finally:
@@ -323,18 +319,115 @@ def test_section_registry_window_scoping_and_table():
     assert "customSection" not in json.loads(profiler.dumps())
 
 
-def test_registered_sections_cover_all_subsystems():
-    # load the lazy tiers so their sections materialize
-    import mxnet_tpu.gluon  # noqa: F401
-    import mxnet_tpu.pipeline  # noqa: F401
-    import mxnet_tpu.resilience  # noqa: F401
-    import mxnet_tpu.serve.decode  # noqa: F401
-    import mxnet_tpu.serve.router  # noqa: F401
+def _flash_row(fa):
+    fa._built[("resident", "fwd", (1, 1, 128, 128, 64), "bfloat16", 1,
+               (1, 1), 1, None)] += 1
 
-    d = json.loads(profiler.dumps())
-    for section in ("cachedGraph", "trainerStep", "dataPipeline",
-                    "resilience", "telemetry", "decodeServe", "router"):
-        assert section in d, sorted(d)
+
+def _dp_record(dp):
+    dp._step_log.append((0, 1, time.perf_counter_ns(), 1000, 1000, 1000, 8))
+
+
+# (section, the module that owns its counters and registers it, the
+# keys it had when profiler.py still named every owner, one of them
+# counted up by `bump(owner)`): None where only a live trainer counts
+SECTIONS = [
+    ("cachedGraph", "gluon.block", "compiles reuses", "compiles",
+     lambda m: m._graph_stats.update(compiles=m._graph_stats["compiles"] + 1)),
+    ("ctrl", "serve.control_plane",
+     "blocked_bounds blocked_cooldown load replicas retired rpc_errors "
+     "rpc_requests rpc_streams scale_downs scale_ups spawn_failures spawns "
+     "stale_leases_rejected ticks", "ticks",
+     lambda m: m._sec_bump(ticks=1)),
+    ("dataParallelStep", "parallel.data_parallel",
+     "args_ms builds enqueue_ms put_bytes put_ms remat_children remat_saves "
+     "steps", "steps", _dp_record),
+    ("dataPipeline", "pipeline.stats",
+     "batches h2d_ms host_build_ms prefetch_hits prefetch_misses wait_ms",
+     "batches", lambda m: m.add("batches", 1)),
+    ("decodeServe", "serve.decode",
+     "accept_rate admitted cow_copies draft_steps expired_deadlines finished "
+     "pages_in_flight prefill_batches prefix_hit_pages slot_occupancy "
+     "spec_accepted spec_proposed steps tokens", "tokens",
+     lambda m: m._sec_bump(tokens=1)),
+    ("flashAttention", "ops.pallas.flash_attention",
+     "built grouped kernels resident residual_bytes residual_pairs "
+     "residuals_named streamed", "kernels", _flash_row),
+    ("health", "telemetry.health",
+     "alerts checkpoint_ms collective_ms compile_ms compute_ms "
+     "flops_per_step goodput h2d_ms input_wait_ms lost_ms mfu optimizer_ms "
+     "rules_firing step_ms step_p95_ms steps stragglers ticks", "ticks",
+     lambda m: m._counters.update(ticks=m._counters["ticks"] + 1)),
+    ("moeRouting", "models.decoder_lm",
+     "layers max_over_mean rows_here rows_per_expert share_here", "layers",
+     None),
+    ("quantize", "contrib.quantization",
+     "calib_batches calib_ms int8_serve_batches layers_quantized "
+     "requant_folds", "requant_folds",
+     lambda m: m._sec_bump(requant_folds=1)),
+    ("resilience", "resilience.stats",
+     "fallback_restores ranks_lost reshard_ms resizes restarts retries "
+     "time_lost_ms watchdog_fires", "restarts",
+     lambda m: m.add("restarts")),
+    ("router", "serve.router",
+     "dispatched evictions hedge_wins hedges probe_failures probes reloads "
+     "replacements retries", "dispatched",
+     lambda m: m._sec_bump(dispatched=1)),
+    ("telemetry", "telemetry.tracer",
+     "aggregations dropped flight_dumps instants requests scrapes spans",
+     "scrapes", lambda m: m.bump("scrapes")),
+    ("trainerStep", "gluon.trainer",
+     "buckets_built dispatches dispatches_per_step params_fused spmd_steps "
+     "steps whole_step_compiles whole_step_fallbacks whole_step_steps "
+     "zero_fallbacks zero_steps", "steps",
+     lambda m: m._step_stats.update(steps=m._step_stats["steps"] + 1)),
+    ("tune", "tune",
+     "baseline_score best_over_baseline best_score blocked_moves "
+     "candidates_ranked knobs_moved measurements recompiles_spent trials",
+     "trials", lambda m: m.trials._counters.update(
+         trials=m.trials._counters["trials"] + 1)),
+]
+
+
+@pytest.mark.parametrize("name,owner,keys,counted,bump", SECTIONS,
+                         ids=[row[0] for row in SECTIONS])
+def test_registered_sections_cover_all_subsystems(name, owner, keys, counted,
+                                                  bump, monkeypatch):
+    """Importing the module that owns a section's counters registers
+    the section, with the keys it always had, and a reset read zeroes
+    it: the registry calls the owner's reset, no owner decides."""
+    import importlib
+
+    from mxnet_tpu.telemetry import health
+
+    # `health` says nothing until a monitor has been armed
+    monkeypatch.setattr(health, "_ever_armed", True)
+    module = importlib.import_module("mxnet_tpu." + owner)
+    assert name in profiler.section_names()
+    if bump is not None:
+        bump(module)
+    before = profiler.sections()[name]
+    assert sorted(before) == sorted(keys.split())
+    if bump is not None:
+        assert before[counted] >= 1
+    assert profiler.sections(reset=True)[name] == before
+    after = profiler.sections().get(name)
+    assert after is None or after[counted] == 0, after
+    assert json.loads(profiler.dumps())[name].keys() == before.keys()
+
+
+def test_profiler_names_no_subsystem():
+    """profiler.py is below every module that owns a section: it looks
+    none of them up, and imports `base`, `storage` and `telemetry`
+    alone of the package."""
+    import re
+
+    source = open(profiler.__file__).read()
+    assert "sys.modules" not in source
+    assert not re.search(r"def \w+_counters\(", source)
+    imported = set(re.findall(r"^\s*from \.(\w*) import", source, re.M))
+    assert imported <= {"base", "storage", "telemetry"}, imported
+    assert "__package__" not in source and "import_module" not in source
 
 
 # ---------------------------------------------------------------------------
