@@ -41,6 +41,7 @@ import numpy as np
 
 from .. import profiler
 from ..gluon.block import HybridBlock
+from ..ops import moe as moe_op
 from ..ops.nn import rotary_frequencies
 
 _opened_ns = 0      # the clock when the `moeRouting` section's window opened
@@ -221,14 +222,20 @@ class DecoderLM(HybridBlock):
 
 def routing_stats(rows):
     """One expert layer's line of the `moeRouting` section from its
-    (held + 1,) routing counts."""
+    (held + 1,) routing counts; `capacity` is the rows the expert path
+    worked on in that step (`ops/moe.py:capacity`, the op's own choice
+    among its static row counts) and `capacity_share` how full it
+    was."""
     held = np.asarray(rows[:-1], np.float64)
     here, total = float(held.sum()), float(np.sum(rows))
     mean = here / len(held) if len(held) else 0.0
+    capacity = moe_op.capacity(int(here), int(total))
     return {"rows_per_expert": [int(r) for r in held],
             "rows_here": int(here),
             "share_here": here / total if total else 0.0,
-            "max_over_mean": float(held.max() / mean) if mean else 0.0}
+            "max_over_mean": float(held.max() / mean) if mean else 0.0,
+            "capacity": capacity,
+            "capacity_share": here / capacity if capacity else 0.0}
 
 
 def moe_routing_stats(newest=False, window=False):
@@ -246,7 +253,8 @@ def moe_routing_stats(newest=False, window=False):
     from ..parallel import data_parallel
 
     out = {"layers": 0, "rows_here": {}, "share_here": {},
-           "max_over_mean": {}, "rows_per_expert": {}}
+           "max_over_mean": {}, "capacity": {}, "capacity_share": {},
+           "rows_per_expert": {}}
     trainers = [t for t in data_parallel.live_trainers()
                 if isinstance(t.block, DecoderLM) and t.block._sparse_layers]
     if window:
@@ -259,7 +267,8 @@ def moe_routing_stats(newest=False, window=False):
             key = f"trainer{trainer._serial}.layer{layer}"
             stats = routing_stats(rows)
             out["layers"] += 1
-            for name in ("rows_here", "share_here", "max_over_mean"):
+            for name in ("rows_here", "share_here", "max_over_mean",
+                         "capacity", "capacity_share"):
                 out[name][key] = stats[name]
             for e, count in enumerate(stats["rows_per_expert"]):
                 out["rows_per_expert"][f"{key}.expert{e}"] = count
@@ -279,7 +288,9 @@ def _routing_table(stats):
     for key in sorted(stats["rows_here"]):
         out.append(f"  {key}: rows here {stats['rows_here'][key]}, share "
                    f"{stats['share_here'][key]:.4f}, max/mean "
-                   f"{stats['max_over_mean'][key]:.3f}")
+                   f"{stats['max_over_mean'][key]:.3f}, capacity "
+                   f"{stats['capacity'][key]} filled "
+                   f"{stats['capacity_share'][key]:.3f}")
     return out
 
 

@@ -399,8 +399,12 @@ def test_rotary_embedding_float32_aot(one_chip, rotated):
 def test_expert_layer_at_the_laguna_cells_shapes_aot(one_chip):
     """`moe_ffn` at the cell's size: 16,384 tokens, a 256-wide router,
     16 held experts of width 512, top-8: the grouped products compile
-    to the TPU's ragged-dot kernels, forward and backward."""
-    from mxnet_tpu.ops.moe import _k_moe_ffn
+    to the TPU's ragged-dot kernels, forward and backward, once for
+    each row capacity inside a conditional a pass, and no pass scatters
+    rows."""
+    import re
+
+    from mxnet_tpu.ops.moe import _k_moe_ffn, capacities
 
     bf16 = jnp.bfloat16
     specs = (jax.ShapeDtypeStruct((16384, 2048), bf16),
@@ -413,7 +417,21 @@ def test_expert_layer_at_the_laguna_cells_shapes_aot(one_chip):
                           scale=2.5)[0].astype(jnp.float32).sum()
 
     text = _aot_grad_compile(one_chip, loss, *specs)
-    assert "ragged-dot" in text or "ragged_dot" in text
+    caps = capacities(16384 * 8)
+    assert caps == (16384, 32768, 131072)
+    branches = re.findall(r"conditional\(.*branch_computations=\{([^}]*)\}",
+                          text)
+    assert [len(b.split(",")) for b in branches] == [len(caps)] * 2
+    products = re.findall(r"= (\w+)\[(\d+),\d+\]\S* custom-call\(.*"
+                          r'op_name="[^"]*ragged-dot-none"', text)
+    rows = [int(n) for _, n in products]
+    # by capacity: a pair forward; backward the pair again and, for the
+    # gradient of x alone, each product's transpose to its rows
+    assert sorted(rows) == sorted(caps * 6), sorted(rows)
+    assert len(re.findall(r'op_name="[^"]*ragged-dot-none"', text)) \
+        == len(rows)
+    assert not re.search(r"\[\d+,2048\]\S* scatter\(", text)
+    assert not re.search(r"\[16,\d+,\d+\]\S* scatter\(", text)
 
 
 def _dispatch_loss(q, mask):

@@ -215,8 +215,9 @@ def _moe_inputs(tokens=48, h=64, width=32, router_width=16, seed=0):
                         jnp.float32))
 
 
-def _dense_layer(x, router, w_in, w_out, top_k, scale):
-    """The uncut layer, expert by expert with masks."""
+def _dense_layer(x, router, w_in, w_out, top_k, scale, held=None):
+    """The uncut layer (or the part of it that the experts `held`
+    give), expert by expert with masks."""
     import jax
     import jax.numpy as jnp
 
@@ -224,7 +225,7 @@ def _dense_layer(x, router, w_in, w_out, top_k, scale):
     top, experts = jax.lax.top_k(probs, top_k)
     weights = top / top.sum(-1, keepdims=True) * scale
     out = jnp.zeros_like(x)
-    for e in range(router.shape[1]):
+    for e in range(router.shape[1]) if held is None else held:
         gate, up = jnp.split(
             jnp.matmul(x, w_in[e], precision="highest"), 2, axis=-1)
         y = jnp.matmul(jax.nn.silu(gate) * up, w_out[e], precision="highest")
@@ -314,6 +315,133 @@ def test_moe_gradients_match_the_dense_form_on_an_uneven_routing():
                                    atol=2e-5)
 
 
+def _routed(rows_here):
+    """Inputs that send `rows_here` assignments to the held experts
+    4-7: the first third of them from tokens that send BOTH of theirs
+    (to 4, 5 or 6 in turn, and to 7), the rest from tokens that send
+    one (to 4, 5 or 6); every other assignment goes to the absent
+    experts 12 and 13.  The first five features of x say which, the
+    rest is noise the router hardly reads."""
+    import jax.numpy as jnp
+
+    x, router, w_in, w_out = map(np.array, _moe_inputs(seed=5))
+    both = rows_here // 3
+    senders = rows_here - both
+    assert senders <= x.shape[0]
+    x[:, :6] = 0.0
+    x[np.arange(senders), np.arange(senders) % 3] = 1.0
+    x[:both, 3] = 1.0
+    x[:, 4] = 1.0
+    router *= 0.05
+    router[:6] = 0.0
+    router[[0, 1, 2], [4, 5, 6]] = 3.0
+    router[3, 7] = 2.5
+    router[4, 12], router[4, 13] = 2.0, 1.5
+    return tuple(jnp.asarray(a) for a in (x, router, w_in, w_out))
+
+
+@pytest.mark.parametrize("rows_here,capacity", [
+    (0, 12), (7, 12), (12, 12), (13, 24), (24, 24), (25, 96), (None, 96)],
+    ids=["no_row", "in_the_smallest", "fills_the_smallest",
+         "just_over_the_smallest", "fills_the_second",
+         "just_over_the_second", "all_on_one_expert"])
+def test_moe_at_every_capacity_matches_the_dense_form_and_the_top_branch(
+        monkeypatch, rows_here, capacity):
+    """48 tokens x top-2 compile the expert path at 12, 24 and 96 rows;
+    the routing decides on the device which of them runs.  Whichever
+    does, the output and all four gradients are the dense form's and
+    the top capacity's (today's path: every assignment's row)."""
+    import jax
+
+    from mxnet_tpu.ops import moe
+
+    if rows_here is None:       # every assignment on held experts 5 and 6
+        x, router, w_in, w_out = _moe_inputs()
+        x = abs(x)
+        router = router.at[:].set(0.0).at[:, 5].set(1.0).at[:, 6].set(0.5)
+        rows_here = 2 * x.shape[0]
+    else:
+        x, router, w_in, w_out = _routed(rows_here)
+    assert moe.capacities(2 * x.shape[0]) == (12, 24, 96)
+    assert moe.capacity(rows_here, 2 * x.shape[0]) == capacity
+
+    def op(x, router, w_in, w_out):
+        return moe._k_moe_ffn(x, router, w_in[4:8], w_out[4:8],
+                              first_expert=4, top_k=2, scale=2.5)
+
+    def dense(x, router, w_in, w_out):
+        return _dense_layer(x, router, w_in, w_out, 2, 2.5, held=range(4, 8))
+
+    def value_and_grads(fn):
+        return jax.value_and_grad(
+            lambda *args: (fn(*args) ** 2).sum(), argnums=(0, 1, 2, 3))(
+                x, router, w_in, w_out)
+
+    y, log = op(x, router, w_in, w_out)
+    assert log[:4].sum() == rows_here and log.sum() == 2 * x.shape[0]
+    # every capacity is compiled; which of them runs is `capacity`'s
+    # choice, by the one rule the op's own switch follows
+    text = str(jax.make_jaxpr(op)(x, router, w_in, w_out))
+    assert all(f"[{c},64]" in text for c in (12, 24, 96))
+    got = value_and_grads(lambda *args: op(*args)[0])
+    want = value_and_grads(dense)
+    monkeypatch.setattr(moe, "_CAPACITY_SHARES", (1,))
+    assert moe.capacities(2 * x.shape[0]) == (96,)
+    top = value_and_grads(lambda *args: op(*args)[0])
+    np.testing.assert_allclose(np.asarray(y), np.asarray(
+        dense(x, router, w_in, w_out)), rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(
+        op(x, router, w_in, w_out)[0]), rtol=2e-5, atol=2e-6)
+    for g, w, t in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                       jax.tree.leaves(top)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
+                                   atol=2e-5)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(t), rtol=2e-5,
+                                   atol=2e-6)
+
+
+@pytest.mark.parametrize("capacity", [96, 192, 768])
+def test_dispatch_and_combine_are_transposes_at_a_capacity(capacity):
+    """<combine(rows), g> == <rows, dispatch(g)> for any rows and g, at
+    the two token-ordered capacities (the second longer than one block
+    of the run sums) and at the top one, and each is the other's
+    registered vjp."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import moe
+
+    rng = np.random.RandomState(capacity)
+    tokens, top_k, held, rows_here = 96, 8, 4, min(capacity, 150)
+    assert moe.capacities(tokens * top_k) == (96, 192, 768)
+    # the live assignments crowd on the first 24 tokens (runs of up to
+    # 8 rows a token); the rest go to absent experts
+    group = np.full(tokens * top_k, held)
+    group[rng.permutation(24 * top_k)[:rows_here]] = rng.randint(
+        0, held, rows_here)
+    order = jnp.argsort(jnp.asarray(group), stable=True)
+    inverse = jnp.argsort(order)
+    here = jnp.asarray(group < held).reshape(tokens, top_k)
+    plan = moe._plan(capacity, order, inverse, here, rows_here, top_k)
+    rows = jnp.asarray(rng.randn(capacity, 8), jnp.float32)
+    g = jnp.asarray(rng.randn(tokens, 8), jnp.float32)
+    back = moe._combine(rows, plan, top_k)
+    out = moe._dispatch(g, plan, top_k)
+    assert back.shape == g.shape and out.shape == rows.shape
+    np.testing.assert_allclose(float((back * g).sum()),
+                               float((rows * out).sum()), rtol=1e-5)
+    # rows past the live ones are neither read nor written
+    assert not np.asarray(out[rows_here:]).any()
+    np.testing.assert_array_equal(np.asarray(back), np.asarray(moe._combine(
+        rows.at[rows_here:].set(np.nan), plan, top_k)))
+    np.testing.assert_allclose(
+        np.asarray(jax.vjp(lambda r: moe._combine(r, plan, top_k), rows)[1](
+            g)[0]), np.asarray(out), rtol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(jax.vjp(lambda v: moe._dispatch(v, plan, top_k), g)[1](
+            rows)[0]), np.asarray(back), rtol=1e-6)
+
+
 @pytest.mark.parametrize("kind", ["full_attention", "sliding_attention"])
 def test_rotary_embedding_matches_reference_tables(reference, kind):
     import jax.numpy as jnp
@@ -388,6 +516,12 @@ def test_moe_routing_section_is_on_metrics(reference):
     assert stats["rows_here"][mine[0]] == ids.size * 2
     assert stats["share_here"][mine[0]] == 1.0
     assert stats["max_over_mean"][mine[0]] >= 1.0
+    # and the expert path ran at its top capacity, full
+    assert stats["capacity"][mine[0]] == ids.size * 2
+    assert stats["capacity_share"][mine[0]] == 1.0
+    assert decoder_lm.routing_stats([100, 28, 896])["capacity"] == 128
+    assert decoder_lm.routing_stats([100, 29, 895])["capacity"] == 256
+    assert decoder_lm.routing_stats([0, 0, 0])["capacity_share"] == 0.0
     assert sum(stats["rows_per_expert"][f"{mine[0]}.expert{e}"]
                for e in range(16)) == ids.size * 2
     assert list(decoder_lm.moe_routing_stats(newest=True)["rows_here"]) \
@@ -395,7 +529,9 @@ def test_moe_routing_section_is_on_metrics(reference):
     assert profiler.sections()["moeRouting"] == stats
     text = metrics.default_registry().render()
     assert "mxtpu_moe_routing_rows_here{key=" in text
-    assert "MoE Routing" in "\n".join(profiler._section_tables())
+    assert "mxtpu_moe_routing_capacity_share{key=" in text
+    table = "\n".join(profiler._section_tables())
+    assert "MoE Routing" in table and "filled 1.000" in table
     # window-scoped like every section: after a reset dump the trainer
     # is back with its next step; the accessor itself takes no window
     assert profiler.sections(reset=True)["moeRouting"] == stats

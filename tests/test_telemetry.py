@@ -359,7 +359,8 @@ SECTIONS = [
      "rules_firing step_ms step_p95_ms steps stragglers ticks", "ticks",
      lambda m: m._counters.update(ticks=m._counters["ticks"] + 1)),
     ("moeRouting", "models.decoder_lm",
-     "layers max_over_mean rows_here rows_per_expert share_here", "layers",
+     "capacity capacity_share layers max_over_mean rows_here rows_per_expert "
+     "share_here", "layers",
      None),
     ("quantize", "contrib.quantization",
      "calib_batches calib_ms int8_serve_batches layers_quantized "
