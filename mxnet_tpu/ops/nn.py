@@ -785,16 +785,37 @@ register("SVMOutput", _k_svm_output, arg_names=("data", "label"),
 # Each is its own oracle: the XLA form below is the only form.
 
 
-def _k_rms_norm(data, gamma, *, eps=1e-6):
-    """x / sqrt(mean(x^2) + eps) * gamma over the last axis; the
-    statistics and the scaling in float32, the result in data's dtype."""
+def _rms_normed(data, eps):
+    """x / sqrt(mean(x^2) + eps) over the last axis, in float32."""
     x = data.astype(jnp.float32)
     inv = lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
-    return (x * inv * gamma.astype(jnp.float32)).astype(data.dtype)
+    return x * inv
+
+
+def _k_rms_norm(data, gamma, *, eps=1e-6, zero_centered=False):
+    """x / sqrt(mean(x^2) + eps) * gamma over the last axis; the
+    statistics and the scaling in float32, the result in data's dtype.
+    `zero_centered`: the gain is 1 + gamma (gamma starts at 0)."""
+    normed = _rms_normed(data, eps)
+    gain = gamma.astype(jnp.float32)
+    if zero_centered:
+        gain = 1.0 + gain
+    return (normed * gain).astype(data.dtype)
 
 
 register("rms_norm", _k_rms_norm, arg_names=("data", "gamma"),
          aliases=("RMSNorm",))
+
+
+def _k_gated_rms_norm(data, gate, gamma, *, eps=1e-6):
+    """rms_norm(data) * gamma * silu(gate) over the last axis, in
+    float32; `gate` has data's shape.  The result in data's dtype."""
+    return (_rms_normed(data, eps) * gamma.astype(jnp.float32)
+            * jax.nn.silu(gate.astype(jnp.float32))).astype(data.dtype)
+
+
+register("gated_rms_norm", _k_gated_rms_norm,
+         arg_names=("data", "gate", "gamma"))
 
 
 def rotary_frequencies(rotary_dim, *, rope_theta=10000.0,

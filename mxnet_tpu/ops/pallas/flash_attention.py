@@ -65,6 +65,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ... import profiler
+from .. import registry
 from . import pallas_call
 
 _NEG_INF = -1e9
@@ -77,7 +78,7 @@ _GROUP_VMEM_BYTES = 4 * 2 ** 20
 # the names the fwd rules give the kernel's output and its row statistic
 # (`_name_residuals`); a `jax.checkpoint` policy that saves them keeps
 # the forward kernel out of the recomputation
-RESIDUAL_NAMES = ("flash_out", "flash_lse")
+RESIDUAL_NAMES = registry.RESIDUAL_NAMES["flash_attention"]
 
 
 # every kernel built while a program was traced: (variant, kernel, (b, h,

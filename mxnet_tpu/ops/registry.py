@@ -21,6 +21,16 @@ from ..base import MXNetError
 
 _ops = {}
 
+# the outputs ops name (`jax.ad_checkpoint.checkpoint_name`) for a
+# `jax.checkpoint` policy to keep, by the op that names them:
+# `DataParallelTrainer(remat=True)` saves every name here, and the op
+# is not run again in the recomputation of what follows it.  An op
+# that names a residual lists it here and nowhere else.
+RESIDUAL_NAMES = {
+    "flash_attention": ("flash_out", "flash_lse"),
+    "gated_delta_rule": ("delta_rule_out",),
+}
+
 
 class Param:
     """Self-documenting op parameter descriptor.

@@ -121,11 +121,14 @@ _profiler.register_section(
 
 
 def _remat_saves():
-    """The names `remat=True` keeps across a checkpoint: the flash
-    kernels' output and row statistic, as their fwd rules name them."""
-    from ..ops.pallas.flash_attention import RESIDUAL_NAMES
+    """The names `remat=True` keeps across a checkpoint: every output
+    an op names for it (`ops.registry.RESIDUAL_NAMES`: the flash
+    kernels' output and row statistic, the gated delta rule's
+    output)."""
+    from ..ops import registry
 
-    return RESIDUAL_NAMES
+    return tuple(name for names in registry.RESIDUAL_NAMES.values()
+                 for name in names)
 
 
 @functools.cache
@@ -200,7 +203,9 @@ class DataParallelTrainer:
         # (`flash_attention.RESIDUAL_NAMES`), b*s*h*d x itemsize +
         # 4*b*h*s bytes an attention: the backward kernels read both
         # whether kept or recomputed, so keeping them costs capacity
-        # and no traffic, and the forward kernel runs once.  Lowered for
+        # and no traffic, and the forward kernel runs once; and the
+        # gated delta rule's output (`linear_attention.RESIDUAL_NAMES`),
+        # so the rule is not run again for what follows it.  Lowered for
         # a CPU the names sit in the dispatch's dropped TPU branch, and
         # the XLA form of attention is recomputed whole.
         # Trades ~1/3 more FLOPs, less the attention forward's, for

@@ -281,30 +281,126 @@ def test_grouped_flash_at_the_laguna_cells_shapes_aot(one_chip, heads,
     assert text.count("tpu_custom_call") == 3
 
 
-def _decoder_step_aot(topo, monkeypatch, layer_types, heads, seq, chips,
-                      sparse=False):
-    """A `DecoderLM` of laguna's head counts (8 K/V heads of 128) at a
-    small width and a short sequence, stepped by
-    `DataParallelTrainer(remat=True)` in bf16, two sequences a chip:
-    the whole step compiled for the described v5e chips.  Nothing is put
-    on a device: the
-    trainer's `global_put` hands back shapes (and plain SGD has no
-    state to make there)."""
+@pytest.mark.parametrize("dt", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_grouped_flash_at_head_size_256_aot(one_chip, dt):
+    """The grouped kernels at the `qwen3_next_80b.seq8k` cell's shape:
+    16 query heads over 2 K/V heads (a group of 8) of head size 256, 2
+    x 8,192 tokens.  `_grouped_ok` admits it by its byte rule (a K/V
+    head of 8,192 x 256 in float32 is 16.8 MB twice over, inside the 32
+    MiB); a fall to the XLA form would materialise 16 x 8,192^2 float32
+    scores a sequence, and passes here only by being three Mosaic
+    calls: forward, dQ, dK/dV."""
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((2, 16, 8192, 256), dt)
+    kv = jax.ShapeDtypeStruct((2, 2, 8192, 256), dt)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True) \
+            .astype(jnp.float32).sum()
+
+    jitted = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                     in_shardings=(one_chip,) * 3)
+    text = jitted.lower(q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+
+
+def test_delta_rule_at_the_qwen_cells_shape_holds_no_state_a_token_aot(
+        one_chip):
+    """`gated_delta_rule` at (2, 32, 8,192, 128) over 16 key heads in
+    bf16, forward and backward: it compiles for v5e, its largest
+    float32 array is the states at the chunks' starts of one head group
+    (128 x 2 x 8 x 128 x 128), nothing the size of a state a token
+    (8,192 x 64 KB a head), and its temporaries stay under 3 GB (2.37
+    when written; 7.0 before the heads were worked on in groups)."""
+    import re
+
+    from mxnet_tpu.ops.linear_attention import (_k_gated_delta_rule,
+                                                head_groups)
+
+    qk = jax.ShapeDtypeStruct((2, 16, 8192, 128), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((2, 32, 8192, 128), jnp.bfloat16)
+    gb = jax.ShapeDtypeStruct((2, 32, 8192), jnp.float32)
+    assert head_groups(2, 16, 32) == 4
+
+    def loss(q, k, v, g, beta):
+        return _k_gated_delta_rule(q, k, v, g, beta) \
+            .astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
+                       in_shardings=(one_chip,) * 5) \
+        .lower(qk, qk, v, gb, gb).compile()
+    text = compiled.as_text()
+    sizes = {int(np.prod([int(n) for n in dims.split(",")]))
+             * (4 if kind == "f32" else 2)
+             for kind, dims in re.findall(r"\b(f32|bf16)\[([0-9,]+)\]", text)}
+    states_kept = 128 * 2 * 8 * 128 * 128 * 4
+    assert states_kept in sizes
+    assert max(sizes) <= 2 * states_kept, max(sizes)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
+    assert "(delta_rule)" in text and "while" in text
+
+
+def _hybrid_step_aot(topo, monkeypatch, seq=512):
+    """A 4-layer hybrid `DecoderLM` (three Gated DeltaNet layers of 4
+    key / 8 value heads of 128, one gated attention layer of 16 / 2
+    heads of 256, an expert layer in each) at a small width, stepped by
+    `DataParallelTrainer(remat=True)` in bf16 on one described chip."""
+    config = dict(
+        vocab_size=1024, hidden_size=256, head_dim=256,
+        num_attention_heads=16, num_key_value_heads=2,
+        linear_num_key_heads=4, linear_num_value_heads=8,
+        linear_key_head_dim=128, linear_value_head_dim=128,
+        linear_conv_kernel_dim=4, num_hidden_layers=4,
+        layer_types=["linear_attention"] * 3 + ["full_attention"],
+        mlp_layer_types=["sparse"] * 4, moe_intermediate_size=128,
+        shared_expert_intermediate_size=128, num_experts=4, router_width=16,
+        num_experts_per_tok=2, norm_zero_centered=True, qk_norm=True,
+        attention_output_gate="elementwise", shared_expert_gate=True,
+        rope_parameters={"full_attention": dict(
+            rope_type="default", rope_theta=1e7,
+            partial_rotary_factor=0.25)})
+    return _step_aot(topo, monkeypatch, config, seq, 1)
+
+
+def test_hybrid_remat_step_names_its_scopes_and_kernels_aot(topo,
+                                                            monkeypatch):
+    """The remat step of the hybrid model: the rule's instructions
+    carry `/linear_attention/.../delta_rule/` (and the convolution's
+    `/conv/`) in forward and backward, the grouped products keep their
+    phase prefix (the PR 30 lesson: a change to how layers are traced
+    can leave them bare), the one attention layer is three Mosaic
+    calls, and the rule's forward runs once a layer outside the
+    backward pass's own recomputation: its output is kept by name."""
+    import re
+
+    text = _hybrid_step_aot(topo, monkeypatch).as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    rule = [n for n in names if re.search(
+        r"/linear_attention/.*/delta_rule/", n)]
+    assert any("transpose(jvp(forward))" in n for n in rule)
+    assert any("transpose(" not in n for n in rule)
+    assert any(re.search(r"/linear_attention/.*/conv/", n) for n in names)
+    grouped = [n for n in names if "ragged-dot-" in n]
+    assert grouped and all(
+        re.match(r"jit\(step_phases\)/.*jvp\(forward\).*/ragged-dot-", n)
+        for n in grouped), sorted(set(grouped))
+    attention = re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*attention_full', text)
+    assert len(attention) == 3
+
+
+def _step_aot(topo, monkeypatch, config, seq, chips):
+    """`DecoderLM(config)` stepped by `DataParallelTrainer(remat=True)`
+    in bf16, two sequences a chip: the whole step compiled for the
+    described v5e chips.  Nothing is put on a device: the trainer's
+    `global_put` hands back shapes (and plain SGD has no state to make
+    there)."""
     import mxnet_tpu as mx
     from mxnet_tpu.models import decoder_lm
     from mxnet_tpu.parallel import data_parallel, mesh as mesh_mod
 
-    rope = dict(rope_type="default", rope_theta=10000,
-                partial_rotary_factor=1)
-    config = dict(
-        vocab_size=1024, hidden_size=256, head_dim=128,
-        num_key_value_heads=8, intermediate_size=512, sliding_window=256,
-        num_hidden_layers=len(layer_types), layer_types=layer_types,
-        num_attention_heads_per_layer=heads,
-        mlp_layer_types=["sparse" if sparse else "dense"] * len(layer_types),
-        moe_intermediate_size=128, shared_expert_intermediate_size=128,
-        num_experts=4, router_width=16, num_experts_per_tok=2,
-        rope_parameters={"full_attention": rope, "sliding_attention": rope})
     net = decoder_lm.DecoderLM(config)
     net.initialize(mx.init.Zero())
     monkeypatch.setattr(
@@ -321,6 +417,24 @@ def _decoder_step_aot(topo, monkeypatch, layer_types, heads, seq, chips,
         trainer._params, trainer._states, (ids, ids),
         np.zeros((len(ids),), np.float32),
         jax.ShapeDtypeStruct((2,), jnp.uint32), scalar, scalar).compile()
+
+
+def _decoder_step_aot(topo, monkeypatch, layer_types, heads, seq, chips,
+                      sparse=False):
+    """A `DecoderLM` of laguna's head counts (8 K/V heads of 128) at a
+    small width and a short sequence."""
+    rope = dict(rope_type="default", rope_theta=10000,
+                partial_rotary_factor=1)
+    config = dict(
+        vocab_size=1024, hidden_size=256, head_dim=128,
+        num_key_value_heads=8, intermediate_size=512, sliding_window=256,
+        num_hidden_layers=len(layer_types), layer_types=layer_types,
+        num_attention_heads_per_layer=heads,
+        mlp_layer_types=["sparse" if sparse else "dense"] * len(layer_types),
+        moe_intermediate_size=128, shared_expert_intermediate_size=128,
+        num_experts=4, router_width=16, num_experts_per_tok=2,
+        rope_parameters={"full_attention": rope, "sliding_attention": rope})
+    return _step_aot(topo, monkeypatch, config, seq, chips)
 
 
 @pytest.mark.parametrize("layer_types,heads,chips", [

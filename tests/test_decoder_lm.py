@@ -4,7 +4,10 @@ and its small ops against the plain reference of the benchmark
 at a small size on the CPU with seeded weights: loss and every leaf's
 gradient through `DataParallelTrainer.step`, both layer kinds and both
 rotary forms; no assignment dropped whatever the imbalance; and the
-shares of an expert-parallel layer adding up to the uncut layer."""
+shares of an expert-parallel layer adding up to the uncut layer.  The
+hybrid model (Gated DeltaNet layers beside a gated attention layer,
+a gated shared expert) against benchmarks/reference/qwen3_next_80b.py,
+whose delta rule runs token by token."""
 from __future__ import annotations
 
 import copy
@@ -40,16 +43,45 @@ SMALL = dict(
                            "epsilon": 1e-8}})
 
 
-@pytest.fixture(scope="module")
-def reference():
+# the hybrid model at a small size, in the PUBLISHED key names of
+# Qwen3-Next's config.json: 3 Gated DeltaNet layers to 1 gated
+# attention layer, an expert layer with a gated shared expert in each
+SMALL_HYBRID = dict(
+    vocab_size=128, hidden_size=64, head_dim=16, num_attention_heads=8,
+    num_key_value_heads=2, linear_num_key_heads=2, linear_num_value_heads=4,
+    linear_key_head_dim=8, linear_value_head_dim=16,
+    linear_conv_kernel_dim=4, full_attention_interval=4,
+    decoder_sparse_step=1, mlp_only_layers=[], num_hidden_layers=4,
+    moe_intermediate_size=32, shared_expert_intermediate_size=32,
+    num_experts=16, router_width=16, first_expert=0, num_experts_per_tok=3,
+    rms_norm_eps=1e-6, rope_theta=10000000, partial_rotary_factor=0.25,
+    assumed={"init_stdev": 0.05, "conv_init_stdev": 0.25,
+             "optimizer": {"name": "adamw", "learning_rate": 1e-3,
+                           "wd": 0.01, "beta1": 0.9, "beta2": 0.999,
+                           "epsilon": 1e-8}})
+
+
+def _bench_module(directory, name):
     if BENCH not in sys.path:
         sys.path.insert(0, BENCH)
     spec = importlib.util.spec_from_file_location(
-        "reference_laguna_xs2",
-        os.path.join(BENCH, "reference", "laguna_xs2.py"))
+        f"{directory}_{name}", os.path.join(BENCH, directory, name + ".py"))
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return _bench_module("reference", "laguna_xs2")
+
+
+@pytest.fixture(scope="module")
+def hybrid():
+    """(the plain reference, the function that turns the published
+    keys into `DecoderLM`'s config) of the hybrid model."""
+    return (_bench_module("reference", "qwen3_next_80b"),
+            _bench_module("configs", "qwen3_next_80b").decoder_config)
 
 
 def _weights(reference, config, seed=3):
@@ -86,25 +118,16 @@ def _filled(config, arrays):
     return net
 
 
-@pytest.mark.parametrize("router_width,first", [(16, 0), (64, 16)],
-                         ids=["whole", "share"])
-def test_model_matches_reference_loss_and_every_gradient(
-        reference, router_width, first):
-    import jax
-
+def _step_matches_gradients(reference, config, model_config, params0, ids,
+                            labels, want_loss, want):
+    """One step of plain SGD at rate 1 without decay, so that the
+    step's change IS the gradient, through the entry point the
+    benchmark drives (`remat=True`): the loss to 1e-5 and every leaf's
+    gradient to 2e-4 of its largest element (or of the median leaf's)
+    against the reference's.  Returns (net, trainer)."""
     from mxnet_tpu.parallel import data_parallel
 
-    config = copy.deepcopy(SMALL)
-    config.update(router_width=router_width, first_expert=first)
-    params0 = _weights(reference, config)
-    ids, labels = _batch(config)
-    (want_loss, want_rows), want = jax.value_and_grad(
-        reference.loss_and_routing, has_aux=True)(
-            list(params0), ids, labels, config=config, precision="float32")
-
-    net = _filled(config, params0)
-    # plain SGD at rate 1 without decay: the step's change IS the
-    # gradient, through the entry point the benchmark drives
+    net = _filled(model_config, params0)
     trainer = data_parallel.DataParallelTrainer(
         net, lambda out, _: out, "sgd", {"learning_rate": 1.0, "wd": 0.0},
         mesh=_one_device_mesh(), remat=True)
@@ -119,6 +142,25 @@ def test_model_matches_reference_loss_and_every_gradient(
         got = np.asarray(p0) - np.asarray(p1)
         err = np.abs(got - np.asarray(g)).max()
         assert err < 2e-4 * max(np.abs(np.asarray(g)).max(), scale), name
+    return net, trainer
+
+
+@pytest.mark.parametrize("router_width,first", [(16, 0), (64, 16)],
+                         ids=["whole", "share"])
+def test_model_matches_reference_loss_and_every_gradient(
+        reference, router_width, first):
+    import jax
+
+    config = copy.deepcopy(SMALL)
+    config.update(router_width=router_width, first_expert=first)
+    params0 = _weights(reference, config)
+    ids, labels = _batch(config)
+    (want_loss, want_rows), want = jax.value_and_grad(
+        reference.loss_and_routing, has_aux=True)(
+            list(params0), ids, labels, config=config, precision="float32")
+
+    net, trainer = _step_matches_gradients(
+        reference, config, config, params0, ids, labels, want_loss, want)
     # the routing log: the rows each held expert got, layer by layer
     np.testing.assert_array_equal(np.asarray(trainer._params[0]),
                                   np.asarray(want_rows))
@@ -130,11 +172,66 @@ def test_model_matches_reference_loss_and_every_gradient(
     assert float(np.asarray(want_rows)[0].sum()) == ids.size * 2
 
 
-def test_trainer_in_bfloat16_with_remat_learns(reference):
+@pytest.mark.parametrize("router_width,first,seq", [
+    (16, 0, 64), (64, 16, 96)], ids=["whole", "share_padded_chunk"])
+def test_hybrid_model_matches_reference_loss_and_every_gradient(
+        hybrid, router_width, first, seq):
+    """The chunked rule inside the model against the reference's token
+    scan, with the convolution, the l2 norms, the gated norm, the
+    zero-centred norms, q/k norms, the element-wise output gate and the
+    gated shared expert around it; 96 tokens are a chunk and a half.
+    float32: 2e-4 of a leaf's largest gradient (or of the median
+    leaf's), as the laguna model; the state in bf16 reads 1e-2."""
+    import jax
+
+    reference, decoder_config = hybrid
+    config = copy.deepcopy(SMALL_HYBRID)
+    config.update(router_width=router_width, first_expert=first)
+    params0 = _weights(reference, config)
+    ids, labels = _batch(config, seq=seq)
+    (want_loss, want_rows), want = jax.value_and_grad(
+        reference.loss_and_routing, has_aux=True)(
+            list(params0), ids, labels, config=config, precision="float32")
+
+    net, trainer = _step_matches_gradients(
+        reference, config, decoder_config(config), params0, ids, labels,
+        want_loss, want)
+    names = [name for name, _ in net._ordered_params()]
+    assert sum("qkvz" in n for n in names) == 3 and sum(
+        "q_norm" in n for n in names) == 1
+    assert all(np.abs(np.asarray(g)).max() > 0 for g in want[1:])
+    np.testing.assert_array_equal(np.asarray(trainer._params[0]),
+                                  np.asarray(want_rows))
+    # every one of the four layers has an expert layer and logs it
+    rows = net.routing_rows(trainer.aux_params()[net.routing_log.name])
+    assert sorted(rows) == [0, 1, 2, 3]
+
+
+def test_hybrid_config_keys_default_to_the_plain_decoder():
+    """Every key the hybrid model added defaults to the older
+    behaviour: per-head gate, plain gains from 1, no q/k norm, no gate
+    on the shared expert; an unknown gate is refused."""
+    from mxnet_tpu.models import decoder_lm
+
+    layer = decoder_lm.DecoderLayer(SMALL, 1)
+    assert layer._gate == "per_head" and not layer._qk_norm
+    assert not layer._zero_centered and not layer._shared_gate
+    assert layer.gate_weight.shape == (16, 64)
+    assert not hasattr(layer, "q_norm")
+    with pytest.raises(ValueError, match="per_head or elementwise"):
+        decoder_lm.DecoderLayer(dict(SMALL, attention_output_gate="x"), 0)
+
+
+@pytest.mark.parametrize("model", ["mixed_attention", "hybrid"])
+def test_trainer_in_bfloat16_with_remat_learns(reference, hybrid, model):
     from mxnet_tpu.parallel import data_parallel
 
-    config = copy.deepcopy(SMALL)
-    net = _filled(config, _weights(reference, config))
+    if model == "hybrid":
+        config = copy.deepcopy(SMALL_HYBRID)
+        net = _filled(hybrid[1](config), _weights(hybrid[0], config))
+    else:
+        config = copy.deepcopy(SMALL)
+        net = _filled(config, _weights(reference, config))
     trainer = data_parallel.DataParallelTrainer(
         net, lambda out, _: out, "adamw",
         {"learning_rate": 1e-2, "wd": 0.01}, mesh=_one_device_mesh(),
@@ -233,25 +330,57 @@ def _dense_layer(x, router, w_in, w_out, top_k, scale, held=None):
     return out
 
 
-def test_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
-    """4 shares of a 16-wide layer: each routes over all 16, computes
-    its own 4 experts' part; the parts add up to the whole layer."""
+@pytest.mark.parametrize("shares,width,top_k,scale,shared", [
+    (4, 16, 2, 2.5, False), (32, 64, 10, 1.0, True)],
+    ids=["4_shares_top_2", "32_shares_top_10_gated_shared_expert"])
+def test_shares_of_the_expert_layer_add_up_to_the_uncut_layer(
+        shares, width, top_k, scale, shared):
+    """Each share routes over the whole router and computes its own
+    experts' part; the parts add up to the whole layer.  With a gated
+    shared expert, which every chip computes alike, that part is
+    counted ONCE beside the 32 routed parts."""
+    import jax
+    import jax.numpy as jnp
+
     from mxnet_tpu.ops.moe import _k_moe_ffn
 
-    x, router, w_in, w_out = _moe_inputs()
+    x, router, w_in, w_out = _moe_inputs(router_width=width)
+    held = width // shares
     parts, rows = [], []
-    for first in range(0, 16, 4):
-        y, log = _k_moe_ffn(x, router, w_in[first:first + 4],
-                            w_out[first:first + 4], first_expert=first,
-                            top_k=2, scale=2.5)
+    for first in range(0, width, held):
+        y, log = _k_moe_ffn(x, router, w_in[first:first + held],
+                            w_out[first:first + held], first_expert=first,
+                            top_k=top_k, scale=scale)
         parts.append(np.asarray(y))
         rows.append(np.asarray(log))
-        assert log[:4].sum() + log[4] == x.shape[0] * 2
-    want = np.asarray(_dense_layer(x, router, w_in, w_out, 2, 2.5))
-    np.testing.assert_allclose(sum(parts), want, rtol=2e-5, atol=2e-6)
-    assert sum(r[:4].sum() for r in rows) == x.shape[0] * 2
+        assert log[:held].sum() + log[held] == x.shape[0] * top_k
+    want = np.asarray(_dense_layer(x, router, w_in, w_out, top_k, scale))
+    got = sum(parts)
+    if shared:
+        import mxnet_tpu as mx
+
+        rng = np.random.RandomState(9)
+        s_in, s_out, s_gate = (rng.randn(*shape).astype(np.float32) * 0.1
+                               for shape in ((64, 64), (64, 32), (1, 64)))
+        nd = mx.nd.array
+        fc = lambda a, w: mx.nd.FullyConnected(  # noqa: E731
+            a, nd(w), no_bias=True, flatten=False, num_hidden=w.shape[0])
+        # the program's ops, once
+        once = (fc(mx.nd.swiglu(fc(nd(np.asarray(x)), s_in)), s_out)
+                * mx.nd.sigmoid(fc(nd(np.asarray(x)), s_gate))).asnumpy()
+        got = got + once
+        gate, up = jnp.split(jnp.matmul(x, s_in.T, precision="highest"), 2,
+                             axis=-1)
+        want = want + np.asarray(
+            jax.nn.sigmoid(jnp.matmul(x, s_gate.T, precision="highest"))
+            * jnp.matmul(jax.nn.silu(gate) * up, s_out.T,
+                         precision="highest"))
+        # counted in every share it would be 32 times too much
+        assert np.abs(got + (shares - 1) * once - want).max() > 1e-2
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    assert sum(r[:held].sum() for r in rows) == x.shape[0] * top_k
     # no share is the whole: each leaves the others' part out
-    assert all(np.abs(p - want).max() > 1e-3 for p in parts)
+    assert all(np.abs(p - sum(parts)).max() > 1e-3 for p in parts)
 
 
 def test_every_assignment_is_computed_when_all_tokens_pick_one_expert():

@@ -225,7 +225,8 @@ def test_section_says_what_remat_wrapped_and_keeps():
     _trainer("sgd", remat=True).step(x, y)
     stats = profiler.sections()["dataParallelStep"]
     assert stats["builds"] == 2 and stats["remat_children"] == 2
-    assert stats["remat_saves"] == {"flash_out": 1, "flash_lse": 1}
+    assert stats["remat_saves"] == {
+        "flash_out": 1, "flash_lse": 1, "delta_rule_out": 1}
 
     flat = gluon.nn.Dense(4)
     flat.initialize(mx.init.Xavier())
@@ -236,7 +237,8 @@ def test_section_says_what_remat_wrapped_and_keeps():
     trainer.build(x)        # built once: counted once
     stats = profiler.sections()["dataParallelStep"]
     assert stats["remat_children"] == 2
-    assert stats["remat_saves"] == {"flash_out": 2, "flash_lse": 2}
+    assert stats["remat_saves"] == {
+        "flash_out": 2, "flash_lse": 2, "delta_rule_out": 2}
 
     table = profiler._section_tables()
     assert f"{'remat: children checkpointed':<40}{2:>12}" in table

@@ -1,0 +1,260 @@
+"""ops/linear_attention.py on the CPU at tiny sizes: the chunked gated
+delta rule against a token-by-token scan (values and every gradient,
+several chunks, a length that is no multiple of the chunk, decays near
+0 and near 1, the head groups), the triangular inverse where keys
+repeat, the causal convolution against explicit shifts, and the
+`linearAttention` profiler section."""
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+
+def _recurrent(q, k, v, g, beta):
+    """The rule one token at a time, float32, state from 0."""
+    b, h, _, dv = v.shape
+    r = h // k.shape[1]
+    q, k = (jnp.repeat(x, r, 1).astype(jnp.float32) for x in (q, k))
+    v, g, beta = (x.astype(jnp.float32) for x in (v, g, beta))
+
+    def token(state, x):
+        q_t, k_t, v_t, g_t, beta_t = x
+        state = state * jnp.exp(g_t)[..., None, None]
+        d = beta_t[..., None] * (v_t - jnp.einsum(
+            "bhkv,bhk->bhv", state, k_t, precision="highest"))
+        state = state + k_t[..., :, None] * d[..., None, :]
+        return state, jnp.einsum("bhkv,bhk->bhv", state, q_t,
+                                 precision="highest")
+
+    xs = [jnp.moveaxis(x, 2, 0) for x in (q, k, v, g, beta)]
+    _, o = jax.lax.scan(token, jnp.zeros((b, h, k.shape[-1], dv)), xs)
+    return jnp.moveaxis(o, 0, 2)
+
+
+def _inputs(b=2, hk=2, hv=4, seq=200, dk=16, dv=24, seed=0,
+            dtype=jnp.float32):
+    rng = np.random.RandomState(seed)
+
+    def unit(x):
+        return x / np.sqrt((x * x).sum(-1, keepdims=True) + 1e-6)
+
+    q = unit(rng.randn(b, hk, seq, dk)) / np.sqrt(dk)
+    # keys that lean one way: neighbours overlap, the triangular
+    # system is far from the identity
+    k = unit(rng.randn(b, hk, seq, dk) + 1.5)
+    v = rng.randn(b, hv, seq, dv)
+    g = -np.exp(rng.randn(b, hv, seq) * 2 - 1)
+    g[:, 0] = -1e-4         # a head that forgets nothing
+    g[:, 1] = -30.0         # a head that forgets everything, every token
+    beta = 1 / (1 + np.exp(-rng.randn(b, hv, seq) * 2))
+    return (jnp.asarray(q, dtype), jnp.asarray(k, dtype),
+            jnp.asarray(v, dtype), jnp.asarray(g, jnp.float32),
+            jnp.asarray(beta, jnp.float32))
+
+
+def _weighted(fn, *args):
+    o = fn(*args).astype(jnp.float32)
+    return (o * jnp.cos(jnp.arange(o.size, dtype=jnp.float32)
+                        .reshape(o.shape))).sum()
+
+
+@pytest.mark.parametrize("shape", [
+    dict(), dict(seq=128), dict(seq=65, hv=2),
+    dict(b=4, hk=4, hv=8, seq=96)],
+    ids=["200_padded_to_four_chunks", "two_chunks", "one_token_past_a_chunk",
+         "two_head_groups"])
+def test_chunked_rule_matches_the_token_scan_values_and_gradients(shape):
+    """float32: the two forms are one function, so they agree to
+    round-off: 1e-5 of the largest value (a bf16 state, 4e-3, or a
+    decay left out would fail by orders); a length that is no multiple
+    of the chunk is padded, never cut."""
+    from mxnet_tpu.ops import linear_attention as la
+
+    args = _inputs(**shape)
+    if "b" in shape:
+        assert la.head_groups(4, 4, 8) == 2
+    got = la._k_gated_delta_rule(*args)
+    want = _recurrent(*args)
+    assert got.shape == want.shape == args[2].shape
+    scale = float(jnp.abs(want).max())
+    assert float(jnp.abs(got - want).max()) < 1e-5 * scale
+    # the last tokens, past the last whole chunk, are computed
+    assert float(jnp.abs(got[:, :, -1]).max()) > 0
+    grads = [jax.grad(lambda *a, fn=fn: _weighted(fn, *a),
+                      argnums=(0, 1, 2, 3, 4))(*args)
+             for fn in (la._k_gated_delta_rule, _recurrent)]
+    for name, g, w in zip("q k v g beta".split(), *grads):
+        assert float(jnp.abs(g - w).max()) < 2e-5 * float(jnp.abs(w).max()), \
+            name
+
+
+def test_chunked_rule_in_bfloat16_stays_near_the_float32_scan():
+    """bf16 operands, float32 state and accumulation: 2 % of the
+    largest value (one bf16 rounding of each operand is 0.4 %, several
+    products deep); the state itself in bf16 over 200 tokens reads 5 %
+    and more."""
+    from mxnet_tpu.ops import linear_attention as la
+
+    args = _inputs(dtype=jnp.bfloat16)
+    got = la._k_gated_delta_rule(*args).astype(jnp.float32)
+    want = _recurrent(*args)
+    assert got.dtype == jnp.float32 and jnp.isfinite(got).all()
+    assert float(jnp.abs(got - want).max()) < 2e-2 * float(
+        jnp.abs(want).max())
+    grad = jax.grad(lambda *a: _weighted(la._k_gated_delta_rule, *a),
+                    argnums=(0, 1, 2, 3, 4))(*args)
+    want = jax.grad(lambda *a: _weighted(_recurrent, *a),
+                    argnums=(0, 1, 2, 3, 4))(*args)
+    for name, g, w in zip("q k v g beta".split(), grad, want):
+        assert jnp.isfinite(g.astype(jnp.float32)).all(), name
+        assert float(jnp.abs(g.astype(jnp.float32) - w).max()) < 5e-2 * float(
+            jnp.abs(w).max()), name
+
+
+def test_strong_decay_overflows_nothing():
+    """g = -80 a token: exp(-80 * 64) is 0 and its reciprocal would be
+    inf; only differences g_i - g_j <= 0 are ever exponentiated."""
+    from mxnet_tpu.ops import linear_attention as la
+
+    q, k, v, g, beta = _inputs(seq=128)
+    g = jnp.full_like(g, -80.0)
+    out, grads = jax.value_and_grad(
+        lambda *a: _weighted(la._k_gated_delta_rule, *a),
+        argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+    assert np.isfinite(float(out))
+    assert all(bool(jnp.isfinite(x).all()) for x in grads)
+    want = _recurrent(q, k, v, g, beta)
+    got = la._k_gated_delta_rule(q, k, v, g, beta)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+
+
+def test_unit_lower_inverse_is_exact_where_keys_repeat():
+    """All keys equal and beta 1: A is the strictly lower matrix of
+    ones, whose 64-step Neumann series cancels binomial coefficients
+    of 1e18; by blocks of 16 the inverse (the bidiagonal 1, -1) is
+    exact."""
+    from mxnet_tpu.ops.linear_attention import _unit_lower_inverse
+
+    a = jnp.tril(jnp.ones((64, 64), jnp.float32), -1)
+    np.testing.assert_array_equal(
+        np.asarray(_unit_lower_inverse(a)), np.eye(64) - np.eye(64, k=-1))
+    a = jnp.tril(jnp.asarray(np.random.RandomState(1).rand(3, 64, 64),
+                             jnp.float32), -1)
+    product = jnp.matmul(_unit_lower_inverse(a), jnp.eye(64) + a,
+                         precision="highest")
+    np.testing.assert_allclose(np.asarray(product),
+                               np.broadcast_to(np.eye(64), (3, 64, 64)),
+                               atol=2e-4)
+
+
+def test_value_heads_must_be_a_multiple_of_key_heads():
+    from mxnet_tpu.ops import linear_attention as la
+
+    q, k, v, g, beta = _inputs(hk=3, hv=4)
+    with pytest.raises(ValueError, match="4 value heads over 3"):
+        la._k_gated_delta_rule(q, k, v, g, beta)
+
+
+@pytest.mark.parametrize("dtype,tolerance", [
+    ("float32", 1e-5), ("bfloat16", 2e-2)])
+def test_causal_conv1d_matches_explicit_shifts_and_leaks_nothing(dtype,
+                                                                 tolerance):
+    """SiLU of four explicit shifts; in bf16 the taps are summed in
+    float32 and the result rounded once (0.4 %), on inputs rounded to
+    bf16 beforehand."""
+    import mxnet_tpu as mx
+
+    rng = np.random.RandomState(3)
+    x = mx.nd.array(rng.randn(2, 12, 6)).astype(dtype)
+    w = mx.nd.array(rng.randn(6, 4)).astype(dtype)
+    got = mx.nd.causal_conv1d(x, w)
+    assert got.dtype == x.dtype
+    got = got.astype("float32").asnumpy()
+    x, w = x.astype("float32").asnumpy(), w.astype("float32").asnumpy()
+    want = np.zeros_like(x)
+    for t in range(12):
+        for j in range(4):          # tap j reads position t - 3 + j
+            if t - 3 + j >= 0:
+                want[:, t] += w[:, j] * x[:, t - 3 + j]
+    want = want / (1 + np.exp(-want))
+    np.testing.assert_allclose(got, want, rtol=tolerance, atol=tolerance)
+    # nothing from t + 1 reaches t: perturb token 7, tokens 0-6 stay
+    later = x.copy()
+    later[:, 7] += 10.0
+    moved = mx.nd.causal_conv1d(
+        mx.nd.array(later).astype(dtype),
+        mx.nd.array(w).astype(dtype)).astype("float32").asnumpy()
+    np.testing.assert_array_equal(moved[:, :7], got[:, :7])
+    assert np.abs(moved[:, 7:11] - got[:, 7:11]).min() > 0
+    np.testing.assert_array_equal(moved[:, 11], got[:, 11])
+
+
+def test_the_trainers_remat_keeps_what_the_ops_name():
+    """One list of the names a `jax.checkpoint` policy keeps
+    (`ops.registry.RESIDUAL_NAMES`): each op names its outputs from it,
+    and `DataParallelTrainer(remat=True)` saves them all, without
+    naming an op."""
+    from mxnet_tpu.ops import linear_attention as la
+    from mxnet_tpu.ops import registry
+    from mxnet_tpu.ops.pallas import flash_attention
+    from mxnet_tpu.parallel import data_parallel
+
+    assert la.RESIDUAL_NAMES == ("delta_rule_out",)
+    assert registry.RESIDUAL_NAMES == {
+        "flash_attention": flash_attention.RESIDUAL_NAMES,
+        "gated_delta_rule": la.RESIDUAL_NAMES}
+    assert set(data_parallel._remat_saves()) == {
+        "flash_out", "flash_lse", "delta_rule_out"}
+    jaxpr = jax.make_jaxpr(la._k_gated_delta_rule)(*_inputs(seq=64))
+    assert "name=delta_rule_out" in str(jaxpr)
+
+
+def test_l2_norm_and_the_norms_new_forms():
+    import mxnet_tpu as mx
+
+    rng = np.random.RandomState(4)
+    x = rng.randn(3, 5, 16).astype(np.float32)
+    gamma = rng.randn(16).astype(np.float32) * 0.1
+    gate = rng.randn(3, 5, 16).astype(np.float32)
+    np.testing.assert_allclose(
+        mx.nd.l2_norm(mx.nd.array(x), eps=1e-6).asnumpy(),
+        x / np.sqrt((x * x).sum(-1, keepdims=True) + 1e-6), rtol=1e-5)
+    x_hat = x / np.sqrt((x * x).mean(-1, keepdims=True) + 1e-6)
+    np.testing.assert_allclose(
+        mx.nd.rms_norm(mx.nd.array(x), mx.nd.array(gamma), eps=1e-6,
+                       zero_centered=True).asnumpy(),
+        x_hat * (1 + gamma), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        mx.nd.gated_rms_norm(mx.nd.array(x), mx.nd.array(gate),
+                             mx.nd.array(gamma), eps=1e-6).asnumpy(),
+        x_hat * gamma * gate / (1 + np.exp(-gate)), rtol=1e-5, atol=1e-6)
+
+
+def test_linear_attention_section_is_on_metrics():
+    from mxnet_tpu import profiler
+    from mxnet_tpu.ops import linear_attention as la
+    from mxnet_tpu.telemetry import metrics
+
+    assert "linearAttention" in profiler.section_names()
+    profiler.sections(reset=True)
+    assert profiler.sections()["linearAttention"]["layers"] == 0
+    args = _inputs(seq=200)
+    la._k_gated_delta_rule(*args)
+    la._k_gated_delta_rule(*args)
+    stats = profiler.sections()["linearAttention"]
+    key = "b2 h4 s200 k16 v24 float32"
+    assert stats == la.linear_attention_stats()
+    assert stats["layers"] == 1 and stats["traces"] == {key: 2}
+    assert stats["chunk"][key] == 64
+    assert stats["chunks_per_sequence"][key] == 4       # 200 padded to 256
+    assert stats["state_bytes_kept"][key] == 4 * 2 * 4 * 16 * 24 * 4
+    text = metrics.default_registry().render()
+    assert 'mxtpu_linear_attention_state_bytes_kept{key="' + key in text
+    assert "mxtpu_linear_attention_layers 1" in text
+    table = "\n".join(profiler._section_tables())
+    assert "Linear Attention" in table and "4 chunks of 64" in table
+    assert profiler.sections(reset=True)["linearAttention"] == stats
+    assert profiler.sections()["linearAttention"]["layers"] == 0
