@@ -26,6 +26,42 @@ x 4 blocks has a zero fourth power).  The whole 64-step product form
 would be as exact on paper and loses everything to cancellation where
 keys repeat (its powers grow like binomial coefficients).
 
+The intra-chunk part is ONE function with its own derivative
+(`_wy`).  For one (sequence, value head) and one chunk, from q, k (the
+key head's: value head h reads key head h // r), v, g, beta it returns
+what the chunk scan consumes, the chunk axis first:
+
+    G = cumsum(g)    D_ij = exp(G_i - G_j), i >= j
+    A = strict_lower(beta_i D_ij (k k^T)_ij)         T = (I + A)^-1
+    u = T (beta v)   w = T (beta e^G k)   within = D * (q k^T)
+    q_in = q e^G     k_out = k exp(G_C - G)          last = exp(G_C)
+
+and, given their cotangents, the gradients by the closed form, never
+through the inverse's products:
+
+    dT = du (beta v)^T + dw (beta e^G k)^T
+    d(beta v) = T^T du    d(beta e^G k) = T^T dw
+    dA = -strict_lower(T^T dT T^T)        (exact float32, as T itself)
+    with M = k k^T, N = q k^T:
+    dM = dA * beta_i D_ij    dN = dwithin * D
+    dD = dA * beta_i M + dwithin * N
+    dk += (dM + dM^T) k + dN^T q          dq += dN k
+    dbeta_i += sum_j dA_ij D_ij M_ij
+    dG_i += sum_j dD_ij D_ij              dG_j -= sum_i dD_ij D_ij
+
+plus the element-wise terms of beta v, beta e^G k, q e^G, k exp(G_C -
+G) and exp(G_C); dg is the reverse running sum of dG inside the chunk,
+dq and dk sum over the r value heads of a key head.  The backward
+keeps the five inputs and T (float32, 33.5 MB a head group at 2 x
+8,192 tokens), none of the inverse's intermediate powers.  Two forms
+of it, one mathematics: `_wy_xla` in `jax.numpy`, and the kernel pair
+of ops/pallas/delta_rule.py, taken where the computation is LOWERED
+for the TPU (`lax.platform_dependent`) and the shapes allow it: an even
+number of chunks, key and value sizes multiples of 128, bf16 or
+float32.  tests/test_linear_attention.py holds `_wy_xla` to
+`jax.grad` of the plainly differentiated rule and the kernels to
+`_wy_xla`.  The chunk scan stays a `lax.scan` differentiated by JAX.
+
 Memory.  No pass holds a state a TOKEN.  The heads are worked on in
 groups, one after another (`head_groups`), each group recomputed inside
 its own backward pass, which is the chunk scan's own derivative: it
@@ -40,14 +76,13 @@ group at a time inside the backward), not three times.
 
 Products take their operands in the dtype of q, k, v and accumulate in
 float32 (`precision="highest"`: exact float32 products for float32
-operands); the state, the decays, the inverse and every sum are
-float32.  A length that is no multiple of the chunk is padded with
-tokens that leave the state as it is (beta 0, g 0) and whose outputs
-are dropped.
+operands), the cotangents' products too; the state, the decays, the
+inverse, its derivative's two products and every sum are float32.  A
+length that is no multiple of the chunk is padded with tokens that
+leave the state as it is (beta 0, g 0) and whose outputs are dropped.
 
-The XLA form below is the only form: a Pallas kernel for the rule is
-ROADMAP R6's next step.  Every rule traced is counted (the profiler
-section `linearAttention`).
+Every rule traced is counted, and whether its shapes are the kernels'
+(the profiler section `linearAttention`).
 """
 from __future__ import annotations
 
@@ -69,8 +104,9 @@ _PAIRS = 16     # (sequence, value head) pairs a pass of the rule holds
 RESIDUAL_NAMES = registry.RESIDUAL_NAMES["gated_delta_rule"]
 
 # every rule traced: (batch, value heads, seq, key size, value size,
-# dtype) -> traces
+# dtype) -> traces, and those of them whose shapes the kernels take
 _traced = collections.Counter()
+_kernel_traced = collections.Counter()
 
 
 def _k_l2_norm(data, *, eps=1e-6):
@@ -101,52 +137,74 @@ def _k_causal_conv1d(data, weight):
 register("causal_conv1d", _k_causal_conv1d, arg_names=("data", "weight"))
 
 
+def _exact(x, y):
+    return jnp.matmul(x, y, precision="highest")
+
+
+def _dot(dtype):
+    """Products of two operands rounded to `dtype`, accumulated in
+    float32 (exact float32 products for float32 operands)."""
+    def dot(spec, x, y):
+        return jnp.einsum(spec, x.astype(dtype), y.astype(dtype),
+                          precision="highest",
+                          preferred_element_type=jnp.float32)
+
+    return dot
+
+
 def _unit_lower_inverse(a):
     """(I + a)^-1 for strictly lower triangular `a` (..., CHUNK, CHUNK),
     float32, by matrix products alone (module docstring)."""
     eye = jnp.eye(CHUNK, dtype=a.dtype)
 
-    def mm(x, y):
-        return jnp.matmul(x, y, precision="highest")
-
     def nilpotent_inverse(n, index):
         # (I - n)^-1 for n^index = 0: the product of (I + n^(2^j))
         out, power, reach = eye + n, n, 2
         while reach < index:
-            power = mm(power, power)
-            out = out + mm(out, power)
+            power = _exact(power, power)
+            out = out + _exact(out, power)
             reach *= 2
         return out
 
     block = jnp.arange(CHUNK) // _BLOCK
     same = block[:, None] == block[None, :]
     diagonal = nilpotent_inverse(-jnp.where(same, a, 0.0), _BLOCK)
-    below = mm(diagonal, jnp.where(same, 0.0, a))
-    return mm(nilpotent_inverse(-below, CHUNK // _BLOCK), diagonal)
+    below = _exact(diagonal, jnp.where(same, 0.0, a))
+    return _exact(nilpotent_inverse(-below, CHUNK // _BLOCK), diagonal)
 
 
-def _rule(q, k, v, g, beta):
-    """The chunked rule for heads that are worked on together: q, k, v
-    (b, h, seq, size), g and beta (b, h, seq), seq a multiple of
-    `CHUNK`."""
-    b, h, seq, dv = v.shape
-    dk, dtype, n = k.shape[-1], v.dtype, seq // CHUNK
+def _wy_prelude(q, k, v, g, beta):
+    """What the forward and the backward of the intra-chunk part both
+    start from, by chunk: q, k (repeated to the value heads), v as (b,
+    h, n, CHUNK, size), beta and the running sum G of g inside each
+    chunk as (b, h, n, CHUNK) float32, decay[i, j] = exp(G_i - G_j) for
+    i >= j and 0 above the diagonal."""
+    h, n = v.shape[1], v.shape[2] // CHUNK
 
-    def dot(spec, x, y):
-        return jnp.einsum(spec, x.astype(dtype), y.astype(dtype),
-                          precision="highest",
-                          preferred_element_type=jnp.float32)
+    def chunks(x):
+        return x.reshape(x.shape[:2] + (n, CHUNK) + x.shape[3:])
 
-    q, k, v = (x.reshape(b, h, n, CHUNK, x.shape[-1]) for x in (q, k, v))
-    beta = beta.astype(jnp.float32).reshape(b, h, n, CHUNK)
-    total = jnp.cumsum(g.astype(jnp.float32).reshape(b, h, n, CHUNK), -1)
-    # decay[i, j] = exp(G_i - G_j) for i >= j, 0 above the diagonal
+    q, k = (jnp.repeat(chunks(x), h // k.shape[1], axis=1) for x in (q, k))
+    beta = chunks(beta.astype(jnp.float32))
+    total = jnp.cumsum(chunks(g.astype(jnp.float32)), -1)
     seen = jnp.tril(jnp.ones((CHUNK, CHUNK), bool))
     decay = jnp.where(seen, jnp.exp(jnp.where(
         seen, total[..., :, None] - total[..., None, :], 0.0)), 0.0)
-    strict = jnp.tril(jnp.ones((CHUNK, CHUNK), bool), -1)
-    a = jnp.where(strict, beta[..., None] * decay
-                  * dot("...id,...jd->...ij", k, k), 0.0)
+    return q, k, chunks(v), beta, total, decay
+
+
+def _strict_lower(x):
+    return jnp.where(jnp.tril(jnp.ones((CHUNK, CHUNK), bool), -1), x, 0.0)
+
+
+def _wy_forward(q, k, v, g, beta):
+    """The intra-chunk part in `jax.numpy` (module docstring): the six
+    arrays the chunk scan consumes, the chunk axis first, and the
+    inverse T (b, h, n, CHUNK, CHUNK) that the backward keeps."""
+    dtype, dot = v.dtype, _dot(v.dtype)
+    q, k, v, beta, total, decay = _wy_prelude(q, k, v, g, beta)
+    a = _strict_lower(beta[..., None] * decay
+                      * dot("...id,...jd->...ij", k, k))
     inverse = _unit_lower_inverse(a)
     into = jnp.exp(total)[..., None]            # from the chunk's start
     u = dot("...ij,...jd->...id", inverse, v * beta[..., None])
@@ -154,6 +212,101 @@ def _rule(q, k, v, g, beta):
     within = decay * dot("...id,...jd->...ij", q, k)
     # what each token's k d^T is worth at the chunk's end
     k_out = k * jnp.exp(total[..., -1:] - total)[..., None]
+    outs = (u, w.astype(dtype), within.astype(dtype),
+            (q * into).astype(dtype), k_out.astype(dtype),
+            jnp.exp(total[..., -1]))
+    return tuple(jnp.moveaxis(x, 2, 0) for x in outs), inverse
+
+
+def _wy_backward(q, k, v, g, beta, inverse, cotangents):
+    """The derivative of `_wy_forward` by its closed form (module
+    docstring): nothing is differentiated through the inverse."""
+    shapes = [(x.shape, x.dtype) for x in (q, k, v, g, beta)]
+    hk, dot = q.shape[1], _dot(v.dtype)
+    q, k, v, beta, total, decay = _wy_prelude(q, k, v, g, beta)
+    du, dw, dwithin, dq_in, dk_out, dlast = (
+        jnp.moveaxis(x, 0, 2).astype(jnp.float32) for x in cotangents)
+    q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
+    m = dot("...id,...jd->...ij", k, k)
+    n = dot("...id,...jd->...ij", q, k)
+    into = jnp.exp(total)[..., None]
+    out = jnp.exp(total[..., -1:] - total)[..., None]
+    b_col = beta[..., None]
+    # u = T (beta v), w = T (beta e^G k): T's cotangent, then A's
+    d_t = dot("...id,...jd->...ij", du, v32 * b_col) \
+        + dot("...id,...jd->...ij", dw, k32 * (b_col * into))
+    d_bv = dot("...ji,...jd->...id", inverse, du)
+    d_bk = dot("...ji,...jd->...id", inverse, dw)
+    transposed = jnp.swapaxes(inverse, -1, -2)
+    d_a = -_strict_lower(_exact(_exact(transposed, d_t), transposed))
+    d_m = d_a * b_col * decay
+    d_n = dwithin * decay
+    through_decay = (d_a * b_col * m + dwithin * n) * decay
+    d_k = dot("...ij,...jd->...id", d_m + jnp.swapaxes(d_m, -1, -2), k) \
+        + dot("...ji,...jd->...id", d_n, q)
+    d_q = dot("...ij,...jd->...id", d_n, k)
+    # the element-wise terms: beta v, beta e^G k, q e^G, k e^(G_C - G)
+    bk_k = (d_bk * k32).sum(-1)
+    at_end = (dk_out * k32).sum(-1) * out[..., 0]
+    d_v = b_col * d_bv
+    d_beta = (d_a * decay * m).sum(-1) + (d_bv * v32).sum(-1) \
+        + into[..., 0] * bk_k
+    d_k = d_k + (b_col * into) * d_bk + dk_out * out
+    d_q = d_q + dq_in * into
+    d_total = through_decay.sum(-1) - through_decay.sum(-2) \
+        + into[..., 0] * (beta * bk_k + (dq_in * q32).sum(-1)) - at_end
+    d_end = at_end.sum(-1) + dlast * jnp.exp(total[..., -1])
+    # g reaches G_i of every later token of its chunk, G_C included
+    d_g = jnp.flip(jnp.cumsum(jnp.flip(d_total, -1), -1), -1) \
+        + d_end[..., None]
+    d_q, d_k = (x.reshape((x.shape[0], hk, -1) + x.shape[2:]).sum(2)
+                for x in (d_q, d_k))
+    return tuple(x.reshape(shape).astype(dtype) for x, (shape, dtype)
+                 in zip((d_q, d_k, d_v, d_g, d_beta), shapes))
+
+
+@jax.custom_vjp
+def _wy_xla(q, k, v, g, beta):
+    return _wy_forward(q, k, v, g, beta)[0]
+
+
+def _wy_xla_fwd(*xs):
+    outs, inverse = _wy_forward(*xs)
+    return outs, xs + (inverse,)
+
+
+_wy_xla.defvjp(_wy_xla_fwd,
+               lambda kept, cotangents: _wy_backward(*kept, cotangents))
+
+
+def _wy(q, k, v, g, beta):
+    """The intra-chunk part: the kernels of ops/pallas/delta_rule.py
+    where the computation is lowered for the TPU and they take the
+    shapes, `_wy_xla` everywhere else."""
+    from .pallas import delta_rule
+
+    if not delta_rule.admits(q, k, v):
+        return _wy_xla(q, k, v, g, beta)
+
+    def kernels(*xs):
+        from ..parallel.mesh import per_batch_shard
+
+        # the chunk axis leads what the scan consumes; a step that is
+        # partitioned over the batch wants the batch there
+        outs = per_batch_shard(lambda *xs: tuple(
+            jnp.moveaxis(x, 1, 0) for x in delta_rule.wy(*xs)), *xs)
+        return tuple(jnp.moveaxis(x, 0, 1) for x in outs)
+
+    return jax.lax.platform_dependent(q, k, v, g, beta, tpu=kernels,
+                                      default=_wy_xla)
+
+
+def _rule(q, k, v, g, beta):
+    """The chunked rule for heads that are worked on together: q, k
+    (b, key heads, seq, size), v (b, h, seq, size), g and beta (b, h,
+    seq), seq a multiple of `CHUNK`."""
+    b, h, seq, dv = v.shape
+    dk, dtype, dot = k.shape[-1], v.dtype, _dot(v.dtype)
 
     def step(state, xs):
         u, w, within, q_in, k_out, last = xs
@@ -164,11 +317,8 @@ def _rule(q, k, v, g, beta):
             + dot("...id,...ie->...de", k_out, d)
         return state, o.astype(dtype)
 
-    by_chunk = [jnp.moveaxis(x, 2, 0) for x in (
-        u, w.astype(dtype), within.astype(dtype), (q * into).astype(dtype),
-        k_out.astype(dtype), jnp.exp(total[..., -1]))]
     _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, dv), jnp.float32),
-                        by_chunk)
+                        _wy(q, k, v, g, beta))
     return jnp.moveaxis(o, 0, 2).reshape(b, h, seq, dv)
 
 
@@ -195,13 +345,14 @@ def _k_gated_delta_rule(q, k, v, g, beta):
     if h % hk:
         raise ValueError(f"gated_delta_rule: {h} value heads over "
                          f"{hk} key heads")
-    _traced[(b, h, seq, dk, dv, jnp.dtype(v.dtype).name)] += 1
+    from .pallas import delta_rule
+
+    key = (b, h, seq, dk, dv, jnp.dtype(v.dtype).name)
+    _traced[key] += 1
     groups = head_groups(b, hk, h)
 
     def one_group(xs):
-        q, k, v, g, beta = xs
-        q, k = (jnp.repeat(x, h // hk, axis=1) for x in (q, k))
-        return _rule(q, k, v, g, beta)
+        return _rule(*xs)
 
     with jax.named_scope("delta_rule"):
         pad = -seq % CHUNK
@@ -211,6 +362,7 @@ def _k_gated_delta_rule(q, k, v, g, beta):
                        for x in (q, k, v))
             g, beta = (jnp.pad(x, ((0, 0), (0, 0), (0, pad)))
                        for x in (g, beta))
+        _kernel_traced[key] += delta_rule.admits(q, k, v)
         if groups == 1:
             o = one_group((q, k, v, g, beta))
         else:
@@ -234,12 +386,16 @@ def linear_attention_stats():
     `chunks_per_sequence` and `state_bytes_kept` (the float32 states at
     the chunks' starts that the scan's derivative keeps for the layer
     being differentiated) by shape."""
-    out = {"layers": len(_traced), "traces": {}, "chunk": {},
-           "chunks_per_sequence": {}, "state_bytes_kept": {}}
-    for (b, h, seq, dk, dv, dtype), n in _traced.items():
+    out = {"layers": len(_traced), "traces": {}, "kernel_traces": {},
+           "xla_traces": {}, "chunk": {}, "chunks_per_sequence": {},
+           "state_bytes_kept": {}}
+    for shape, n in _traced.items():
+        b, h, seq, dk, dv, dtype = shape
         key = f"b{b} h{h} s{seq} k{dk} v{dv} {dtype}"
         chunks = -(-seq // CHUNK)
         out["traces"][key] = n
+        out["kernel_traces"][key] = _kernel_traced[shape]
+        out["xla_traces"][key] = n - _kernel_traced[shape]
         out["chunk"][key] = CHUNK
         out["chunks_per_sequence"][key] = chunks
         out["state_bytes_kept"][key] = chunks * b * h * dk * dv * 4
@@ -248,12 +404,15 @@ def linear_attention_stats():
 
 def reset_linear_attention_stats():
     _traced.clear()
+    _kernel_traced.clear()
 
 
 def _stats_table(stats):
     out = ["Linear Attention (delta rules traced):"]
     for key in sorted(stats["traces"]):
-        out.append(f"  {key}: x{stats['traces'][key]}, "
+        out.append(f"  {key}: x{stats['traces'][key]} "
+                   f"({stats['kernel_traces'][key]} of shapes the kernels "
+                   f"take), "
                    f"{stats['chunks_per_sequence'][key]} chunks of "
                    f"{stats['chunk'][key]}, keeps "
                    f"{stats['state_bytes_kept'][key]} bytes of states")

@@ -312,8 +312,14 @@ def test_delta_rule_at_the_qwen_cells_shape_holds_no_state_a_token_aot(
     bf16, forward and backward: it compiles for v5e, its largest
     float32 array is the states at the chunks' starts of one head group
     (128 x 2 x 8 x 128 x 128), nothing the size of a state a token
-    (8,192 x 64 KB a head), and its temporaries stay under 3 GB (2.37
-    when written; 7.0 before the heads were worked on in groups)."""
+    (8,192 x 64 KB a head), and its temporaries stay under 2 GB (1.25
+    with the intra-chunk kernels; 2.37 while JAX differentiated through
+    the inverse; 7.0 before the heads were worked on in groups).  The
+    intra-chunk part is three Mosaic calls (the forward kernel in the
+    pass and in a group's recomputation, the backward kernel), each
+    under `/delta_rule/`, where `delta_rule_roofline` and
+    `linear_attn_ms` look for the rule's events; no (2, 8, 128, 64, 64)
+    float32 array of the inverse's products is left."""
     import re
 
     from mxnet_tpu.ops.linear_attention import (_k_gated_delta_rule,
@@ -325,8 +331,11 @@ def test_delta_rule_at_the_qwen_cells_shape_holds_no_state_a_token_aot(
     assert head_groups(2, 16, 32) == 4
 
     def loss(q, k, v, g, beta):
-        return _k_gated_delta_rule(q, k, v, g, beta) \
-            .astype(jnp.float32).sum()
+        # inside another scope, as in the trainer's step: the outermost
+        # scope of a differentiated function is named `jvp(<scope>)`
+        with jax.named_scope("forward"):
+            return _k_gated_delta_rule(q, k, v, g, beta) \
+                .astype(jnp.float32).sum()
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
                        in_shardings=(one_chip,) * 5) \
@@ -338,8 +347,65 @@ def test_delta_rule_at_the_qwen_cells_shape_holds_no_state_a_token_aot(
     states_kept = 128 * 2 * 8 * 128 * 128 * 4
     assert states_kept in sizes
     assert max(sizes) <= 2 * states_kept, max(sizes)
-    assert compiled.memory_analysis().temp_size_in_bytes < 3e9
-    assert "(delta_rule)" in text and "while" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    assert "/delta_rule/" in text and "while" in text
+    kernels = re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
+    assert len(kernels) == text.count("tpu_custom_call") == 3
+    assert all("/delta_rule/" in name for name in kernels), kernels
+    assert sum("transpose(" in name for name in kernels) == 2
+    assert "f32[2,8,128,64,64]" not in text
+
+
+@pytest.mark.parametrize("dt,dk,dv", [
+    (jnp.float32, 128, 256), (jnp.bfloat16, 128, 256),
+    (jnp.float32, 512, 512)], ids=["f32", "bf16", "f32-heads-of-512"])
+def test_delta_rule_kernels_aot(one_chip, dt, dk, dv):
+    """The rule's two kernels in both dtypes at a short sequence, key
+    heads under value heads, one head group and 12 pairs of chunks (a
+    block of 6 a grid step; of 3 at float32 heads of 512, whose blocks
+    would not fit the kernels' VMEM at 6: the block is sized from the
+    bytes); lowered for the CPU the same call holds no kernel."""
+    from mxnet_tpu.ops.linear_attention import _k_gated_delta_rule
+
+    qk = jax.ShapeDtypeStruct((1, 2, 1536, dk), dt)
+    v = jax.ShapeDtypeStruct((1, 4, 1536, dv), dt)
+    gb = jax.ShapeDtypeStruct((1, 4, 1536), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        return _k_gated_delta_rule(q, k, v, g, beta) \
+            .astype(jnp.float32).sum()
+
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
+    text = jax.jit(grad, in_shardings=(one_chip,) * 5) \
+        .lower(qk, qk, v, gb, gb).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "custom_call" not in jax.jit(grad).lower(
+        qk, qk, v, gb, gb).as_text()
+
+
+def test_delta_rule_kernels_under_auto_partitioning(four_chips):
+    """As the attention kernels: in a step partitioned over the batch
+    the rule's kernels run a shard of the batch each
+    (`per_batch_shard`), the chunk scan around them is partitioned by
+    the compiler."""
+    from mxnet_tpu.ops.linear_attention import _k_gated_delta_rule
+    from mxnet_tpu.parallel import mesh as mesh_mod
+
+    mesh, batch = four_chips
+    qk = jax.ShapeDtypeStruct((4, 2, 256, 128), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((4, 4, 256, 128), jnp.bfloat16)
+    gb = jax.ShapeDtypeStruct((4, 4, 256), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        with mesh_mod.auto_partitioned(mesh):
+            return _k_gated_delta_rule(q, k, v, g, beta) \
+                .astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
+                   in_shardings=(batch,) * 5) \
+        .lower(qk, qk, v, gb, gb).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
 
 
 def _hybrid_step_aot(topo, monkeypatch, seq=512):

@@ -2,8 +2,10 @@
 delta rule against a token-by-token scan (values and every gradient,
 several chunks, a length that is no multiple of the chunk, decays near
 0 and near 1, the head groups), the triangular inverse where keys
-repeat, the causal convolution against explicit shifts, and the
-`linearAttention` profiler section."""
+repeat, the intra-chunk part's closed-form derivative against the
+plainly differentiated rule, the Pallas kernels (interpreted) against
+the `jax.numpy` form, the causal convolution against explicit shifts,
+and the `linearAttention` profiler section."""
 from __future__ import annotations
 
 import numpy as np
@@ -150,6 +152,149 @@ def test_unit_lower_inverse_is_exact_where_keys_repeat():
                                atol=2e-4)
 
 
+def _rule_plainly_differentiated(q, k, v, g, beta):
+    """The chunked rule as it stood before its intra-chunk part had a
+    derivative of its own: JAX differentiates through everything, the
+    inverse's ten products included.  The plain reference for
+    `_wy_xla`."""
+    from mxnet_tpu.ops.linear_attention import CHUNK, _unit_lower_inverse
+
+    b, h, seq, dv = v.shape
+    dk, dtype, n = k.shape[-1], v.dtype, seq // CHUNK
+    q, k = (jnp.repeat(x, h // k.shape[1], axis=1) for x in (q, k))
+
+    def dot(spec, x, y):
+        return jnp.einsum(spec, x.astype(dtype), y.astype(dtype),
+                          precision="highest",
+                          preferred_element_type=jnp.float32)
+
+    q, k, v = (x.reshape(b, h, n, CHUNK, x.shape[-1]) for x in (q, k, v))
+    beta = beta.astype(jnp.float32).reshape(b, h, n, CHUNK)
+    total = jnp.cumsum(g.astype(jnp.float32).reshape(b, h, n, CHUNK), -1)
+    seen = jnp.tril(jnp.ones((CHUNK, CHUNK), bool))
+    decay = jnp.where(seen, jnp.exp(jnp.where(
+        seen, total[..., :, None] - total[..., None, :], 0.0)), 0.0)
+    strict = jnp.tril(jnp.ones((CHUNK, CHUNK), bool), -1)
+    a = jnp.where(strict, beta[..., None] * decay
+                  * dot("...id,...jd->...ij", k, k), 0.0)
+    inverse = _unit_lower_inverse(a)
+    into = jnp.exp(total)[..., None]
+    u = dot("...ij,...jd->...id", inverse, v * beta[..., None])
+    w = dot("...ij,...jd->...id", inverse, k * (beta[..., None] * into))
+    within = decay * dot("...id,...jd->...ij", q, k)
+    k_out = k * jnp.exp(total[..., -1:] - total)[..., None]
+
+    def step(state, xs):
+        u, w, within, q_in, k_out, last = xs
+        d = u - dot("...id,...de->...ie", w, state)
+        o = dot("...id,...de->...ie", q_in, state) \
+            + dot("...ij,...je->...ie", within, d)
+        state = state * last[..., None, None] \
+            + dot("...id,...ie->...de", k_out, d)
+        return state, o.astype(dtype)
+
+    by_chunk = [jnp.moveaxis(x, 2, 0) for x in (
+        u, w.astype(dtype), within.astype(dtype), (q * into).astype(dtype),
+        k_out.astype(dtype), jnp.exp(total[..., -1]))]
+    _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, dv), jnp.float32),
+                        by_chunk)
+    return jnp.moveaxis(o, 0, 2).reshape(b, h, seq, dv)
+
+
+def _case(name, dtype, **shape):
+    """Inputs that lean on one part of the derivative each."""
+    q, k, v, g, beta = _inputs(dtype=dtype, **shape)
+    if name == "strong_decay":
+        g = jnp.full_like(g, -40.0).at[:, ::2, ::3].set(-0.5)
+    elif name == "repeated_keys":
+        k = jnp.broadcast_to(k[:, :, :1], k.shape)
+        beta = jnp.full_like(beta, 0.9)
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("dtype,tolerance,ill_conditioned", [
+    ("float32", 2e-5, 1e-2), ("bfloat16", 4e-2, 1e-1)])
+@pytest.mark.parametrize("name", ["mixed", "strong_decay", "repeated_keys"])
+def test_closed_form_derivative_matches_the_plainly_differentiated_rule(
+        name, dtype, tolerance, ill_conditioned):
+    """`_wy_xla`'s backward is the same function's derivative: against
+    `jax.value_and_grad` THROUGH the inverse's products, the value and
+    all five gradients agree to float32 round-off (a missing term of
+    dG, or dA's sign, fails by the gradient's own size); in bf16 to
+    the rounding of the cotangents' operands, a few products deep.  2
+    key heads under 4 value heads: dq and dk sum over the pair.  Where
+    every key is the same the triangular system is ill-conditioned:
+    the two float32 derivatives sit 2e-3 and 3e-3 of dg's size from
+    the token scan's, 5e-3 from each other."""
+    from mxnet_tpu.ops import linear_attention as la
+    from mxnet_tpu.ops.pallas import delta_rule
+
+    args = _case(name, dtype, seq=192)
+    assert not delta_rule.admits(*args[:3])
+    (got, got_grads), (want, want_grads) = (
+        jax.value_and_grad(lambda *a, fn=fn: _weighted(fn, *a),
+                           argnums=(0, 1, 2, 3, 4))(*args)
+        for fn in (la._rule, _rule_plainly_differentiated))
+    assert np.isfinite(float(got))
+    assert abs(float(got) - float(want)) <= 1e-6 * abs(float(want))
+    for which, g, w in zip("q k v g beta".split(), got_grads, want_grads):
+        assert g.dtype == w.dtype and g.shape == w.shape, which
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        assert float(jnp.abs(w).max()) > 0, which
+        assert float(jnp.abs(g - w).max()) < (
+            ill_conditioned if name == "repeated_keys" else tolerance
+        ) * float(jnp.abs(w).max()), which
+
+
+@pytest.mark.parametrize("dtype,tolerance", [
+    ("float32", 1e-5), ("bfloat16", 2e-3)])
+def test_kernels_match_the_jnp_form_interpreted(interpret_pallas, dtype,
+                                                tolerance):
+    """Both kernels of ops/pallas/delta_rule.py against `_wy_xla` at dk
+    = dv = 128, two pairs of chunks, one key head under two value
+    heads: the six outputs (shape, dtype, chunk axis first) and the
+    five gradients for random cotangents.  The two share every
+    rounding, so float32 agrees to round-off and bf16 but for a last
+    bit of an operand (a lost mask or a block of the pair placed in
+    the other chunk fails by 1)."""
+    from mxnet_tpu.ops import linear_attention as la
+    from mxnet_tpu.ops.pallas import delta_rule
+
+    args = _case("mixed", dtype, b=1, hk=1, hv=2, seq=256, dk=128, dv=128)
+    assert delta_rule.admits(*args[:3])
+    got, got_vjp = jax.vjp(delta_rule.wy, *args)
+    want, want_vjp = jax.vjp(la._wy_xla, *args)
+    rng = np.random.RandomState(5)
+    cotangents = tuple(jnp.asarray(rng.randn(*x.shape), x.dtype)
+                       for x in want)
+
+    def near(name, g, w):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        assert float(jnp.abs(g - w).max()) <= tolerance * float(
+            jnp.abs(w).max()), name
+
+    assert want[0].shape == (4, 1, 2, 64, 128)
+    for name, g, w in zip("u w within q_in k_out last".split(), got, want):
+        near(name, g, w)
+    for name, g, w in zip("q k v g beta".split(), got_vjp(cotangents),
+                          want_vjp(cotangents)):
+        near("d" + name, g, w)
+
+
+def test_kernels_take_whole_lanes_and_pairs_of_chunks_only():
+    from mxnet_tpu.ops.pallas import delta_rule
+
+    def admits(seq=256, dk=128, dv=128, dtype=jnp.bfloat16):
+        q = jax.ShapeDtypeStruct((2, 2, seq, dk), dtype)
+        return delta_rule.admits(
+            q, q, jax.ShapeDtypeStruct((2, 4, seq, dv), dtype))
+
+    assert admits() and admits(dtype=jnp.float32) and admits(dk=256)
+    assert not admits(seq=192) and not admits(dk=64) and not admits(dv=96)
+    assert not admits(dtype=jnp.float16)
+
+
 def test_value_heads_must_be_a_multiple_of_key_heads():
     from mxnet_tpu.ops import linear_attention as la
 
@@ -248,12 +393,27 @@ def test_linear_attention_section_is_on_metrics():
     key = "b2 h4 s200 k16 v24 float32"
     assert stats == la.linear_attention_stats()
     assert stats["layers"] == 1 and stats["traces"] == {key: 2}
+    # heads of 16 and 24 fill no lane: the `jax.numpy` form
+    assert stats["kernel_traces"] == {key: 0}
+    assert stats["xla_traces"] == {key: 2}
     assert stats["chunk"][key] == 64
     assert stats["chunks_per_sequence"][key] == 4       # 200 padded to 256
     assert stats["state_bytes_kept"][key] == 4 * 2 * 4 * 16 * 24 * 4
     text = metrics.default_registry().render()
     assert 'mxtpu_linear_attention_state_bytes_kept{key="' + key in text
     assert "mxtpu_linear_attention_layers 1" in text
+    assert 'mxtpu_linear_attention_xla_traces{key="' + key + '"} 2' in text
+    # whole lanes, an even number of chunks (200 padded to 256): the
+    # kernels' shapes, counted whatever the platform lowered for
+    wide = _inputs(b=1, hk=1, hv=2, seq=200, dk=128, dv=128)
+    la._k_gated_delta_rule(*wide)
+    stats = la.linear_attention_stats()
+    wide_key = "b1 h2 s200 k128 v128 float32"
+    assert stats["kernel_traces"][wide_key] == 1
+    assert stats["xla_traces"][wide_key] == 0
+    assert "(1 of shapes the kernels take)" in "\n".join(
+        profiler._section_tables())
+    stats = profiler.sections()["linearAttention"]
     table = "\n".join(profiler._section_tables())
     assert "Linear Attention" in table and "4 chunks of 64" in table
     assert profiler.sections(reset=True)["linearAttention"] == stats
