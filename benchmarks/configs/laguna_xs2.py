@@ -7,6 +7,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from harness import work
+
 BYTES_PER_ELEMENT = 2
 
 
@@ -98,6 +100,22 @@ def attention_work(config, traffic):
         flops += 12 * b * heads * visible_pairs(s, window) * d
         moved += (6 * heads + 6 * kv) * b * s * d * BYTES_PER_ELEMENT
     return flops, moved
+
+
+def projection_work(config, traffic):
+    """(FLOPs, bytes) of every dense product of every mixer held, by
+    the published shapes, whatever implements them: q, the shared k and
+    v, the per-head output gate and the output projection of each
+    attention layer at its own head count (`harness/work.py:
+    dense_work`).  Norms, rotary embedding, the gate's sigmoid and the
+    head transposes are not counted."""
+    h, d = config["hidden_size"], config["head_dim"]
+    kv = config["num_key_value_heads"]
+    products = []
+    for heads, _, _ in layers(config):
+        products += [(h, heads * d), (h, kv * d), (h, kv * d), (h, heads),
+                     (heads * d, h)]
+    return work.dense_work(units_per_step(config, traffic), products)
 
 
 def attention_kernel_events(config, traffic):

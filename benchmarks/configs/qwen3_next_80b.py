@@ -8,6 +8,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from harness import work
+
 BYTES_PER_ELEMENT = 2
 
 
@@ -137,6 +139,26 @@ def attention_work(config, traffic):
     layers = _count(config, "full_attention")
     return (layers * 12 * b * n * visible_pairs(s) * d,
             layers * (6 * n + 6 * kv) * b * s * d * BYTES_PER_ELEMENT)
+
+
+def projection_work(config, traffic):
+    """(FLOPs, bytes) of every dense product of every mixer held, by
+    the published shapes, whatever implements them (`harness/work.py:
+    dense_work`): the
+    attention layer's `[q | gate]` (doubled: the gate is an element's),
+    k, v and output projection; the linear mixer's `[q | k | v | z]`,
+    `[b | a]` and output projection.  Norms, rotary embedding, l2
+    norms, the gates' own arithmetic, the short convolution and the
+    rule are not counted."""
+    h, d = config["hidden_size"], config["head_dim"]
+    n, kv = config["num_attention_heads"], config["num_key_value_heads"]
+    hk, hv, dk, dv = linear_sizes(config)
+    full = [(h, 2 * n * d), (h, kv * d), (h, kv * d), (n * d, h)]
+    linear = [(h, 2 * hk * dk + 2 * hv * dv), (h, 2 * hv), (hv * dv, h)]
+    return work.dense_work(
+        units_per_step(config, traffic),
+        _count(config, "full_attention") * full
+        + _count(config, "linear_attention") * linear)
 
 
 def attention_kernel_events(config, traffic):

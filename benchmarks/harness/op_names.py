@@ -7,12 +7,13 @@ name starts with its instruction's name (`harness.trace.short_name`).
 device time of the events whose instruction's op_name `wanted` accepts,
 averaged over the devices, a step.  Instructions the compiler added
 with no op_name of their own are not counted, nor are the events of
-loops and branches themselves (their bodies' events are).  Nothing to read (no
+loops, branches and calls themselves (their bodies' events are:
+`harness.trace.operations` leaves every container out, by the
+benchmark's one match, `harness.trace.CONTAINER`).  A reader by scope
+calls this function and sums no event on its own.  Nothing to read (no
 trace, no compiled text, no step in the window) gives None.
 """
 from __future__ import annotations
-
-import re
 
 from harness import scopes, trace
 
@@ -22,10 +23,6 @@ from harness import scopes, trace
 # `named_scope`s the call was traced under are LOST, so a reader of the
 # expert layer's time asks for these beside the `/moe/` scope
 GROUPED_PRODUCT = "/ragged-dot-"
-
-# a loop's or a branch's own event spans the events of its body, which
-# the trace holds too: counting both would count the body twice
-CONTAINER = re.compile(r"^(while|conditional|cond|call)[.\d]*( |$)")
 
 
 def instruction_op_names(program_text):
@@ -50,20 +47,20 @@ def of_run(run):
     return run.instruction_op_names
 
 
-def seconds_a_step(run, wanted):
+def seconds_a_step(run, wanted, unless=None):
+    """`unless`, a compiled pattern, leaves out the events whose own
+    NAME it finds (a Mosaic call's name holds its target and its first
+    operand's shape, which no op_name does)."""
     names = of_run(run)
     if not names:
         return None
     steps = len(trace.step_starts(run.trace, run.traffic["step_program"]))
-    start, end = trace.window(run.trace)
     total = hits = 0
-    for dev in run.trace["devices"].values():
-        for name, s, d in dev["ops"]:
-            inside = min(s + d, end) - max(s, start)
-            if inside > 0 and not CONTAINER.match(name) and wanted(
-                    names.get(name.split(" ", 1)[0], "")):
-                total += inside
-                hits += 1
+    for name, inside in trace.operations(run.trace):
+        if wanted(names.get(name.split(" ", 1)[0], "")) and not (
+                unless and unless.search(name)):
+            total += inside
+            hits += 1
     if not steps or not hits:
         return None
     return total / len(run.trace["devices"]) / steps / 1e9

@@ -15,7 +15,11 @@ instruction to its `op_name`.  A fusion goes where its own
 instruction's metadata puts it.  What the compiler added with no
 op_name of its own (the async copies and slices of its memory-space
 assignment, layout copies) goes where the first instruction that
-consumes its result goes: it runs on that one's behalf.  Where less
+consumes its result goes: it runs on that one's behalf.  A loop's, a
+branch's or a call's own event is no operation and is neither placed
+nor counted among the unplaced: its body's events are
+(`harness.trace.operations`), so the phases add up to the device's busy
+time and not to more.  Where less
 than `PLACED_SHARE` of the device's busy time finds a phase (a stale
 executable loaded from a cache, scopes lost to a refactoring, a program
 that never had them) there is no number, and one `scope-note` line on
@@ -82,20 +86,15 @@ def split(record, phases, step_program):
     from a trace record and an {instruction: phase} map: summed device
     time of each phase's events inside the window, averaged over the
     devices, over the steps that start in it."""
-    start, end = trace.window(record)
     steps = len(trace.step_starts(record, step_program))
     total = dict.fromkeys(PHASES, 0)
     unplaced = {}
-    for dev in record["devices"].values():
-        for name, s, d in dev["ops"]:
-            inside = min(s + d, end) - max(s, start)
-            if inside <= 0:
-                continue
-            phase = phases.get(name.split(" ", 1)[0])
-            if phase:
-                total[phase] += inside
-            else:
-                unplaced[name] = unplaced.get(name, 0) + inside
+    for name, inside in trace.operations(record):
+        phase = phases.get(name.split(" ", 1)[0])
+        if phase:
+            total[phase] += inside
+        else:
+            unplaced[name] = unplaced.get(name, 0) + inside
     placed, lost = sum(total.values()), sum(unplaced.values())
     if not steps or not placed + lost:
         return None, 0.0, []

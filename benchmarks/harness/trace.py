@@ -25,6 +25,13 @@ HOST_SPANS = ("bench_window", "step_call", "loss_read", "host_batch")
 
 SHAPE = re.compile(r"[a-z]+[0-9]+\[[0-9,]*\]")
 CUSTOM_CALL = re.compile(r'custom_call_target="([^"]+)"')
+# A loop's, a branch's or a call's own event spans the events of its
+# body, which the trace holds too: whoever SUMS device time leaves it
+# out, or the body counts twice.  XLA names a container it clones as it
+# inlines a function the layers share `while.N.clone.M` / `cond.N.clone.M`.
+# This is the benchmark's one match: `operations` applies it for every
+# reader that sums (the phases, the scopes, the heaviest operations).
+CONTAINER = re.compile(r"^(while|conditional|cond|call)(\.[\w.-]*)?( |$)")
 
 
 def short_name(hlo):
@@ -118,6 +125,17 @@ def busy_seconds(record):
     return sum(busy) / len(busy) / 1e9, (end - start) / 1e9
 
 
+def operations(record):
+    """(name, nanoseconds inside the window) of every device operation,
+    one device after another, the containers' own events left out."""
+    start, end = window(record)
+    for dev in record["devices"].values():
+        for name, s, d in dev["ops"]:
+            inside = min(s + d, end) - max(s, start)
+            if inside > 0 and not CONTAINER.match(name):
+                yield name, inside
+
+
 def kernel_seconds(record, pattern):
     """Summed device durations, within the window and averaged over the
     devices, of the operations whose name matches `pattern`; and how
@@ -153,13 +171,11 @@ def mean_step_period_s(record, pattern):
 
 
 def top_device_ops(record, n=10):
-    start, end = window(record)
+    """The `n` operations that took most device seconds within the
+    window; a container is no operation, its body's events are."""
     total = {}
-    for dev in record["devices"].values():
-        for name, s, d in dev["ops"]:
-            inside = min(s + d, end) - max(s, start)
-            if inside > 0:
-                total[name] = total.get(name, 0) + inside
+    for name, inside in operations(record):
+        total[name] = total.get(name, 0) + inside
     k = len(record["devices"])
     heavy = sorted(total.items(), key=lambda kv: -kv[1])[:n]
     return [[name, d / k / 1e9] for name, d in heavy]

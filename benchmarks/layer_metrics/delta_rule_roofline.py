@@ -10,13 +10,13 @@ triangular inverse and its chunk scan, the recomputed forward
 included: the share says what the step pays, the work what it needs).
 A configuration without `delta_rule_work`, or a program without the
 scope, gives None."""
-from harness import scope_time
+from harness import op_names
 
 
 def read(run):
     if run.trace is None or not hasattr(run.config_mod, "delta_rule_work"):
         return None
-    seconds = scope_time.seconds_a_step(
+    seconds = op_names.seconds_a_step(
         run, lambda name: "/delta_rule/" in name)
     if seconds is None:
         return None

@@ -6,11 +6,11 @@ the output projection); forward, the recomputed forward and backward.
 `jax.named_scope("linear_attention")` in
 mxnet_tpu/models/decoder_lm.py.  The rule's chunk scan is a `while` on
 the device: containers are left out, their `.clone.N` copies too
-(harness/scope_time.py)."""
-from harness import scope_time
+(harness/op_names.py)."""
+from harness import op_names
 
 
 def read(run):
-    seconds = scope_time.seconds_a_step(
+    seconds = op_names.seconds_a_step(
         run, lambda name: "/linear_attention/" in name)
     return None if seconds is None else 1000.0 * seconds

@@ -8,7 +8,11 @@ else the configuration's `make_batch` reads, `pool`, `loss_every`,
 `check_steps` (how many of the first steps the reference follows),
 `rate_metric` (the name under which units per second are reported),
 `step_program` (what the step's device program is called in a trace),
-`traced_steps`, `limits`.
+`traced_steps`, `limits`; and, where the WEIGHTS decide how much work a
+step is (a router that sends its rows by them), `weights_seed`: the
+weights of program and reference are then made from it whatever
+`--seed` says, and `--seed` draws the batches alone, so every seed
+gives the same work on other inputs.
 """
 from __future__ import annotations
 
@@ -53,7 +57,7 @@ def make_pool(job):
 
 
 def seeded_weights(job):
-    return weights.make(_seed32(job.seed),
+    return weights.make(_seed32(job.traffic.get("weights_seed", job.seed)),
                         job.reference_mod.param_specs(job.config))
 
 

@@ -172,6 +172,48 @@ def test_broken_step_is_not_correct(bench, monkeypatch, fault):
     assert result["correct"] is False, result["compared"]
 
 
+def test_weights_seed_fixes_the_weights_and_leaves_the_batches_to_the_seed(
+        bench):
+    """The real cell states one: the routing, and with it the rows the
+    expert path works on, follows the weights."""
+    import numpy as np
+
+    run, manifest = bench
+    assert "weights_seed" not in manifest.cell_params(CELL)
+
+    def made(seed, **more):
+        job = run.make_job(manifest, CELL, seed, 0.0, 0, tiny.CPU_DEVICE)
+        job.traffic = dict(job.traffic, **more)
+        return (np.asarray(job.traffic_mod.seeded_weights(job)[1]),
+                job.traffic_mod.make_pool(job)[0][0][0])
+
+    (w1, x1), (w2, x2) = made(2 ** 31 + 1), made(2 ** 31 + 2)
+    assert not np.array_equal(w1, w2) and not np.array_equal(x1, x2)
+    (f1, y1), (f2, y2) = (made(2 ** 31 + 1, weights_seed=7),
+                          made(2 ** 31 + 2, weights_seed=7))
+    np.testing.assert_array_equal(f1, f2)
+    np.testing.assert_array_equal(x1, y1)      # the batches are the seed's
+    np.testing.assert_array_equal(x2, y2)
+    assert not np.array_equal(f1, w1)
+
+
+def test_the_real_cell_states_its_weights_seed_and_the_rule_that_picked_it(
+        bench):
+    """The draw is picked by a rule on the routing log that the cell's
+    file states, with the readings of the seeds it passed over."""
+    _, manifest = bench
+    cell = manifest.cell_params("laguna_xs2.seq8k")
+    assert cell["weights_seed"] == 3600000014
+    note = cell["weights_seed_note"]
+    assert "RULE ON THE ROUTING LOG" in note and "12,288" in note
+    for passed_over in ("20,527", "14,328", "14,371"):
+        assert passed_over in note
+    # the cells whose work does not follow their weights state none
+    for name in ("bert_base.seq128", "resnet50_v1.train224",
+                 "qwen3_next_80b.seq8k"):
+        assert "weights_seed" not in manifest.cell_params(name)
+
+
 def test_make_batch_is_seeded_zipf_with_shifted_labels(bench):
     import numpy as np
 
@@ -212,6 +254,35 @@ def test_model_flops_follow_the_published_sizes(bench):
     assert 0.25 < attention / flops < 0.6
 
 
+def test_projection_work_by_hand_at_the_published_sizes(bench):
+    _, manifest = bench
+    module = manifest.module("configs", "laguna_xs2")
+    config = manifest.config("laguna_xs2")
+    traffic = manifest.cell_params("laguna_xs2.seq8k")
+    tokens = 2 * 8192
+    # q, k, v, the per-head gate and the output projection of a layer of
+    # 48 query heads (the dense layer 0 and the full layer 4) and of 64
+    # (the three window layers), 8 K/V heads, head size 128
+    full = 2048 * 6144 + 2 * 2048 * 1024 + 2048 * 48 + 6144 * 2048
+    window = 2048 * 8192 + 2 * 2048 * 1024 + 2048 * 64 + 8192 * 2048
+    assert (full, window) == (29_458_432, 37_879_808)
+    weights = 2 * full + 3 * window
+    # every product's input and output row: (2048 + 6144) + ...
+    rows = 2 * (8192 + 2 * 3072 + 2096 + 8192) \
+        + 3 * (10240 + 2 * 3072 + 2112 + 10240)
+    flops, moved = module.projection_work(config, traffic)
+    assert flops == 6 * tokens * weights
+    assert moved == 3 * 2 * (weights + tokens * rows)
+    # 16.96 TFLOP, 86.1 ms at the chip's peak; the bytes need 17.5 ms
+    assert flops / 197e12 == pytest.approx(0.0861, rel=1e-3)
+    assert moved / 819e9 == pytest.approx(0.0175, rel=1e-2)
+    # the arithmetic itself, on one 8 x 4 weight
+    from harness import work
+
+    assert work.dense_work(tokens, [(8, 4)]) == (
+        6 * tokens * 32, 6 * (32 + tokens * 12))
+
+
 # -- the three readers on a synthetic trace ---------------------------------
 
 STEP_TEXT = """
@@ -223,6 +294,9 @@ STEP_TEXT = """
 %attn.5 = bf16[16,8,8192,128] custom-call(%q, %k), custom_call_target="tpu_custom_call", metadata={op_name="jit(step_phases)/jvp(forward)/attention_window/pallas_call"}
 %fusion.6 = f32[8] fusion(%p), kind=kLoop, metadata={op_name="jit(step_phases)/optimizer/mul"}
 %while.8 = s32[] while(%p), metadata={op_name="jit(step_phases)/jvp(forward)/checkpoint/jit(f)/moe/dispatch/while"}
+%fusion.10 = bf16[8,8] fusion(%p), kind=kLoop, metadata={op_name="jit(step_phases)/jvp(forward)/checkpoint/attention_window/dot_general"}
+%fusion.11 = bf16[8,8] fusion(%p), kind=kLoop, metadata={op_name="jit(step_phases)/transpose(jvp(forward))/checkpoint/attention_full/jit(sigmoid)/mul"}
+%cond.12.clone.1 = bf16[8,8] conditional(%i, %p), metadata={op_name="jit(step_phases)/transpose(jvp(forward))/checkpoint/attention_full/cond"}
 """
 
 
@@ -240,7 +314,12 @@ def _synthetic_run(manifest, text=STEP_TEXT):
            ["attn.7 tpu_custom_call(bf16[16,6,8192,128])", 45 * ms, 30 * ms],
            ["fusion.6 f32[8]", 75 * ms, 5 * ms],
            # a loop's own event, around events counted already
-           ["while.8 s32[]", 10 * ms, 15 * ms]]
+           ["while.8 s32[]", 10 * ms, 15 * ms],
+           # around the attention kernels: a projection, the gate, and a
+           # cloned switch's own event that spans the gate's
+           ["fusion.10 bf16[8,8]", 81 * ms, 3 * ms],
+           ["fusion.11 bf16[8,8]", 85 * ms, 5 * ms],
+           ["cond.12.clone.1 bf16[8,8]", 84 * ms, 9 * ms]]
     # two steps in the window: the same events again 100 ms later
     ops += [[n, s + 100 * ms, d] for n, s, d in ops]
     record = {"devices": {"/device:TPU:0": {
@@ -283,6 +362,26 @@ def test_attn_mixed_roofline_reads_both_kinds_of_kernel(bench):
         e for e in run.trace["devices"]["/device:TPU:0"]["ops"]
         if "8192" not in e[0]]
     assert reader.read(run) is None
+
+
+def test_mixer_proj_roofline_reads_what_runs_around_the_kernels(bench):
+    _, manifest = bench
+    reader = manifest.module("layer_metrics", "mixer_proj_roofline")
+    run = _synthetic_run(manifest)
+    flops, moved = run.config_mod.projection_work(run.config, run.traffic)
+    least = max(flops / 197e12, moved / 819e9)
+    # the projection's 3 ms and the gate's 5: not the window kernel's 20
+    # under the same scope, nor the switch's own 9
+    assert reader.read(run) == pytest.approx(100 * least / 0.008)
+    untraced = _synthetic_run(manifest)
+    untraced.trace = None
+    assert reader.read(untraced) is None
+    no_scopes = _synthetic_run(manifest, STEP_TEXT.replace(
+        "/attention_", "/mixer_"))
+    assert reader.read(no_scopes) is None
+    for other in ("bert_base", "resnet50_v1"):   # no `projection_work`
+        run.config_mod = manifest.module("configs", other)
+        assert reader.read(run) is None
 
 
 def test_expert_matmul_roofline_uses_the_routing_logs_rows(bench,

@@ -213,6 +213,18 @@ def test_the_cut_follows_the_issues_arithmetic(bench):
     assert module.attention_kernel_events(config, traffic) == (
         r"tpu_custom_call\(bf16\[4,8,8192,256\]\)")
     assert module.expert_work(config, [5120])[0] == 18 * 5120 * 2048 * 512
+    # the mixers' dense products: [q | gate], k, v, out of the attention
+    # layer; [q | k | v | z], [b | a], out of each Gated DeltaNet layer
+    full = 2048 * 8192 + 2 * 2048 * 512 + 4096 * 2048
+    linear = 2048 * 12288 + 2048 * 64 + 4096 * 2048
+    assert (full, linear) == (27_262_976, 33_685_504)
+    rows = (10240 + 2 * 2560 + 6144) + 3 * (14336 + 2112 + 6144)
+    proj_flops, proj_bytes = module.projection_work(config, traffic)
+    assert proj_flops == 6 * tokens * (full + 3 * linear)
+    assert proj_bytes == 3 * 2 * (full + 3 * linear + tokens * rows)
+    # 12.61 TFLOP, 64.0 ms at the chip's peak; the bytes need 11.7 ms
+    assert proj_flops / 197e12 == pytest.approx(0.0640, rel=1e-3)
+    assert proj_bytes / 819e9 == pytest.approx(0.01166, rel=1e-2)
 
 
 def test_make_batch_is_seeded_zipf_with_shifted_labels(bench):
@@ -246,6 +258,7 @@ STEP_TEXT = f"""
 %fusion.7 = f32[8,8] fusion(%p), kind=kLoop, metadata={{op_name="jit(step_phases)/transpose(jvp(forward))/jvp(forward)/checkpoint/linear_attention/jit(_k_gated_delta_rule)/delta_rule/while/body/closed_call/checkpoint/dot_general"}}
 %fusion.8 = bf16[8,8] fusion(%p), kind=kLoop, metadata={{op_name="jit(step_phases)/jvp(forward)/attention_full/dot_general"}}
 %call.9 = f32[8] call(%p), metadata={{op_name="{RULE}/closed_call"}}
+%attn.10 = bf16[4,8,8192,256] custom-call(%q, %k), custom_call_target="tpu_custom_call", metadata={{op_name="jit(step_phases)/jvp(forward)/attention_full/pallas_call"}}
 """
 
 
@@ -262,7 +275,8 @@ def _synthetic_run(manifest, text=STEP_TEXT):
            ["while.6.clone.1 s32[]", 40 * ms, 10 * ms],
            ["fusion.7 f32[8,8]", 41 * ms, 8 * ms],
            ["fusion.8 bf16[8,8]", 60 * ms, 7 * ms],
-           ["call.9 f32[8]", 15 * ms, 20 * ms]]
+           ["call.9 f32[8]", 15 * ms, 20 * ms],
+           ["attn.10 tpu_custom_call(bf16[4,8,8192,256])", 70 * ms, 11 * ms]]
     # two steps in the window: the same events again 100 ms later
     ops += [[n, s + 100 * ms, d] for n, s, d in ops]
     record = {"devices": {"/device:TPU:0": {
@@ -312,4 +326,30 @@ def test_delta_rule_roofline_reads_the_rule_alone(bench):
     # a configuration without the work function (the accepted ones)
     other = _synthetic_run(manifest)
     other.config_mod = manifest.module("configs", "laguna_xs2")
+    assert reader.read(other) is None
+
+
+def test_mixer_proj_roofline_leaves_rule_conv_kernels_and_containers_out(
+        bench):
+    _, manifest = bench
+    reader = manifest.module("layer_metrics", "mixer_proj_roofline")
+    run = _synthetic_run(manifest)
+    flops, moved = run.config_mod.projection_work(run.config, run.traffic)
+    least = max(flops / 197e12, moved / 819e9)
+    # the linear mixer's projection 2 ms and the attention layer's 7: not
+    # the convolution's 3, the rule's 4 + 5 + 5 + 8, the attention
+    # kernel's 11, nor any loop's or call's own event
+    assert reader.read(run) == pytest.approx(100 * least / 0.009)
+    # with the scope's other readers the mixers' time splits into parts
+    # that add up: 27 under `linear_attention` = 2 + conv 3 + rule 22
+    linear = manifest.module("layer_metrics", "linear_attn_ms").read(run)
+    assert linear == pytest.approx(2.0 + 3.0 + 22.0)
+    untraced = _synthetic_run(manifest)
+    untraced.trace = None
+    assert reader.read(untraced) is None
+    no_scopes = _synthetic_run(manifest, STEP_TEXT.replace(
+        "linear_attention", "mixer").replace("attention_full", "mixer"))
+    assert reader.read(no_scopes) is None
+    other = _synthetic_run(manifest)
+    other.config_mod = manifest.module("configs", "bert_base")
     assert reader.read(other) is None

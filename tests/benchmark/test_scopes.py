@@ -15,7 +15,7 @@ import tiny
 
 tiny.on_path()
 
-from harness import scopes  # noqa: E402
+from harness import scopes, trace  # noqa: E402
 from harness.manifest import Manifest  # noqa: E402
 
 MANIFEST = Manifest(tiny.REPO)
@@ -124,6 +124,77 @@ def test_two_devices_are_averaged():
         record, scopes.instruction_phases(PROGRAM_TEXT), "jit_step")
     assert values["forward"] == pytest.approx((60 + 150) / 2 / 2 / 1e6)
     assert values["backward"] == pytest.approx(104 / 2 / 2 / 1e6)
+
+
+# a step whose layers share a lowered function: XLA clones the loop and
+# the switch as it inlines it and names them `.clone.M`
+FWD, BWD = "jit(step)/jvp(forward)", "jit(step)/transpose(jvp(forward))"
+LOOPS_TEXT = f"""\
+%fusion.1 = f32[8]{{0}} fusion(%p), kind=kLoop, metadata={{op_name="{FWD}/moe/experts/branch_1_fun/mul"}}
+%cond.2.clone.1 = f32[8]{{0}} conditional(%i, %p, %p), metadata={{op_name="{FWD}/moe/experts/cond"}}
+%fusion.3 = f32[8]{{0}} fusion(%p), kind=kLoop, metadata={{op_name="{FWD}/delta_rule/while/body/dot_general"}}
+%while.4 = (s32[], f32[8]{{0}}) while(%t), metadata={{op_name="{FWD}/delta_rule/while"}}
+%fusion.5 = f32[8]{{0}} fusion(%p), kind=kLoop, metadata={{op_name="{BWD}/delta_rule/while/body/while/body/dot_general"}}
+%while.6.clone.2 = (s32[], f32[8]{{0}}) while(%t), metadata={{op_name="{BWD}/delta_rule/while/body/while"}}
+%while.7.clone.1.clone.3 = (s32[], f32[8]{{0}}) while(%t), metadata={{op_name="{BWD}/delta_rule/while"}}
+%conditional.8 = f32[8]{{0}} conditional(%i, %p, %p), metadata={{op_name="{BWD}/moe/experts/cond"}}
+%fusion.9 = f32[8]{{0}} fusion(%p), kind=kLoop, metadata={{op_name="{BWD}/moe/experts/branch_0_fun/mul"}}
+%call.10 = f32[8]{{0}} call(%p), to_apply=%f
+%while_fusion.11 = f32[8]{{0}} fusion(%p), kind=kLoop, metadata={{op_name="jit(step)/optimizer/add"}}
+"""
+
+
+def _loops_record():
+    ops = [["cond.2.clone.1 f32[8]", 100, 40],   # spans its branch
+           ["fusion.1 f32[8]", 105, 30],
+           ["while.4 s32[]", 150, 50],           # two turns of its body
+           ["fusion.3 f32[8]", 155, 20],
+           ["fusion.3 f32[8]", 178, 20],
+           # a loop in a loop, both cloned, around three turns
+           ["while.7.clone.1.clone.3 s32[]", 300, 200],
+           ["while.6.clone.2 s32[]", 310, 90],
+           ["fusion.5 f32[8]", 315, 25],
+           ["fusion.5 f32[8]", 345, 25],
+           ["while.6.clone.2 s32[]", 405, 90],
+           ["fusion.5 f32[8]", 410, 25],
+           ["conditional.8 f32[8]", 520, 60],
+           ["fusion.9 f32[8]", 525, 50],
+           ["call.10 f32[8]", 600, 100],         # no op_name at all
+           ["while_fusion.11 f32[8]", 700, 10]]  # a fusion, no container
+    return {"devices": {"/device:TPU:0": {
+        "ops": ops, "modules": [["jit_step(1)", 100, 700]]}},
+        "host": [["bench_window", 0, 1000]]}
+
+
+def test_a_containers_own_event_is_left_out_and_its_body_placed_once():
+    values, share, heaviest = scopes.split(
+        _loops_record(), scopes.instruction_phases(LOOPS_TEXT), "jit_step")
+    # one step; forward 30 + 20 + 20, backward 3 x 25 + 50, optimizer 10:
+    # not the switches' 40 and 60, the loops' 50, 200 and 2 x 90
+    assert values == {"forward": pytest.approx(70 / 1e6),
+                      "backward": pytest.approx(125 / 1e6),
+                      "optimizer": pytest.approx(10 / 1e6)}
+    # the call's event, which no phase would claim, is no operation
+    # either: it does not lower the placed share
+    assert share == 1.0 and heaviest == []
+    busy, _ = trace.busy_seconds(_loops_record())
+    assert sum(values.values()) * 1e6 <= busy * 1e9
+
+
+def test_top_device_ops_lists_operations_and_no_container():
+    top = trace.top_device_ops(_loops_record())
+    assert [name for name, _ in top] == [
+        "fusion.5 f32[8]", "fusion.9 f32[8]", "fusion.3 f32[8]",
+        "fusion.1 f32[8]", "while_fusion.11 f32[8]"]
+    assert top[0][1] == pytest.approx(75e-9)
+    for name in ("while.245 s32[]", "while.6.clone.1", "cond.68.clone.2 "
+                 "bf16[16384,2048]", "conditional.3", "call.9 f32[8]", "while"):
+        assert trace.CONTAINER.match(name), name
+    # the match is the instruction's kind and its numbering, no prefix
+    for name in ("while_fusion.11 f32[8]", "condition_fusion f32[8]",
+                 "fusion.2 f32[8]", "call-start.3", "branch_0_fun.43 "
+                 "tpu_custom_call(bf16[16,6,8192,128])"):
+        assert not trace.CONTAINER.match(name), name
 
 
 @pytest.mark.parametrize("record, text, share", [
