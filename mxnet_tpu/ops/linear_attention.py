@@ -27,7 +27,7 @@ would be as exact on paper and loses everything to cancellation where
 keys repeat (its powers grow like binomial coefficients).
 
 The intra-chunk part is ONE function with its own derivative
-(`_wy`).  For one (sequence, value head) and one chunk, from q, k (the
+(`_wy_xla`).  For one (sequence, value head) and one chunk, from q, k (the
 key head's: value head h reads key head h // r), v, g, beta it returns
 what the chunk scan consumes, the chunk axis first:
 
@@ -60,15 +60,40 @@ for the TPU (`lax.platform_dependent`) and the shapes allow it: an even
 number of chunks, key and value sizes multiples of 128, bf16 or
 float32.  tests/test_linear_attention.py holds `_wy_xla` to
 `jax.grad` of the plainly differentiated rule and the kernels to
-`_wy_xla`.  The chunk scan stays a `lax.scan` differentiated by JAX.
+`_wy_xla`.
+
+The chunk scan, for one pair and chunk in order, from S = 0:
+
+    d = u - w S      o = q_in S + within d      S <- last S + k_out^T d
+
+and, in reverse, from the state's cotangent dS = 0 and the kept S:
+
+    dd = k_out dS + within^T do           (du = dd)
+    dwithin = do d^T   dk_out = d dS^T   dq_in = do S^T   dw = -dd S^T
+    dlast = sum(S * dS)    dS <- last dS + q_in^T do - w^T dd
+
+Two forms of it: `_scan_xla`, a `lax.scan` that JAX differentiates, and
+the kernel pair of ops/pallas/delta_rule.py (`delta_rule.scan`, with
+its own `jax.custom_vjp`), taken under the same `lax.platform_dependent`
+and the same shapes as the intra-chunk kernels.  The kernels keep a
+block of pairs' float32 states (and in the backward their cotangents)
+in VMEM across the chunks and round exactly where `_scan_xla` and its
+derivative do: a product's operands to the inputs' dtype (the state
+too, as an operand only); a float32 cotangent against a bf16 operand
+goes in whole (three bf16 parts, the float32 product of
+`precision="highest"`), and a cotangent of a bf16 value is rounded to
+bf16 as JAX rounds it.  tests/test_linear_attention.py holds the
+kernels to `_scan_xla`, values and the six gradients.
 
 Memory.  No pass holds a state a TOKEN.  The heads are worked on in
 groups, one after another (`head_groups`), each group recomputed inside
-its own backward pass, which is the chunk scan's own derivative: it
-keeps the float32 state at every chunk's start (`state_bytes_kept`:
-sequence / chunk x batch x heads x key x value x 4 bytes, 537 MB for 2
-x 8,192 tokens and 32 heads of 128 x 128, a quarter of it alive at a
-time there).  The output is NAMED (`RESIDUAL_NAMES`): a
+its own backward pass, which keeps the float32 state at every chunk's
+start (`state_bytes_kept`: sequence / chunk x batch x heads x key x
+value x 4 bytes, 537 MB for 2 x 8,192 tokens and 32 heads of 128 x
+128, a quarter of it alive at a time there): `_scan_xla`'s derivative
+stacks them as its residuals, the kernels' backward writes them with a
+forward of its own (the pass's forward writes none) and reads them in
+reverse.  The output is NAMED (`RESIDUAL_NAMES`): a
 `jax.checkpoint` whose policy saves it (`DataParallelTrainer(remat=
 True)`) recomputes what follows the rule without running the rule
 again, so a step runs the rule's forward twice (the pass itself, and a
@@ -104,9 +129,11 @@ _PAIRS = 16     # (sequence, value head) pairs a pass of the rule holds
 RESIDUAL_NAMES = registry.RESIDUAL_NAMES["gated_delta_rule"]
 
 # every rule traced: (batch, value heads, seq, key size, value size,
-# dtype) -> traces, and those of them whose shapes the kernels take
+# dtype) -> traces, those of them whose shapes the intra-chunk kernels
+# take, and those whose chunk scan takes the scan's kernel pair
 _traced = collections.Counter()
 _kernel_traced = collections.Counter()
+_scan_kernel_traced = collections.Counter()
 
 
 def _k_l2_norm(data, *, eps=1e-6):
@@ -279,34 +306,12 @@ _wy_xla.defvjp(_wy_xla_fwd,
                lambda kept, cotangents: _wy_backward(*kept, cotangents))
 
 
-def _wy(q, k, v, g, beta):
-    """The intra-chunk part: the kernels of ops/pallas/delta_rule.py
-    where the computation is lowered for the TPU and they take the
-    shapes, `_wy_xla` everywhere else."""
-    from .pallas import delta_rule
-
-    if not delta_rule.admits(q, k, v):
-        return _wy_xla(q, k, v, g, beta)
-
-    def kernels(*xs):
-        from ..parallel.mesh import per_batch_shard
-
-        # the chunk axis leads what the scan consumes; a step that is
-        # partitioned over the batch wants the batch there
-        outs = per_batch_shard(lambda *xs: tuple(
-            jnp.moveaxis(x, 1, 0) for x in delta_rule.wy(*xs)), *xs)
-        return tuple(jnp.moveaxis(x, 0, 1) for x in outs)
-
-    return jax.lax.platform_dependent(q, k, v, g, beta, tpu=kernels,
-                                      default=_wy_xla)
-
-
-def _rule(q, k, v, g, beta):
-    """The chunked rule for heads that are worked on together: q, k
-    (b, key heads, seq, size), v (b, h, seq, size), g and beta (b, h,
-    seq), seq a multiple of `CHUNK`."""
-    b, h, seq, dv = v.shape
-    dk, dtype, dot = k.shape[-1], v.dtype, _dot(v.dtype)
+def _scan_xla(u, w, within, q_in, k_out, last):
+    """The chunk scan as a `lax.scan` that carries the float32 state
+    and is differentiated by JAX: over what `_wy_xla` hands it, the
+    chunk axis first; o (b, h, seq, dv) in w's dtype."""
+    n, b, h, _, dv = u.shape
+    dk, dtype, dot = w.shape[-1], w.dtype, _dot(w.dtype)
 
     def step(state, xs):
         u, w, within, q_in, k_out, last = xs
@@ -318,8 +323,36 @@ def _rule(q, k, v, g, beta):
         return state, o.astype(dtype)
 
     _, o = jax.lax.scan(step, jnp.zeros((b, h, dk, dv), jnp.float32),
-                        _wy(q, k, v, g, beta))
-    return jnp.moveaxis(o, 0, 2).reshape(b, h, seq, dv)
+                        (u, w, within, q_in, k_out, last))
+    return jnp.moveaxis(o, 0, 2).reshape(b, h, n * CHUNK, dv)
+
+
+def _rule_xla(q, k, v, g, beta):
+    return _scan_xla(*_wy_xla(q, k, v, g, beta))
+
+
+def _rule(q, k, v, g, beta):
+    """The chunked rule for heads that are worked on together: q, k
+    (b, key heads, seq, size), v (b, h, seq, size), g and beta (b, h,
+    seq), seq a multiple of `CHUNK`.  The intra-chunk part and the
+    chunk scan are the kernel pairs of ops/pallas/delta_rule.py where
+    the computation is lowered for the TPU and they take the shapes,
+    `_wy_xla` and `_scan_xla` everywhere else."""
+    from .pallas import delta_rule
+
+    if not delta_rule.admits(q, k, v):
+        return _rule_xla(q, k, v, g, beta)
+
+    def kernels(*xs):
+        from ..parallel.mesh import per_batch_shard
+
+        # a step that is partitioned over the batch runs them a shard
+        # of it each
+        return per_batch_shard(
+            lambda *xs: delta_rule.scan(*delta_rule.wy(*xs)), *xs)
+
+    return jax.lax.platform_dependent(q, k, v, g, beta, tpu=kernels,
+                                      default=_rule_xla)
 
 
 def head_groups(batch, key_heads, value_heads):
@@ -363,6 +396,7 @@ def _k_gated_delta_rule(q, k, v, g, beta):
             g, beta = (jnp.pad(x, ((0, 0), (0, 0), (0, pad)))
                        for x in (g, beta))
         _kernel_traced[key] += delta_rule.admits(q, k, v)
+        _scan_kernel_traced[key] += delta_rule.admits(q, k, v)
         if groups == 1:
             o = one_group((q, k, v, g, beta))
         else:
@@ -382,19 +416,22 @@ def linear_attention_stats():
     """The `linearAttention` profiler section: the delta rules traced
     since the last reset (a trace a layer's pass, as `flashAttention`
     counts: a layer whose jaxpr JAX reuses does not count again).
-    `layers`: the distinct shapes; `traces`, `chunk`,
-    `chunks_per_sequence` and `state_bytes_kept` (the float32 states at
-    the chunks' starts that the scan's derivative keeps for the layer
-    being differentiated) by shape."""
+    `layers`: the distinct shapes; `traces`, `kernel_traces` (of shapes
+    the intra-chunk kernels take), `scan_kernel_traces` (whose chunk
+    scan takes the scan's kernel pair), `xla_traces` (of neither),
+    `chunk`, `chunks_per_sequence` and `state_bytes_kept` (the float32
+    states at the chunks' starts that the scan's derivative keeps for
+    the layer being differentiated) by shape."""
     out = {"layers": len(_traced), "traces": {}, "kernel_traces": {},
-           "xla_traces": {}, "chunk": {}, "chunks_per_sequence": {},
-           "state_bytes_kept": {}}
+           "scan_kernel_traces": {}, "xla_traces": {}, "chunk": {},
+           "chunks_per_sequence": {}, "state_bytes_kept": {}}
     for shape, n in _traced.items():
         b, h, seq, dk, dv, dtype = shape
         key = f"b{b} h{h} s{seq} k{dk} v{dv} {dtype}"
         chunks = -(-seq // CHUNK)
         out["traces"][key] = n
         out["kernel_traces"][key] = _kernel_traced[shape]
+        out["scan_kernel_traces"][key] = _scan_kernel_traced[shape]
         out["xla_traces"][key] = n - _kernel_traced[shape]
         out["chunk"][key] = CHUNK
         out["chunks_per_sequence"][key] = chunks
@@ -405,6 +442,7 @@ def linear_attention_stats():
 def reset_linear_attention_stats():
     _traced.clear()
     _kernel_traced.clear()
+    _scan_kernel_traced.clear()
 
 
 def _stats_table(stats):
@@ -412,7 +450,8 @@ def _stats_table(stats):
     for key in sorted(stats["traces"]):
         out.append(f"  {key}: x{stats['traces'][key]} "
                    f"({stats['kernel_traces'][key]} of shapes the kernels "
-                   f"take), "
+                   f"take, {stats['scan_kernel_traces'][key]} with the "
+                   f"scan's), "
                    f"{stats['chunks_per_sequence'][key]} chunks of "
                    f"{stats['chunk'][key]}, keeps "
                    f"{stats['state_bytes_kept'][key]} bytes of states")

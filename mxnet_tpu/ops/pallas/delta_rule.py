@@ -1,8 +1,9 @@
 """The gated delta rule's intra-chunk ("WY") part as two Pallas TPU
-kernels: `wy(q, k, v, g, beta)` is ops/linear_attention.py's
-`_wy_xla` (the mathematics, the roundings and the closed-form
-derivative are written down there), with a `jax.custom_vjp` whose
-forward and backward are one kernel each.
+kernels, and its chunk scan as two more (the last paragraph).
+`wy(q, k, v, g, beta)` is ops/linear_attention.py's `_wy_xla` (the
+mathematics, the roundings and the closed-form derivative are written
+down there), with a `jax.custom_vjp` whose forward and backward are
+one kernel each.
 
 Chunks are independent here, so every grid axis is parallel: a step
 takes one (sequence, value head) and a block of PAIRS of chunks (up to
@@ -35,6 +36,35 @@ is kept for the backward lane-dense, a pair's two blocks side by side
 (b, h, n / 2, 64, 128) float32; exp(G_C), a scalar a chunk, is left to
 XLA.  dq and dk leave the backward kernel a VALUE head, float32, and
 are summed over the r heads of a key head outside.
+
+The chunk scan is a second kernel pair, `scan(u, w, within, q_in,
+k_out, last)`: ops/linear_attention.py's `_scan_xla` (the recurrence
+and its derivative are written down there), with a `jax.custom_vjp`.
+The grid is (block of pairs, block of chunks), the chunk axis
+"arbitrary": a block of up to `_SCAN_PAIRS` (sequence, value head)
+pairs keeps its float32 states, dk x dv each, in a VMEM scratch buffer
+from one grid step to the next (zeroed at the first), and a grid step
+works through its chunks (up to `_SCAN_CHUNKS`) in a `lax.fori_loop`,
+the pairs side by side as batched products, so that the chain of
+dependent 64-row products of one pair does not wait alone on the MXU.
+The forward writes o straight into the rule's (b, h, seq, dv) layout.
+Its residuals are its inputs: the backward runs the forward again in a
+mode that writes the float32 state at every chunk's start and no o
+(under the rule's `jax.checkpoint` the pass's forward would write them
+for nothing), then a kernel over the chunks in reverse that carries the
+state's cotangent in VMEM, reads the kept states and writes the six
+cotangents.  Roundings are `_scan_xla`'s and those of JAX's derivative
+of it: a product's operands in the rule's dtype (the state and d
+rounded to it as operands only), every sum, the state, the decay and
+its cotangent float32; a float32 cotangent (the state's, and d's)
+against bf16 operands goes into the product as three bf16 parts whose
+sum it is exactly (`_product`: the float32 product that
+`precision="highest"` gives, in three MXU passes, not six and not one),
+and the cotangent of a bf16 value is rounded to bf16 where JAX rounds
+it.  VMEM: at most `_SCAN_VMEM_LIMIT`; a grid step's blocks, twice for
+the pipeline, at most half of it, the rest for the states, their
+cotangents and a chunk's float32 temporaries (`_Scan` sizes the
+blocks from the bytes).
 """
 from __future__ import annotations
 
@@ -385,3 +415,279 @@ def _wy_fwd(q, k, v, g, beta):
 
 
 wy.defvjp(_wy_fwd, lambda kept, cotangents: _backward(*kept, cotangents))
+
+
+# ---------------------------------------------------------------------------
+# The chunk scan
+
+_SCAN_PAIRS = 16        # (sequence, value head) pairs a grid step, side by side
+_SCAN_CHUNKS = 8        # chunks a grid step at most
+# what a scan kernel may use of VMEM; a grid step's blocks, twice, take
+# at most half of it, and the state, its cotangent and the float32
+# temporaries of a chunk's products the rest
+_SCAN_VMEM_LIMIT = 64 * 2 ** 20
+_SCAN_BLOCK_BYTES = _SCAN_VMEM_LIMIT // 2
+
+
+def _parts(x, dtype):
+    """`x` as a sum of values of `dtype`: itself where it is of that
+    dtype or the dtype is float32, else (a float32 cotangent against
+    bf16) three bf16 values whose sum is x exactly."""
+    if x.dtype == dtype or dtype == jnp.float32:
+        return [x.astype(dtype)]
+    parts = []
+    for _ in range(3):
+        part = x.astype(dtype)
+        parts.append(part)
+        x = x - part.astype(jnp.float32)
+    return parts
+
+
+def _product(dtype, x, y, lhs=2, rhs=1):
+    """sum over x's axis `lhs` and y's axis `rhs` of (pairs, ., .)
+    operands, a pair at a time, float32 out.  Operands of `dtype` go in
+    as they are; a float32 operand against bf16 ones goes in as three
+    bf16 parts, so that the product is the float32 product of JAX's
+    derivative (`precision="highest"`), not one bf16 pass."""
+    precision = jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+    out = None
+    for a in _parts(x, dtype):
+        for b in _parts(y, dtype):
+            p = jax.lax.dot_general(
+                a, b, (((lhs,), (rhs,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32, precision=precision)
+            out = p if out is None else out + p
+    return out
+
+
+def _rounded(x, dtype):
+    """x rounded to `dtype` and back to float32: a cotangent of a value
+    of `dtype`, as JAX's derivative rounds it."""
+    return x.astype(dtype).astype(jnp.float32)
+
+
+def _scan_forward_kernel(*refs, chunks, keep):
+    """The chunks of a grid step in order, a block of pairs side by
+    side.  `keep`: write the state at every chunk's start and no o (the
+    backward's own forward); else write o."""
+    if keep:
+        u_ref, w_ref, k_out_ref, last_ref, states_ref, state_ref = refs
+    else:
+        (u_ref, w_ref, within_ref, q_in_ref, k_out_ref, last_ref, o_ref,
+         state_ref) = refs
+    dtype = w_ref.dtype
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        state_ref[...] = jnp.zeros(state_ref.shape, jnp.float32)
+
+    def turn(t, carry):
+        state = state_ref[...]
+        if keep:
+            states_ref[t] = state
+        # the state goes into a product rounded to the operands' dtype
+        rounded = state.astype(dtype)
+        d = u_ref[t] - _product(dtype, w_ref[t], rounded)
+        d = d.astype(dtype)
+        if not keep:
+            o = _product(dtype, q_in_ref[t], rounded) \
+                + _product(dtype, within_ref[t], d)
+            rows = pl.ds(pl.multiple_of(t * CHUNK, CHUNK), CHUNK)
+            o_ref[:, rows, :] = o.astype(o_ref.dtype)
+        state_ref[...] = state * last_ref[t] \
+            + _product(dtype, k_out_ref[t], d, 1, 1)
+        return carry
+
+    jax.lax.fori_loop(0, chunks, turn, None)
+
+
+def _scan_backward_kernel(states_ref, u_ref, w_ref, within_ref, q_in_ref,
+                          k_out_ref, last_ref, do_ref, du_ref, dw_ref,
+                          dwithin_ref, dq_in_ref, dk_out_ref, dlast_ref,
+                          d_state_ref, *, chunks):
+    """The chunks of a grid step in reverse, the state's cotangent
+    carried in VMEM (module docstring)."""
+    dtype = w_ref.dtype
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        d_state_ref[...] = jnp.zeros(d_state_ref.shape, jnp.float32)
+
+    def turn(i, carry):
+        t = chunks - 1 - i
+        state, d_next = states_ref[t], d_state_ref[...]
+        w, within, q_in, k_out = (ref[t] for ref in (w_ref, within_ref,
+                                                     q_in_ref, k_out_ref))
+        rows = pl.ds(pl.multiple_of(t * CHUNK, CHUNK), CHUNK)
+        do = do_ref[:, rows, :]
+        rounded = state.astype(dtype)
+        d = (u_ref[t] - _product(dtype, w, rounded)).astype(dtype)
+        # d reaches o through `within` and the next state through k_out
+        dd = _rounded(_product(dtype, k_out, d_next, 2, 1), dtype) \
+            + _rounded(_product(dtype, within, do, 1, 1), dtype)
+        du_ref[t] = dd
+        for ref, x in ((dwithin_ref, _product(dtype, do, d, 2, 2)),
+                       (dk_out_ref, _product(dtype, d, d_next, 2, 2)),
+                       (dq_in_ref, _product(dtype, do, rounded, 2, 2)),
+                       (dw_ref, _product(dtype, -dd, rounded, 2, 2))):
+            ref[t] = x.astype(ref.dtype)
+        dlast = jnp.sum(jnp.sum(state * d_next, axis=2, keepdims=True),
+                        axis=1, keepdims=True)
+        dlast_ref[t] = jnp.broadcast_to(dlast, dlast_ref.shape[1:])
+        d_state_ref[...] = d_next * last_ref[t] \
+            + _rounded(_product(dtype, q_in, do, 1, 1), dtype) \
+            + _rounded(_product(dtype, w, -dd, 1, 1), dtype)
+        return carry
+
+    jax.lax.fori_loop(0, chunks, turn, None)
+
+
+class _Scan:
+    """The grid of the scan kernels, (block of pairs, block of chunks),
+    the chunk axis "arbitrary": a pair's state stays in VMEM from one
+    grid step to the next.  Arrays come in the scan's layout flattened
+    to pairs, (n, pairs, 64, size); o and its cotangent (pairs, seq,
+    dv), the layout of the rule's output."""
+
+    def __init__(self, u, w, kind):
+        self.n, self.pairs, _, self.dv = u.shape
+        self.dk, dk, dv, item = w.shape[-1], w.shape[-1], self.dv, \
+            w.dtype.itemsize
+        # VMEM bytes of a pair's blocks a chunk (a lane row takes 8
+        # sublanes, `within` 128 lanes): u, w, k_out and the decay ...
+        block = CHUNK * (dv * 4 + 2 * dk * item) + 8 * dv * 4
+        if kind == "states":        # ... and the state written
+            block += dk * dv * 4
+        else:                       # ... q_in, within, o (do)
+            block += CHUNK * (dk + 128 + dv) * item
+        if kind == "backward":      # ... the state read, du, dw, dq_in,
+            block += dk * dv * 4 + CHUNK * (dv * 4 + (3 * dk + 128) * item) \
+                + 8 * 128 * 4       # dk_out, dwithin, dlast
+        # a pair's state, its cotangent and a chunk's float32 temporaries
+        held = 8 * dk * dv * 4
+        self.side = max(c for c in range(1, _SCAN_PAIRS + 1)
+                        if self.pairs % c == 0 and (c == 1 or (
+                            2 * c * block <= _SCAN_BLOCK_BYTES and c * held
+                            <= _SCAN_VMEM_LIMIT - _SCAN_BLOCK_BYTES)))
+        self.chunks = max(c for c in range(1, _SCAN_CHUNKS + 1)
+                          if self.n % c == 0 and (
+                              c == 1 or 2 * c * self.side * block
+                              <= _SCAN_BLOCK_BYTES))
+        self.blocks = self.n // self.chunks
+        self.reverse = kind == "backward"
+
+    def _chunk(self, c):
+        return self.blocks - 1 - c if self.reverse else c
+
+    def by_chunk(self, rows, size):
+        """(n, pairs, rows, size): a chunk's rows of a pair."""
+        return pl.BlockSpec((self.chunks, self.side, rows, size),
+                            lambda p, c: (self._chunk(c), p, 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    def by_token(self):
+        """(pairs, seq, dv): o and its cotangent."""
+        return pl.BlockSpec((self.side, self.chunks * CHUNK, self.dv),
+                            lambda p, c: (p, self._chunk(c), 0),
+                            memory_space=pltpu.VMEM)
+
+    def call(self, kernel, **specs):
+        return pallas_call(
+            functools.partial(kernel, chunks=self.chunks),
+            grid=(self.pairs // self.side, self.blocks),
+            scratch_shapes=[pltpu.VMEM((self.side, self.dk, self.dv),
+                                       jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=_SCAN_VMEM_LIMIT), **specs)
+
+
+def _by_pair(x):
+    """(n, b, h, ...) -> (n, b * h, ...): free, the pairs are major."""
+    return x.reshape((x.shape[0], -1) + x.shape[3:])
+
+
+def _decay_rows(last, dv):
+    """exp(G_C), (n, b, h), as a float32 lane row a pair and chunk."""
+    last = _by_pair(last.astype(jnp.float32))
+    return jnp.broadcast_to(last[..., None, None], last.shape + (1, dv))
+
+
+def _scan_forward(u, w, within, q_in, k_out, last, keep):
+    """o, (b, h, seq, dv) in w's dtype, or (keep) the float32 state at
+    every chunk's start, (n, pairs, dk, dv)."""
+    n, b, h, _, dv = u.shape
+    dk, dtype = w.shape[-1], w.dtype
+    u, w, within, q_in, k_out = (_by_pair(x) for x in
+                                 (u, w, within, q_in, k_out))
+    cut = _Scan(u, w, "states" if keep else "forward")
+    rows = _decay_rows(last, dv)
+    kernel = functools.partial(_scan_forward_kernel, keep=keep)
+    if keep:
+        return cut.call(
+            kernel,
+            in_specs=[cut.by_chunk(CHUNK, dv), cut.by_chunk(CHUNK, dk),
+                      cut.by_chunk(CHUNK, dk), cut.by_chunk(1, dv)],
+            out_shape=jax.ShapeDtypeStruct((n, b * h, dk, dv), jnp.float32),
+            out_specs=cut.by_chunk(dk, dv),
+        )(u, w, k_out, rows)
+    o = cut.call(
+        kernel,
+        in_specs=[cut.by_chunk(CHUNK, dv), cut.by_chunk(CHUNK, dk),
+                  cut.by_chunk(CHUNK, CHUNK), cut.by_chunk(CHUNK, dk),
+                  cut.by_chunk(CHUNK, dk), cut.by_chunk(1, dv)],
+        out_shape=jax.ShapeDtypeStruct((b * h, n * CHUNK, dv), dtype),
+        out_specs=cut.by_token(),
+    )(u, w, within, q_in, k_out, rows)
+    return o.reshape(b, h, n * CHUNK, dv)
+
+
+def _scan_backward(u, w, within, q_in, k_out, last, do):
+    n, b, h, _, dv = u.shape
+    dk, dtype = w.shape[-1], w.dtype
+    states = _scan_forward(u, w, within, q_in, k_out, last, True)
+    shapes = [(x.shape, x.dtype) for x in (u, w, within, q_in, k_out)]
+    u, w, within, q_in, k_out = (_by_pair(x) for x in
+                                 (u, w, within, q_in, k_out))
+    cut = _Scan(u, w, "backward")
+
+    def chunked(size, kind):
+        return jax.ShapeDtypeStruct((n, b * h, CHUNK, size), kind)
+
+    *grads, dlast = cut.call(
+        _scan_backward_kernel,
+        in_specs=[cut.by_chunk(dk, dv), cut.by_chunk(CHUNK, dv),
+                  cut.by_chunk(CHUNK, dk), cut.by_chunk(CHUNK, CHUNK),
+                  cut.by_chunk(CHUNK, dk), cut.by_chunk(CHUNK, dk),
+                  cut.by_chunk(1, dv), cut.by_token()],
+        out_shape=(chunked(dv, jnp.float32), chunked(dk, dtype),
+                   chunked(CHUNK, dtype), chunked(dk, dtype),
+                   chunked(dk, dtype),
+                   jax.ShapeDtypeStruct((n, b * h, 1, 128), jnp.float32)),
+        out_specs=(cut.by_chunk(CHUNK, dv), cut.by_chunk(CHUNK, dk),
+                   cut.by_chunk(CHUNK, CHUNK), cut.by_chunk(CHUNK, dk),
+                   cut.by_chunk(CHUNK, dk), cut.by_chunk(1, 128)),
+    )(states, u, w, within, q_in, k_out, _decay_rows(last, dv),
+      do.reshape(b * h, n * CHUNK, dv))
+    return tuple(x.reshape(shape).astype(kind) for x, (shape, kind)
+                 in zip(grads, shapes)) \
+        + (dlast[:, :, 0, 0].reshape(last.shape).astype(last.dtype),)
+
+
+@jax.custom_vjp
+def scan(u, w, within, q_in, k_out, last):
+    """The chunk scan of the gated delta rule (module docstring) over
+    what `wy` hands it, the chunk axis first: u (n, b, h, 64, dv)
+    float32, w, q_in, k_out (n, b, h, 64, dk) and within (n, b, h, 64,
+    64) in the rule's dtype, last (n, b, h) float32.  Returns o, (b, h,
+    seq, dv) in that dtype."""
+    return _scan_forward(u, w, within, q_in, k_out, last, False)
+
+
+def _scan_fwd(*xs):
+    # the states are written by the backward's own forward: under a
+    # `jax.checkpoint` the pass's forward would write them for nothing
+    return _scan_forward(*xs, False), xs
+
+
+scan.defvjp(_scan_fwd, lambda xs, do: _scan_backward(*xs, do))

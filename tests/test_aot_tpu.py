@@ -311,15 +311,20 @@ def test_delta_rule_at_the_qwen_cells_shape_holds_no_state_a_token_aot(
     """`gated_delta_rule` at (2, 32, 8,192, 128) over 16 key heads in
     bf16, forward and backward: it compiles for v5e, its largest
     float32 array is the states at the chunks' starts of one head group
-    (128 x 2 x 8 x 128 x 128), nothing the size of a state a token
-    (8,192 x 64 KB a head), and its temporaries stay under 2 GB (1.25
-    with the intra-chunk kernels; 2.37 while JAX differentiated through
-    the inverse; 7.0 before the heads were worked on in groups).  The
-    intra-chunk part is three Mosaic calls (the forward kernel in the
-    pass and in a group's recomputation, the backward kernel), each
-    under `/delta_rule/`, where `delta_rule_roofline` and
-    `linear_attn_ms` look for the rule's events; no (2, 8, 128, 64, 64)
-    float32 array of the inverse's products is left."""
+    (128 x 16 pairs x 128 x 128, which the scan's backward writes and
+    reads), nothing the size of a state a token (8,192 x 64 KB a head),
+    and its temporaries stay under 1.2 GB (1.16 with the scan's
+    kernels, 1.18 with the intra-chunk kernels alone; 2.37 while JAX
+    differentiated through the inverse; 7.0 before the heads were
+    worked on in groups).  The rule is six Mosaic calls, each under
+    `/delta_rule/`, where `delta_rule_roofline` and `linear_attn_ms`
+    look for the rule's events: the intra-chunk forward kernel in the
+    pass and in a group's recomputation and its backward kernel; the
+    scan's forward in the pass (the recomputation's is dead: the
+    backward does not read o), and inside the group's backward the
+    scan's forward that writes the states and its backward kernel.  No (2, 8, 128, 64, 64) float32
+    array of the inverse's products is left, and no `while` of a chunk
+    scan: the one loop is over the head groups."""
     import re
 
     from mxnet_tpu.ops.linear_attention import (_k_gated_delta_rule,
@@ -347,25 +352,30 @@ def test_delta_rule_at_the_qwen_cells_shape_holds_no_state_a_token_aot(
     states_kept = 128 * 2 * 8 * 128 * 128 * 4
     assert states_kept in sizes
     assert max(sizes) <= 2 * states_kept, max(sizes)
-    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
-    assert "/delta_rule/" in text and "while" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9
+    assert "/delta_rule/" in text
     kernels = re.findall(
         r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"', text)
-    assert len(kernels) == text.count("tpu_custom_call") == 3
+    assert len(kernels) == text.count("tpu_custom_call") == 6
     assert all("/delta_rule/" in name for name in kernels), kernels
-    assert sum("transpose(" in name for name in kernels) == 2
+    assert sum("transpose(" in name for name in kernels) == 4
     assert "f32[2,8,128,64,64]" not in text
+    # two loops over the four head groups (the pass, the backward)
+    assert len(re.findall(r"\bwhile\(", text)) == 2
 
 
 @pytest.mark.parametrize("dt,dk,dv", [
     (jnp.float32, 128, 256), (jnp.bfloat16, 128, 256),
     (jnp.float32, 512, 512)], ids=["f32", "bf16", "f32-heads-of-512"])
 def test_delta_rule_kernels_aot(one_chip, dt, dk, dv):
-    """The rule's two kernels in both dtypes at a short sequence, key
+    """The rule's kernels in both dtypes at a short sequence, key
     heads under value heads, one head group and 12 pairs of chunks (a
     block of 6 a grid step; of 3 at float32 heads of 512, whose blocks
     would not fit the kernels' VMEM at 6: the block is sized from the
-    bytes); lowered for the CPU the same call holds no kernel."""
+    bytes): the intra-chunk pair, and the scan's forward, the forward
+    that writes its states and its backward (no `jax.checkpoint` here:
+    the pass's forward is not recomputed); lowered for the CPU the same
+    call holds no kernel."""
     from mxnet_tpu.ops.linear_attention import _k_gated_delta_rule
 
     qk = jax.ShapeDtypeStruct((1, 2, 1536, dk), dt)
@@ -379,16 +389,15 @@ def test_delta_rule_kernels_aot(one_chip, dt, dk, dv):
     grad = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
     text = jax.jit(grad, in_shardings=(one_chip,) * 5) \
         .lower(qk, qk, v, gb, gb).compile().as_text()
-    assert text.count("tpu_custom_call") == 2
+    assert text.count("tpu_custom_call") == 5
     assert "custom_call" not in jax.jit(grad).lower(
         qk, qk, v, gb, gb).as_text()
 
 
 def test_delta_rule_kernels_under_auto_partitioning(four_chips):
     """As the attention kernels: in a step partitioned over the batch
-    the rule's kernels run a shard of the batch each
-    (`per_batch_shard`), the chunk scan around them is partitioned by
-    the compiler."""
+    the rule's kernels, the chunk scan's among them, run a shard of the
+    batch each (`per_batch_shard`)."""
     from mxnet_tpu.ops.linear_attention import _k_gated_delta_rule
     from mxnet_tpu.parallel import mesh as mesh_mod
 
@@ -405,7 +414,39 @@ def test_delta_rule_kernels_under_auto_partitioning(four_chips):
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
                    in_shardings=(batch,) * 5) \
         .lower(qk, qk, v, gb, gb).compile().as_text()
-    assert text.count("tpu_custom_call") == 2
+    assert text.count("tpu_custom_call") == 5
+
+
+@pytest.mark.parametrize("dt", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_delta_rule_scan_kernels_aot(one_chip, dt):
+    """The chunk scan's kernel pair alone, forward and backward, at a
+    short sequence: 24 chunks of four (sequence, value head) pairs at
+    key size 128 under value size 256, so that every kernel's grid has
+    several chunk blocks and the VMEM state crosses grid steps.  Three
+    Mosaic calls: the forward, the forward that writes the states, the
+    backward."""
+    from mxnet_tpu.ops.pallas import delta_rule
+
+    n, b, h, dk, dv = 24, 1, 4, 128, 256
+    xs = (jax.ShapeDtypeStruct((n, b, h, 64, dv), jnp.float32),
+          jax.ShapeDtypeStruct((n, b, h, 64, dk), dt),
+          jax.ShapeDtypeStruct((n, b, h, 64, 64), dt),
+          jax.ShapeDtypeStruct((n, b, h, 64, dk), dt),
+          jax.ShapeDtypeStruct((n, b, h, 64, dk), dt),
+          jax.ShapeDtypeStruct((n, b, h), jnp.float32))
+    by_pair = [jax.ShapeDtypeStruct((n, b * h, 64, x.shape[-1]), x.dtype)
+               for x in xs[:2]]
+    for kind in ("forward", "states", "backward"):
+        assert delta_rule._Scan(*by_pair, kind).blocks > 1
+
+    def loss(*xs):
+        return delta_rule.scan(*xs).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6))),
+                   in_shardings=(one_chip,) * 6).lower(*xs).compile() \
+        .as_text()
+    assert text.count("tpu_custom_call") == 3
 
 
 def _hybrid_step_aot(topo, monkeypatch, seq=512):
@@ -437,8 +478,9 @@ def test_hybrid_remat_step_names_its_scopes_and_kernels_aot(topo,
     `/conv/`) in forward and backward, the grouped products keep their
     phase prefix (the PR 30 lesson: a change to how layers are traced
     can leave them bare), the one attention layer is three Mosaic
-    calls, and the rule's forward runs once a layer outside the
-    backward pass's own recomputation: its output is kept by name."""
+    calls, the rule six a layer, every one under `/delta_rule/`, and
+    the rule's forward runs once a layer outside the backward pass's
+    own recomputation: its output is kept by name."""
     import re
 
     text = _hybrid_step_aot(topo, monkeypatch).as_text()
@@ -461,6 +503,11 @@ def test_hybrid_remat_step_names_its_scopes_and_kernels_aot(topo,
     assert len(_around(linear, True)) == 3 * 12
     assert not [n for n in _around(linear, True) if re.search(
         "/delta_rule/|/conv/", n)]
+    # the rule's six a layer: the intra-chunk pair's forward in the pass
+    # and in the recomputation and its backward, the scan's forward in
+    # the pass, its states' forward and its backward
+    rule = _around(linear, False)
+    assert len(rule) == 3 * 6 and all("/delta_rule/" in n for n in rule)
 
 
 def _mosaic_op_names(text, scope=""):

@@ -282,6 +282,122 @@ def test_kernels_match_the_jnp_form_interpreted(interpret_pallas, dtype,
         near("d" + name, g, w)
 
 
+def test_a_float32_cotangent_goes_into_a_bf16_product_whole():
+    """The scan kernels' `_product`: a float32 operand against a bf16
+    one goes in as three bf16 parts that sum to it exactly, so the
+    product is the float32 product that JAX's derivative takes
+    (`precision="highest"`) to round-off; one bf16 pass of the same
+    operands is 1e-3 off."""
+    from mxnet_tpu.ops.pallas import delta_rule
+
+    rng = np.random.RandomState(6)
+    x = jnp.asarray(rng.randn(3, 64, 128), jnp.float32)
+    y = jnp.asarray(rng.randn(3, 128, 256), jnp.bfloat16)
+    parts = delta_rule._parts(x, jnp.bfloat16)
+    assert [p.dtype for p in parts] == [jnp.bfloat16] * 3
+    np.testing.assert_array_equal(
+        np.asarray(sum(p.astype(jnp.float32) for p in parts)), np.asarray(x))
+    want = jnp.einsum("pij,pjk->pik", x, y.astype(jnp.float32),
+                      precision="highest")
+    scale = float(jnp.abs(want).max())
+    got = delta_rule._product(jnp.bfloat16, x, y)
+    assert float(jnp.abs(got - want).max()) < 1e-6 * scale
+    one_pass = delta_rule._product(jnp.bfloat16, x.astype(jnp.bfloat16), y)
+    assert float(jnp.abs(one_pass - want).max()) > 1e-3 * scale
+    # the other operand transposed, and a float32 pair alone
+    got = delta_rule._product(jnp.bfloat16, y, x[:, :, :128], 1, 2)
+    want = jnp.einsum("pji,pkj->pik", y.astype(jnp.float32), x[:, :, :128],
+                      precision="highest")
+    assert got.shape == (3, 256, 64)
+    assert float(jnp.abs(got - want).max()) < 1e-6 * float(
+        jnp.abs(want).max())
+    assert len(delta_rule._parts(x, jnp.float32)) == 1
+
+
+def _scan_case(name, dtype, seq=1280, dk=128, dv=256):
+    """What `_wy_xla` hands the chunk scan, for one key head under two
+    value heads: 20 chunks (blocks of 5 a grid step: the state crosses
+    three grid steps) at dk != dv."""
+    from mxnet_tpu.ops import linear_attention as la
+
+    return la._wy_xla(*_case(name, dtype, b=1, hk=1, hv=2, seq=seq, dk=dk,
+                             dv=dv))
+
+
+@pytest.mark.parametrize("dtype,tolerance", [
+    ("float32", 1e-5), ("bfloat16", 1e-2)])
+@pytest.mark.parametrize("name,side", [
+    ("mixed", 16), ("strong_decay", 16), ("mixed", 1)],
+    ids=["pairs_side_by_side", "strong_decay", "a_pair_a_grid_step"])
+def test_scan_kernels_match_the_lax_scan_interpreted(
+        interpret_pallas, monkeypatch, dtype, tolerance, name, side):
+    """The chunk scan's kernel pair (`delta_rule.scan`) against
+    `_scan_xla`: o and `jax.vjp` of all six inputs for random
+    cotangents, at key size 128 under value size 256, over several
+    chunk blocks a pair (and several pair blocks at one pair a grid
+    step), so the VMEM state and its cotangent cross grid steps.  The
+    two share every rounding: float32 agrees to round-off; in bf16 o
+    agrees and the gradients but for a bf16 rounding of a cotangent
+    that the float32 products' order of summation moved across a last
+    bit, carried along the chunks (4e-3 of the largest over 20)."""
+    from mxnet_tpu.ops import linear_attention as la
+    from mxnet_tpu.ops.pallas import delta_rule
+
+    monkeypatch.setattr(delta_rule, "_SCAN_PAIRS", side)
+    xs = _scan_case(name, dtype)
+    n, b, h, _, dv = xs[0].shape
+    cut = delta_rule._Scan(delta_rule._by_pair(xs[0]),
+                           delta_rule._by_pair(xs[1]), "backward")
+    assert (cut.side, cut.chunks, cut.blocks) == (min(side, 2), 5, 4)
+    got, got_vjp = jax.vjp(delta_rule.scan, *xs)
+    want, want_vjp = jax.vjp(la._scan_xla, *xs)
+    assert want.shape == (1, 2, 1280, 256) and want.dtype == xs[1].dtype
+    rng = np.random.RandomState(7)
+    cotangent = jnp.asarray(rng.randn(*want.shape), want.dtype)
+
+    def near(which, g, w):
+        assert g.shape == w.shape and g.dtype == w.dtype, which
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        assert float(jnp.abs(w).max()) > 0, which
+        assert float(jnp.abs(g - w).max()) <= tolerance * float(
+            jnp.abs(w).max()), which
+
+    near("o", got, want)
+    for which, g, w in zip("u w within q_in k_out last".split(),
+                           got_vjp(cotangent), want_vjp(cotangent)):
+        near("d" + which, g, w)
+
+
+def test_rule_with_every_kernel_matches_the_token_scan(monkeypatch,
+                                                       interpret_pallas):
+    """The rule as the TPU runs it (both branches of its
+    `lax.platform_dependent`s the TPU's, every kernel interpreted):
+    values and all five gradients against the token-by-token scan, as
+    the chunked form is held above; 200 tokens padded to four chunks,
+    one key head under two value heads of 128, float32."""
+    from mxnet_tpu.ops import linear_attention as la
+    from mxnet_tpu.ops.pallas import delta_rule
+
+    monkeypatch.setattr(jax.lax, "platform_dependent",
+                        lambda *args, tpu, default: tpu(*args))
+    calls = []
+    scan = delta_rule.scan
+    monkeypatch.setattr(delta_rule, "scan",
+                        lambda *xs: calls.append(1) or scan(*xs))
+    args = _inputs(b=1, hk=1, hv=2, seq=200, dk=128, dv=128)
+    got = la._k_gated_delta_rule(*args)
+    want = _recurrent(*args)
+    assert calls and got.shape == want.shape == args[2].shape
+    assert float(jnp.abs(got - want).max()) < 1e-5 * float(
+        jnp.abs(want).max())
+    grads = [jax.grad(lambda *a, fn=fn: _weighted(fn, *a),
+                      argnums=(0, 1, 2, 3, 4))(*args)
+             for fn in (la._k_gated_delta_rule, _recurrent)]
+    for name, g, w in zip("q k v g beta".split(), *grads):
+        assert float(jnp.abs(g - w).max()) < 2e-5 * float(jnp.abs(w).max()), \
+            name
+
+
 def test_kernels_take_whole_lanes_and_pairs_of_chunks_only():
     from mxnet_tpu.ops.pallas import delta_rule
 
@@ -395,6 +511,7 @@ def test_linear_attention_section_is_on_metrics():
     assert stats["layers"] == 1 and stats["traces"] == {key: 2}
     # heads of 16 and 24 fill no lane: the `jax.numpy` form
     assert stats["kernel_traces"] == {key: 0}
+    assert stats["scan_kernel_traces"] == {key: 0}
     assert stats["xla_traces"] == {key: 2}
     assert stats["chunk"][key] == 64
     assert stats["chunks_per_sequence"][key] == 4       # 200 padded to 256
@@ -410,9 +527,13 @@ def test_linear_attention_section_is_on_metrics():
     stats = la.linear_attention_stats()
     wide_key = "b1 h2 s200 k128 v128 float32"
     assert stats["kernel_traces"][wide_key] == 1
+    assert stats["scan_kernel_traces"][wide_key] == 1
+    assert stats["scan_kernel_traces"][key] == 0
     assert stats["xla_traces"][wide_key] == 0
-    assert "(1 of shapes the kernels take)" in "\n".join(
+    assert "(1 of shapes the kernels take, 1 with the scan's)" in "\n".join(
         profiler._section_tables())
+    assert 'mxtpu_linear_attention_scan_kernel_traces{key="' + wide_key \
+        + '"} 1' in metrics.default_registry().render()
     stats = profiler.sections()["linearAttention"]
     table = "\n".join(profiler._section_tables())
     assert "Linear Attention" in table and "4 chunks of 64" in table
