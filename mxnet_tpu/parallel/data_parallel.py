@@ -15,9 +15,12 @@ is the performance path the bench uses.
 What the host does in a step is always on record: `step()` reads the
 clock at the four boundaries of its three phases (the put of the batch,
 the key and the two scalars, the enqueue of the compiled step) and
-appends one tuple to a bounded log, `step_log()`.  The profiler section
-`dataParallelStep` is summed from that log.  The same phases are
-`profiler.op_scope` spans (`dp.step`, `dp.step.put`, ...), so a
+appends one tuple to a bounded log, `step_log()`, with what jax spent
+making programs inside the call (`profiler.compile_account()`: zeros
+for a warm step).  The profiler section `dataParallelStep` is summed
+from that log; `build()` keeps its own phases in `build_record`.  The
+same phases are `profiler.op_scope` spans (`dp.build`,
+`dp.build.params`, `dp.step`, `dp.step.put`, ...), so a
 `jax.profiler` trace holds them on its host plane, and the compiled
 step's instructions carry `forward` / `optimizer` in their `op_name`
 (backward is `transpose(jvp(forward))`), so a device trace splits by
@@ -42,11 +45,21 @@ from ..ndarray.ndarray import NDArray, _wrap
 from . import mesh as mesh_mod
 
 # one record a `step()` call, oldest first: (trainer serial, t, begin_ns,
-# put_ns, args_ns, enqueue_ns, put_bytes).  `begin_ns` is
-# time.perf_counter_ns() when the put began; the three after it are the
-# phases' nanoseconds.  Always on: the cost is four clock reads, a tuple
-# and an append, and the readers run where nothing was armed beforehand.
+# put_ns, args_ns, enqueue_ns, put_bytes, trace_ns, lower_ns,
+# compile_ns, cache_loads).  `begin_ns` is time.perf_counter_ns() when
+# the put began; the three after it are the phases' nanoseconds; the
+# last four what jax spent making programs inside the call's `dp.step`
+# (profiler.compile_account(): the union of each kind, a load inside
+# its compile).  Always on: the cost is four clock reads, a tuple and an
+# append, and the readers run where nothing was armed beforehand.
 _step_log = collections.deque(maxlen=4096)
+
+# what `build()` did, in the clock and units of the step log: begin_ns,
+# then nanoseconds of its two phases and of the whole, then the compile
+# account booked inside `dp.build`
+BuildRecord = collections.namedtuple(
+    "BuildRecord", "serial begin_ns params_ns opt_state_ns total_ns "
+    "trace_ns lower_ns compile_ns cache_loads")
 _built = 0          # trainers built so far; the newest one's serial
 # [children wrapped in a jax.checkpoint, trainers built with remat=True]
 _remat_built = [0, 0]
@@ -219,6 +232,7 @@ class DataParallelTrainer:
         self._params = None     # list of raw jax arrays (device, sharded)
         self._states = None     # optimizer state pytree per param
         self._t = 0
+        self._build_record = None
 
     # -- param plumbing ------------------------------------------------------
 
@@ -625,9 +639,10 @@ class DataParallelTrainer:
     # -- public api ---------------------------------------------------------
 
     def build(self, x):
-        """Trace + compile the step for example input(s) `x` without
-        running a step (needed before `load_states` on a fresh
-        trainer). Idempotent."""
+        """Place the parameters and the optimizer state and make the
+        step's `jit` for example input(s) `x`, without running a step
+        (needed before `load_states` on a fresh trainer); the first
+        `step()` traces and compiles it.  Idempotent."""
         global _built
         if self._step_fn is not None:
             return
@@ -641,14 +656,35 @@ class DataParallelTrainer:
                 x = x._data
             self._n_inputs = 1
             probe = _wrap(jnp.asarray(x[:2]))
+        now = time.perf_counter_ns
         with _profiler.op_scope("dp.build", "trainer") as scope:
-            self._gather_params(probe)
-            self._init_opt_states()
+            begin = now()
+            with _profiler.op_scope("dp.build.params", "trainer"):
+                self._gather_params(probe)
+            params_end = now()
+            with _profiler.op_scope("dp.build.opt_state", "trainer"):
+                self._init_opt_states()
+            states_end = now()
             self._build_step()
             scope.note(params=len(self._named))
+            compiled = _profiler.compile_account()
+            end = now()
         _built += 1
         self._serial = _built
         _live[_built] = self
+        self._build_record = BuildRecord(
+            _built, begin, params_end - begin, states_end - params_end,
+            end - begin, compiled.trace_ns, compiled.lower_ns,
+            compiled.compile_ns, compiled.cache_loads)
+
+    @property
+    def build_record(self):
+        """The `BuildRecord` of this trainer's `build()`, None before
+        it: where it began, how long the parameters' placement
+        (`dp.build.params`), the optimizer state (`dp.build.opt_state`)
+        and the whole took, and what jax spent making programs inside
+        it."""
+        return self._build_record
 
     def step(self, x, y):
         """One compiled SPMD step; returns scalar loss NDArray.
@@ -659,16 +695,17 @@ class DataParallelTrainer:
         """
         now = time.perf_counter_ns
         t = self._t + 1
+        multi = isinstance(x, (tuple, list))
+        if multi:
+            x = tuple(v._data if isinstance(v, NDArray) else v for v in x)
+        elif isinstance(x, NDArray):
+            x = x._data
+        if isinstance(y, NDArray):
+            y = y._data
+        # before the step's span, never inside it: `dp.build` and the
+        # `dp.step`s are disjoint, and so are their compile accounts
+        self.build(x)
         with _profiler.op_scope("dp.step", "trainer", t=t, step_num=t, _r=1):
-            multi = isinstance(x, (tuple, list))
-            if multi:
-                x = tuple(v._data if isinstance(v, NDArray) else v
-                          for v in x)
-            elif isinstance(x, NDArray):
-                x = x._data
-            if isinstance(y, NDArray):
-                y = y._data
-            self.build(x)
             begin = now()
             with _profiler.op_scope("dp.step.put", "trainer") as scope:
                 data_sh = self._data_sh
@@ -693,9 +730,12 @@ class DataParallelTrainer:
                 loss, self._params, self._states = self._step_fn(
                     self._params, self._states, x, y, key, lr, t_arg)
             enqueue_end = now()
+            compiled = _profiler.compile_account()
             _step_log.append((self._serial, t, begin, put_end - begin,
                               args_end - put_end, enqueue_end - args_end,
-                              put_bytes))
+                              put_bytes, compiled.trace_ns,
+                              compiled.lower_ns, compiled.compile_ns,
+                              compiled.cache_loads))
         return _wrap(loss)
 
     def step_many(self, x, y, n_steps=None):

@@ -9,14 +9,22 @@ Two tiers, per SURVEY §5:
    Every ``op_scope`` is also a ``jax.profiler.TraceAnnotation``, so
    whoever runs a jax.profiler session finds the tier-1 scopes on the
    host plane of the same ``.xplane.pb`` as the device's ops.
+
+Besides, one always-on ``jax.monitoring`` listener books what jax
+spends making programs (tracing, lowering, compiling or loading from
+the persistent cache) to every ``op_scope`` open on the thread that
+made them, and keeps the events in ``compile_log()``
+(docs/observability.md, "Set-up and the compile account").
 """
 from __future__ import annotations
 
+import collections
 import json
 import os
 import threading
 import time
 
+import jax.monitoring
 from jax.profiler import TraceAnnotation
 
 from .base import getenv
@@ -164,13 +172,170 @@ def active_scopes():
                 if stack}
 
 
+# ---------------------------------------------------------------------------
+# The compile account.  jax reports each piece of program-making work
+# when it ENDS, on the thread that did it, with its duration; the
+# interval is [now - duration, now] on perf_counter_ns, the step log's
+# clock.  Tracing nests (a jit traced inside another emits its own
+# event, and the outer one includes it) and a persistent-cache load
+# lies inside its compile event, so a plain sum counts time twice.
+# Booked time is a UNION instead: an event inside one already booked
+# adds nothing, an outer event that arrives after its inner ones
+# replaces them and takes their time for its own kind, so the three
+# kinds never overlap and their sum never passes the wall time.  Cache
+# loads are counted apart, as seconds inside `compile`.
+
+TRACE, LOWER, COMPILE, CACHE_LOAD = range(4)
+COMPILE_KINDS = ("trace", "lower", "compile", "cache_load")
+_EVENT_KIND = {
+    "/jax/core/compile/jaxpr_trace_duration": TRACE,
+    # Pallas kernels are lowered to Mosaic inside this one
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": LOWER,
+    # a persistent-cache load included
+    "/jax/core/compile/backend_compile_duration": COMPILE,
+    "/jax/compilation_cache/cache_retrieval_time_sec": CACHE_LOAD,
+}
+# an account keeps at most this many booked intervals; below the oldest
+# kept one nothing is booked again (an event reaching back past it
+# books only what lies after it: the account reads low, never twice)
+_KEPT_INTERVALS = 1 << 16
+
+CompileAccount = collections.namedtuple(
+    "CompileAccount", "traces trace_ns lowers lower_ns compiles compile_ns "
+    "cache_loads cache_load_ns")
+NO_COMPILE = CompileAccount(0, 0, 0, 0, 0, 0, 0, 0)
+
+
+class _Account:
+    """The union of one thread's compile events from `t0` on: per kind
+    the nanoseconds booked and the events counted."""
+    __slots__ = ("floor", "ns", "n", "spans")
+
+    def __init__(self, t0):
+        self.floor = t0
+        self.ns = [0, 0, 0, 0]
+        self.n = [0, 0, 0, 0]
+        self.spans = []     # [(start, end, kind)], disjoint, oldest first
+
+    def book(self, start, end, kind):
+        self.n[kind] += 1
+        start = max(start, self.floor)
+        if kind == CACHE_LOAD:
+            self.ns[kind] += max(end - start, 0)
+            return
+        spans = self.spans
+        # events arrive in the order they end, so a kept interval that
+        # starts inside this one lies inside it: replaced
+        while spans and spans[-1][0] >= start:
+            s, e, k = spans.pop()
+            self.ns[k] -= e - s
+        if spans and spans[-1][1] > start:
+            start = spans[-1][1]    # begun inside an earlier interval
+        if end > start:
+            self.ns[kind] += end - start
+            spans.append((start, end, kind))
+            if len(spans) > _KEPT_INTERVALS:
+                half = len(spans) // 2
+                self.floor = spans[half - 1][1]
+                del spans[:half]
+
+    def totals(self):
+        ns, n = self.ns, self.n
+        return CompileAccount(n[TRACE], ns[TRACE], n[LOWER], ns[LOWER],
+                              n[COMPILE], ns[COMPILE], n[CACHE_LOAD],
+                              ns[CACHE_LOAD])
+
+
+# per thread: `stack`, the open op scopes, innermost last; `root`, the
+# thread's own account, from which the `compile` section is summed
+_thread = threading.local()
+_compile_lock = threading.Lock()
+_compile_window = [0] * 8   # the section: CompileAccount's fields, summed
+# (end_ns, kind, dur_ns, scope path), oldest first.  Most events are
+# traces nested in others (a jnp function is a jit of its own): a tiny
+# two-layer BERT cell logs 1,707 before its window opens, so the log
+# holds a set-up's worth of any model the benchmark runs
+COMPILE_LOG_KEPT = 1 << 15
+_compile_log = collections.deque(maxlen=COMPILE_LOG_KEPT)
+
+
+def _on_compile_event(event, seconds, **_kwargs):
+    kind = _EVENT_KIND.get(event)
+    if kind is None:
+        return
+    end = time.perf_counter_ns()
+    start = end - int(seconds * 1e9)
+    stack = getattr(_thread, "stack", ())
+    root = getattr(_thread, "root", None)
+    if root is None:
+        root = _thread.root = _Account(0)
+    before = root.totals()
+    root.book(start, end, kind)
+    after = root.totals()
+    for scope in stack:
+        if scope.acct is None:
+            scope.acct = _Account(int(scope.t0 * 1e3))
+        scope.acct.book(start, end, kind)
+    path = "/".join(scope.name for scope in stack)
+    with _compile_lock:
+        for i, (a, b) in enumerate(zip(after, before)):
+            _compile_window[i] += a - b
+        _compile_log.append((end, COMPILE_KINDS[kind], end - start, path))
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+
+
+def compile_account():
+    """What jax spent making programs while the innermost ``op_scope``
+    open on this thread was open, its children's included, as a
+    ``CompileAccount``; ``NO_COMPILE`` outside any scope or where
+    nothing was made."""
+    stack = getattr(_thread, "stack", None)
+    if not stack or stack[-1].acct is None:
+        return NO_COMPILE
+    return stack[-1].acct.totals()
+
+
+def compile_log(last=None):
+    """The newest `last` compile events (all that are kept, at most
+    `COMPILE_LOG_KEPT`, by default), oldest first: ``(end_ns, kind,
+    dur_ns, path)``, `kind` one of ``COMPILE_KINDS``, `path` the names
+    of the op scopes open on the thread, outermost first, joined by
+    ``/`` (``""`` outside any).  Durations as jax reported them, not
+    the union."""
+    with _compile_lock:
+        log = list(_compile_log)
+    return log if last is None else log[max(len(log) - last, 0):]
+
+
+def compile_stats():
+    """The ``compile`` profiler section: programs traced, lowered and
+    compiled or loaded since the window opened, and the union of their
+    milliseconds on every thread (a load's inside its compile's)."""
+    with _compile_lock:
+        acct = CompileAccount(*_compile_window)
+    return {"traces": acct.traces, "trace_ms": round(acct.trace_ns / 1e6, 3),
+            "lower_ms": round(acct.lower_ns / 1e6, 3),
+            "compiles": acct.compiles,
+            "compile_ms": round(acct.compile_ns / 1e6, 3),
+            "cache_loads": acct.cache_loads,
+            "cache_load_ms": round(acct.cache_load_ns / 1e6, 3)}
+
+
+def reset_compile_stats():
+    with _compile_lock:
+        _compile_window[:] = [0] * 8
+
+
 class _OpScope:
-    __slots__ = ("name", "cat", "attrs", "t0", "_ann")
+    __slots__ = ("name", "cat", "attrs", "t0", "_ann", "acct")
 
     def __init__(self, name, cat, attrs):
         self.name = name
         self.cat = cat
         self.attrs = attrs
+        self.acct = None    # the compile account, from the first event
 
     def __enter__(self):
         if _scope_track:
@@ -189,6 +354,11 @@ class _OpScope:
         # span in the exported trace / flight-recorder ring
         _tracer.span_begin(self.name, self.cat)
         self.t0 = time.perf_counter() * 1e6
+        # where the compile listener books what this scope makes
+        try:
+            _thread.stack.append(self)
+        except AttributeError:
+            _thread.stack = [self]
         return self
 
     def note(self, **attrs):
@@ -198,6 +368,14 @@ class _OpScope:
         self._ann.set_metadata(**attrs)
 
     def __exit__(self, exc_type, exc, tb):
+        try:
+            stack = _thread.stack
+            if stack[-1] is self:
+                stack.pop()
+            else:
+                stack.remove(self)  # closed out of order
+        except (AttributeError, IndexError, ValueError):
+            pass    # entered on another thread
         t1 = time.perf_counter() * 1e6
         record_op(self.name, self.t0, t1, cat=self.cat)
         _tracer.span_end(self.name, self.cat, **self.attrs)
@@ -334,6 +512,17 @@ register_section(
          ("flight-recorder dumps", "flight_dumps"),
          ("/metrics scrapes", "scrapes"),
          ("aggregate() calls", "aggregations"))))
+# the compile account's counters live in this module
+register_section(
+    "compile", compile_stats, reset_compile_stats, rows_table(
+        "Compile (jax: trace / lower / compile or cache load)",
+        (("programs traced", "traces"),
+         ("tracing (ms)", "trace_ms"),
+         ("lowering (ms)", "lower_ms"),
+         ("programs compiled or loaded", "compiles"),
+         ("compiling, loads included (ms)", "compile_ms"),
+         ("loaded from the persistent cache", "cache_loads"),
+         ("loading (ms)", "cache_load_ms"))))
 
 
 def dumps(reset=False, format="json"):

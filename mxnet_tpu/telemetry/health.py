@@ -17,11 +17,14 @@ Three data paths feed one :class:`HealthMonitor`:
   ``trainer.step``/``whole_step`` close a STEP, ``allreduce``/
   ``reduce_scatter``/``allgather``/``broadcast`` book collective time,
   ``fused_update`` books optimizer time, ``cat="checkpoint"`` scopes
-  book checkpoint stalls, ``cached_op.compile.*`` books compile time.
+  book checkpoint stalls.
 - **sections** — the window deltas of the ``dataPipeline`` section
-  (``wait_ms`` = input starvation, ``h2d_ms``) and the ``resilience``
+  (``wait_ms`` = input starvation, ``h2d_ms``), the ``resilience``
   section (``time_lost_ms`` + ``reshard_ms`` = the goodput debits for
-  restarts / resizes / watchdog recoveries).
+  restarts / resizes / watchdog recoveries) and the ``compile`` section
+  (``trace_ms`` + ``lower_ms`` + ``compile_ms``: what jax spent making
+  programs, every compiling path alike, from the profiler's compile
+  account).
 - **FLOP hooks** — ``Trainer.whole_step`` notes batch/param geometry
   (the analytic dense fallback, ``6 * params * batch``) and
   ``WholeStepCompiler`` notes each fresh executable so the monitor can
@@ -68,7 +71,7 @@ _counters = {
     "collective_ms": 0.0,    # allreduce/reduce_scatter/allgather/broadcast
     "optimizer_ms": 0.0,     # fused_update scopes
     "checkpoint_ms": 0.0,    # cat="checkpoint" scopes (save/restore stalls)
-    "compile_ms": 0.0,       # cached_op.compile.* scopes
+    "compile_ms": 0.0,       # compile section's trace+lower+compile (tick)
     "lost_ms": 0.0,          # resilience debits folded in at tick
     "ticks": 0,              # monitor windows evaluated
     "alerts": 0,             # SLO rule fire transitions
@@ -135,9 +138,6 @@ def _scope_end(name, cat, t0_us, t1_us):
     elif cat == "checkpoint":
         with _lock:
             _counters["checkpoint_ms"] += dur_ms
-    elif name.startswith("cached_op.compile"):
-        with _lock:
-            _counters["compile_ms"] += dur_ms
 
 
 def _note_whole_step(trainer, batch_size):
@@ -412,6 +412,7 @@ class HealthMonitor:
         self._prev = {}
         self._prev_pipeline = {}
         self._prev_resilience = {}
+        self._prev_compile = {}
         self._rank_prev = {}
         self._rank_rate = {}
         self._rank_streak = {}
@@ -491,6 +492,8 @@ class HealthMonitor:
                                                  "pipeline_stats")
         self._prev_resilience = self._read_section(".resilience.stats",
                                                    "resilience_stats")
+        self._prev_compile = self._read_section(".profiler",
+                                                "compile_stats")
 
     # -- the tick ------------------------------------------------------------
 
@@ -544,13 +547,17 @@ class HealthMonitor:
         res = self._read_section(".resilience.stats", "resilience_stats")
         dr = self._delta(res, self._prev_resilience)
         self._prev_resilience = res
+        comp = self._read_section(".profiler", "compile_stats")
+        dc = self._delta(comp, self._prev_compile)
+        self._prev_compile = comp
 
         steps = d.get("steps", 0)
         step_ms = d.get("step_ms", 0.0)
         collective = d.get("collective_ms", 0.0)
         optimizer = d.get("optimizer_ms", 0.0)
         checkpoint = d.get("checkpoint_ms", 0.0)
-        compile_ms = d.get("compile_ms", 0.0)
+        compile_ms = sum(dc.get(k, 0.0)
+                         for k in ("trace_ms", "lower_ms", "compile_ms"))
         compute = max(step_ms - collective - optimizer, 0.0)
         input_wait = dp.get("wait_ms", 0.0)
         h2d = dp.get("h2d_ms", 0.0)
@@ -609,6 +616,7 @@ class HealthMonitor:
             _counters["input_wait_ms"] += input_wait
             _counters["h2d_ms"] += h2d
             _counters["compute_ms"] += compute
+            _counters["compile_ms"] += compile_ms
             _counters["lost_ms"] += lost + compile_ms
             _counters["rules_firing"] = len(firing) + len(stragglers)
             _counters["step_p95_ms"] = round(step_p95, 3)

@@ -158,7 +158,11 @@ def test_step_log_holds_one_record_a_step():
     assert {r[6] for r in records} == {x.nbytes + y.nbytes}
     begins = [r[2] for r in records]
     assert begins == sorted(begins)
-    for _serial, _t, begin, put, args, enqueue, _bytes in records:
+    # the first seven fields keep their meaning and order; the compile
+    # account's four come after them
+    assert {len(r) for r in records} == {11}
+    for _serial, _t, begin, put, args, enqueue, _bytes in (
+            r[:7] for r in records):
         assert put > 0 and args > 0 and enqueue > 0
     # a step's phases end before the next step's begin
     for a, b in zip(records, records[1:]):
@@ -250,6 +254,255 @@ def test_section_says_what_remat_wrapped_and_keeps():
         "dataParallelStep"]["remat_children"] == 2
     stats = profiler.sections()["dataParallelStep"]
     assert stats["remat_children"] == 0 and stats["remat_saves"] == {}
+
+
+# -- the compile account ---------------------------------------------------
+
+def _fresh_jit(scale):
+    """A function jax has not traced yet, so every call of it compiles."""
+    return jax.jit(lambda v: jnp.sin(v) * scale + scale)
+
+
+def test_jit_inside_jit_books_the_union():
+    inner = [_fresh_jit(float(k)) for k in range(1, 6)]
+
+    @jax.jit
+    def outer(v):
+        return sum(f(v) for f in inner) + jnp.cos(v)
+
+    x = jnp.ones(7)
+    with profiler.op_scope("test.union", "test"):
+        outer(x).block_until_ready()
+        account = profiler.compile_account()
+    traces = [e for e in profiler.compile_log()
+              if e[3] == "test.union" and e[1] == "trace"]
+    walls = [dur for _end, _kind, dur, _path in traces]
+    # the outer trace ends last and encloses the inner ones
+    assert len(traces) >= 6 and max(walls) == walls[-1]
+    assert account.traces == len(traces)
+    assert 0 < account.trace_ns <= walls[-1]
+    assert sum(walls) > walls[-1], "a plain sum counts the inner traces twice"
+    assert account.lowers >= 1 and account.compiles >= 1
+    assert account.lower_ns > 0 and account.compile_ns > 0
+
+
+def test_a_scope_holds_its_childrens_account_and_a_warm_call_books_nothing():
+    f = _fresh_jit(3.0)
+    x = jnp.ones(5)
+    with profiler.op_scope("test.outer", "test"):
+        with profiler.op_scope("test.inner", "test"):
+            f(x).block_until_ready()
+            inner = profiler.compile_account()
+        outer = profiler.compile_account()
+        with profiler.op_scope("test.warm", "test"):
+            f(x).block_until_ready()
+            assert profiler.compile_account() == profiler.NO_COMPILE
+    assert inner.compiles >= 1 and outer == inner
+    paths = {e[3] for e in profiler.compile_log(last=inner.traces
+                                                + inner.lowers
+                                                + inner.compiles)}
+    assert paths == {"test.outer/test.inner"}
+    assert profiler.compile_account() == profiler.NO_COMPILE
+
+
+def test_the_compile_log_is_bounded_and_shaped_like_the_step_log(monkeypatch):
+    assert profiler._compile_log.maxlen == profiler.COMPILE_LOG_KEPT
+    monkeypatch.setattr(profiler, "_compile_log", collections.deque(maxlen=3))
+    _fresh_jit(2.0)(jnp.ones(3)).block_until_ready()
+    log = profiler.compile_log()
+    assert len(log) == 3 and profiler.compile_log(last=0) == []
+    assert profiler.compile_log(last=2) == log[1:]
+    assert [e[1] for e in log] == ["trace", "lower", "compile"]
+    ends = [e[0] for e in log]
+    assert ends == sorted(ends) and all(e[2] > 0 for e in log)
+    assert {e[3] for e in log} == {""}, "made outside every scope"
+
+
+def test_the_compile_section_sums_every_event_and_resets():
+    from mxnet_tpu.telemetry import metrics
+
+    profiler.sections(reset=True)
+    with profiler.op_scope("test.section", "test"):
+        _fresh_jit(4.0)(jnp.ones(6)).block_until_ready()
+        account = profiler.compile_account()
+    stats = profiler.sections()["compile"]
+    assert stats["traces"] >= account.traces >= 1
+    assert stats["compiles"] >= account.compiles >= 1
+    assert stats["compile_ms"] >= round(account.compile_ns / 1e6, 3) > 0
+    text = metrics.default_registry().render()
+    assert "mxtpu_compile_compiles" in text
+    assert "mxtpu_compile_trace_ms" in text
+    assert "Compile (jax: trace / lower / compile or cache load):" in \
+        profiler._section_tables()
+    profiler.sections(reset=True)
+    assert set(profiler.sections()["compile"].values()) == {0}
+
+
+def test_scopes_closed_out_of_order_leave_the_stack_sound():
+    a = profiler.op_scope("test.a", "test").__enter__()
+    b = profiler.op_scope("test.b", "test").__enter__()
+    a.__exit__(None, None, None)
+    assert [s.name for s in profiler._thread.stack] == ["test.b"]
+    b.__exit__(None, None, None)
+    assert profiler._thread.stack == []
+
+
+def test_each_thread_books_to_its_own_scopes():
+    f = _fresh_jit(5.0)
+    seen = []
+
+    def other():
+        f(jnp.ones(4)).block_until_ready()
+        seen.append(profiler.compile_account())
+
+    with profiler.op_scope("test.main", "test"):
+        worker = threading.Thread(target=other)
+        worker.start()
+        worker.join(timeout=60)
+        mine = profiler.compile_account()
+    assert not worker.is_alive() and seen == [profiler.NO_COMPILE]
+    assert mine == profiler.NO_COMPILE, "compiled on another thread"
+
+
+CACHE_HIT = r"""
+import json, sys
+sys.path.insert(0, {repo!r})
+import jax, jax.numpy as jnp
+from mxnet_tpu import profiler
+f = jax.jit(lambda v: jnp.sin(v) * 3.0 + 1.0)
+x = jnp.ones(5)
+accounts = []
+for _ in range(2):   # compiling, then loading from the persistent cache
+    jax.clear_caches()
+    with profiler.op_scope("probe", "test"):
+        f(x).block_until_ready()
+        accounts.append(list(profiler.compile_account()))
+events = [e for e in profiler.compile_log() if e[3] == "probe"]
+print(json.dumps({{"accounts": accounts, "events": events,
+                  "section": profiler.compile_stats()}}))
+"""
+
+
+def test_a_cache_hit_books_its_load_once(tmp_path):
+    """On a persistent-cache hit jax reports the compile event AND, inside
+    it, the load: the account books the compile's time once, and counts
+    the load apart."""
+    script = tmp_path / "hit.py"
+    script.write_text(CACHE_HIT.format(repo=REPO))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+    env.pop("MXTPU_TRACE", None)
+    done = subprocess.run([sys.executable, str(script)], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-2000:]
+    out = json.loads(done.stdout.strip().splitlines()[-1])
+    first, second = (profiler.CompileAccount(*a) for a in out["accounts"])
+    assert first.compiles >= 1 and first.cache_loads == 0
+    assert second.cache_loads >= 1
+    loads = [e for e in out["events"] if e[1] == "cache_load"]
+    compiles = [e for e in out["events"] if e[1] == "compile"][-len(loads):]
+    # every load lies inside the compile event that reports it after it
+    for (l_end, _, l_dur, _), (c_end, _, c_dur, _) in zip(loads, compiles):
+        assert c_end - c_dur <= l_end - l_dur and l_end <= c_end
+    assert 0 < second.cache_load_ns <= second.compile_ns
+    assert second.compile_ns <= sum(e[2] for e in compiles)
+    assert second.compile_ns < sum(e[2] for e in compiles + loads)
+    assert out["section"]["cache_loads"] == second.cache_loads
+
+
+# -- the trainer's set-up in its records ------------------------------------
+
+def test_build_record_splits_the_build_and_books_its_compiles():
+    trainer, (x, y) = _trainer(), _batch()
+    assert trainer.build_record is None
+    profiler.sections(reset=True)
+    trainer.build(x)
+    record = trainer.build_record
+    assert isinstance(record, dp.BuildRecord)
+    assert record.serial == trainer._serial
+    assert record.params_ns > 0 and record.opt_state_ns > 0
+    assert record.params_ns + record.opt_state_ns <= record.total_ns
+    made = record.trace_ns + record.lower_ns + record.compile_ns
+    assert 0 <= made <= record.total_ns
+    inside = [e for e in profiler.compile_log()
+              if e[0] >= record.begin_ns and e[3].startswith("dp.build")]
+    assert all(e[3].split("/")[1] in ("dp.build.params", "dp.build.opt_state")
+               for e in inside)
+    trainer.step(x, y)
+    assert trainer.build_record == record, "built once"
+    with pytest.raises(AttributeError):
+        trainer.build_record = None
+
+
+def test_a_warm_step_books_zeros_and_a_new_shape_names_its_recompile():
+    trainer, (x, y) = _trainer(), _batch()
+    for _ in range(3):
+        trainer.step(x, y)
+    x2, y2 = _batch(8)
+    trainer.step(x2, y2)
+    trainer.step(x2, y2).wait_to_read()
+    records = dp.step_log(last=5)
+    assert [r[1] for r in records] == [1, 2, 3, 4, 5]
+    first, warm, new_shape, again = (
+        records[0], records[1:3], records[3], records[4])
+    for record in (first, new_shape):
+        trace_ns, lower_ns, compile_ns, _loads = record[7:]
+        assert trace_ns > 0 and lower_ns > 0 and compile_ns > 0
+        # what was made lies inside the enqueue (the step's own program)
+        # and the put (its first `global_put` of that shape)
+        assert trace_ns + lower_ns + compile_ns <= sum(record[3:6])
+    for record in warm + [again]:
+        assert record[7:] == (0, 0, 0, 0)
+
+
+def test_build_phases_are_on_the_xplane_host_plane(tmp_path):
+    trainer, (x, y) = _trainer(), _batch()
+    with _profiler_session(tmp_path):
+        trainer.step(x, y).wait_to_read()
+    events = _host_events(tmp_path)
+    names = collections.Counter(name for name, _ in events)
+    assert names["dp.build"] == names["dp.build.params"] == \
+        names["dp.build.opt_state"] == 1
+    assert names["dp.step"] == 1
+
+
+def test_step_log_and_trace_share_a_clock_by_one_offset(tmp_path):
+    """A `dp.step.put` event of the xplane and the step-log record of the
+    same `t` differ by one offset, the same for every step: the trace's
+    clock counts from the session's start, the log's is perf_counter_ns
+    (docs/observability.md, "Step log and the dataParallelStep
+    section")."""
+    from jax.profiler import ProfileData
+
+    trainer, (x, y) = _trainer("sgd"), _batch()
+    trainer.step(x, y).wait_to_read()
+    with _profiler_session(tmp_path):
+        for _ in range(6):
+            trainer.step(x, y)
+        trainer.step(x, y).wait_to_read()
+    path, = glob.glob(os.path.join(tmp_path, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    steps, puts = {}, []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name == "dp.step":
+                        steps[int(dict(e.stats)["step_num"])] = (
+                            e.start_ns, e.start_ns + e.duration_ns)
+                    elif e.name == "dp.step.put":
+                        puts.append(e.start_ns)
+    by_t = {}
+    for start in puts:
+        t, = [t for t, (lo, hi) in steps.items() if lo <= start <= hi]
+        by_t[t] = start
+    records = {r[1]: r for r in dp.step_log(last=7)}
+    assert sorted(by_t) == sorted(records) == list(range(2, 9))
+    offsets = [by_t[t] - records[t][2] for t in by_t]
+    middle = statistics.median(offsets)
+    assert all(abs(o - middle) < 500_000 for o in offsets), offsets
 
 
 def test_section_and_span_names_pass_the_invariant_passes():

@@ -334,6 +334,11 @@ def _dp_record(dp):
 SECTIONS = [
     ("cachedGraph", "gluon.block", "compiles reuses", "compiles",
      lambda m: m._graph_stats.update(compiles=m._graph_stats["compiles"] + 1)),
+    ("compile", "profiler",
+     "cache_load_ms cache_loads compile_ms compiles lower_ms trace_ms "
+     "traces", "compiles",
+     lambda m: m._on_compile_event(
+         "/jax/core/compile/backend_compile_duration", 1e-4)),
     ("ctrl", "serve.control_plane",
      "blocked_bounds blocked_cooldown load replicas retired rpc_errors "
      "rpc_requests rpc_streams scale_downs scale_ups spawn_failures spawns "
@@ -424,6 +429,53 @@ def test_registered_sections_cover_all_subsystems(name, owner, keys, counted,
     after = profiler.sections().get(name)
     assert after is None or after[counted] == 0, after
     assert json.loads(profiler.dumps())[name].keys() == before.keys()
+
+
+def test_health_compile_ms_comes_from_the_compile_account(monkeypatch):
+    """The monitor's `compile_ms` is the `compile` section's trace +
+    lower + compile over the window: a Gluon CachedOp's first call and a
+    `DataParallelTrainer`'s first step alike (no span name is matched),
+    and nothing for warm calls."""
+    import inspect
+
+    from mxnet_tpu import gluon
+    from mxnet_tpu.parallel import data_parallel as dp
+    from mxnet_tpu.telemetry import health
+
+    assert "cached_op.compile" not in inspect.getsource(health)
+    # arming marks the process for good: undone after the test
+    monkeypatch.setattr(health, "_ever_armed", health._ever_armed)
+    rng = np.random.RandomState(0)
+    x = rng.rand(8, 6).astype("float32")
+    y = rng.randint(0, 3, (8,)).astype("float32")
+    mon = health.HealthMonitor(tick_sec=0).arm()
+    try:
+        for make in ("cached_op", "trainer"):
+            mon.tick()
+            before = profiler.compile_stats()
+            net = gluon.nn.HybridSequential()
+            net.add(gluon.nn.Dense(5, activation="tanh"), gluon.nn.Dense(3))
+            net.initialize(mx.init.Xavier())
+            if make == "cached_op":
+                net.hybridize()
+                net(mx.nd.array(x)).wait_to_read()
+            else:
+                trainer = dp.DataParallelTrainer(
+                    net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+                    {"learning_rate": 0.1})
+                trainer.step(x, y).wait_to_read()
+            after = profiler.compile_stats()
+            window = mon.tick()
+            made = sum(after[k] - before[k]
+                       for k in ("trace_ms", "lower_ms", "compile_ms"))
+            assert made > 0, make
+            assert window["compile_ms"] == pytest.approx(made, abs=0.01)
+            assert window["lost_ms"] >= window["compile_ms"]
+        trainer.step(x, y).wait_to_read()
+        assert mon.tick()["compile_ms"] == 0, "a warm step makes nothing"
+        assert profiler.sections()["health"]["compile_ms"] > 0
+    finally:
+        mon.disarm()
 
 
 def test_profiler_names_no_subsystem():
