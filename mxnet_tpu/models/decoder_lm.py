@@ -201,8 +201,9 @@ class DecoderLayer(HybridBlock):
         # around it write and read them group-major
         groups = linear_attention.head_groups(u.shape[0], hk, hv)
         mixed = self._linear(F, u, p["qkvz_weight"])
-        qkv = F.causal_conv1d(mixed[:, :, :2 * key + value],
-                              p["conv_weight"])
+        # over [q | k | v], the first columns of `mixed`: the conv
+        # weight's channels
+        qkv = F.causal_conv1d(mixed, p["conv_weight"])
         with jax.named_scope("prologue"):
             q = F.split_heads(qkv, heads=hk, head_dim=dk, norm="l2",
                               scale=dk ** -0.5, groups=groups)

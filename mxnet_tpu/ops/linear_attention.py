@@ -106,8 +106,20 @@ inverse, its derivative's two products and every sum are float32.  A
 length that is no multiple of the chunk is padded with tokens that
 leave the state as it is (beta 0, g 0) and whose outputs are dropped.
 
-Every rule traced is counted, and whether its shapes are the kernels'
-(the profiler section `linearAttention`).
+The short convolution (`causal_conv1d`) has two forms too: `_conv_xla`,
+shifted multiply-adds over a padded copy that JAX differentiates, and
+the kernel pair of ops/pallas/short_conv.py, taken under
+`lax.platform_dependent` where the channels are whole lanes, the taps at
+most 8 and the sequence whole 16-row tiles (bf16 or float32).  Both
+multiply and add the taps in float32 in tap order, take SiLU in float32
+and round once.  The kernels read the channels where the packed
+projection holds them; their backward recomputes the pre-activation in
+VMEM, sums dx's taps in float32 and rounds once (JAX's derivative of
+`_conv_xla` rounds each tap's part to the input's dtype and adds them
+there), accumulates dw in float32 and keeps the inputs only.
+
+Every rule and every convolution traced is counted, and whether its
+shapes are the kernels' (the profiler section `linearAttention`).
 """
 from __future__ import annotations
 
@@ -134,6 +146,10 @@ RESIDUAL_NAMES = registry.RESIDUAL_NAMES["gated_delta_rule"]
 _traced = collections.Counter()
 _kernel_traced = collections.Counter()
 _scan_kernel_traced = collections.Counter()
+# every short convolution traced: (batch, seq, channels, taps, dtype)
+# -> traces, and those of them whose shapes the conv kernels take
+_conv_traced = collections.Counter()
+_conv_kernel_traced = collections.Counter()
 
 
 def _k_l2_norm(data, *, eps=1e-6):
@@ -146,19 +162,52 @@ def _k_l2_norm(data, *, eps=1e-6):
 register("l2_norm", _k_l2_norm, arg_names=("data",))
 
 
+def _conv_xla(data, weight):
+    """The convolution as shifted multiply-adds over a padded copy,
+    which JAX differentiates: the off-TPU path and the kernels'
+    oracle."""
+    seq, (channels, taps) = data.shape[1], weight.shape
+    x = jnp.pad(data[:, :, :channels], ((0, 0), (taps - 1, 0), (0, 0)))
+    w = weight.astype(jnp.float32)
+    y = sum(x[:, j:j + seq].astype(jnp.float32) * w[:, j]
+            for j in range(taps))
+    return jax.nn.silu(y).astype(data.dtype)
+
+
 def _k_causal_conv1d(data, weight):
     """SiLU of the depthwise causal convolution along the sequence of
-    (batch, seq, channels): y_t = silu(sum_j weight[:, j] *
-    x_(t - (taps - 1) + j)), zeros before the sequence's start, no
-    bias; weight (channels, taps), the last tap on x_t itself.  A tap
-    is one shifted multiply-add in float32."""
-    seq, taps = data.shape[1], weight.shape[1]
+    (batch, seq, width) over its first `channels` columns (all of them,
+    or the `[q | k | v]` of a packed `[q | k | v | z]`): y_t =
+    silu(sum_j weight[:, j] * x_(t - (taps - 1) + j)), zeros before
+    the sequence's start, no bias; weight (channels, taps), the last tap
+    on x_t itself; returns (batch, seq, channels) in data's dtype.  The
+    taps are multiplied and added in float32 in tap order, SiLU in
+    float32, one rounding at the end.
+
+    Where the computation is lowered for the TPU and the shapes allow
+    it (channels of whole lanes, at most 8 taps, a sequence of whole
+    16-row tiles, bf16 or float32) the kernel pair of
+    ops/pallas/short_conv.py, which reads the packed array where it
+    lies; everywhere else `_conv_xla`."""
+    from .pallas import short_conv
+
+    b, seq, _ = data.shape
+    channels, taps = weight.shape
+    key = (b, seq, channels, taps, jnp.dtype(data.dtype).name)
+    _conv_traced[key] += 1
     with jax.named_scope("conv"):
-        x = jnp.pad(data, ((0, 0), (taps - 1, 0), (0, 0)))
-        w = weight.astype(jnp.float32)
-        y = sum(x[:, j:j + seq].astype(jnp.float32) * w[:, j]
-                for j in range(taps))
-        return jax.nn.silu(y).astype(data.dtype)
+        if not short_conv.admits(data.shape, weight.shape, data.dtype):
+            return _conv_xla(data, weight)
+        _conv_kernel_traced[key] += 1
+
+        def kernels(data, weight):
+            from ..parallel.mesh import per_batch_shard
+
+            return per_batch_shard(
+                short_conv.conv, weight.astype(jnp.float32).T[None], data)
+
+        return jax.lax.platform_dependent(data, weight, tpu=kernels,
+                                          default=_conv_xla)
 
 
 register("causal_conv1d", _k_causal_conv1d, arg_names=("data", "weight"))
@@ -421,10 +470,17 @@ def linear_attention_stats():
     scan takes the scan's kernel pair), `xla_traces` (of neither),
     `chunk`, `chunks_per_sequence` and `state_bytes_kept` (the float32
     states at the chunks' starts that the scan's derivative keeps for
-    the layer being differentiated) by shape."""
+    the layer being differentiated) by shape; and the short
+    convolutions traced, by their own shape: `conv_kernel_traces` (of
+    shapes the conv kernels take) and `conv_xla_traces`."""
     out = {"layers": len(_traced), "traces": {}, "kernel_traces": {},
            "scan_kernel_traces": {}, "xla_traces": {}, "chunk": {},
-           "chunks_per_sequence": {}, "state_bytes_kept": {}}
+           "chunks_per_sequence": {}, "state_bytes_kept": {},
+           "conv_kernel_traces": {}, "conv_xla_traces": {}}
+    for shape, n in _conv_traced.items():
+        key = "b{} s{} c{} t{} {}".format(*shape)
+        out["conv_kernel_traces"][key] = _conv_kernel_traced[shape]
+        out["conv_xla_traces"][key] = n - _conv_kernel_traced[shape]
     for shape, n in _traced.items():
         b, h, seq, dk, dv, dtype = shape
         key = f"b{b} h{h} s{seq} k{dk} v{dv} {dtype}"
@@ -443,6 +499,8 @@ def reset_linear_attention_stats():
     _traced.clear()
     _kernel_traced.clear()
     _scan_kernel_traced.clear()
+    _conv_traced.clear()
+    _conv_kernel_traced.clear()
 
 
 def _stats_table(stats):
@@ -455,6 +513,10 @@ def _stats_table(stats):
                    f"{stats['chunks_per_sequence'][key]} chunks of "
                    f"{stats['chunk'][key]}, keeps "
                    f"{stats['state_bytes_kept'][key]} bytes of states")
+    for key in sorted(stats["conv_kernel_traces"]):
+        out.append(f"  conv {key}: {stats['conv_kernel_traces'][key]} of "
+                   f"shapes the kernels take, "
+                   f"{stats['conv_xla_traces'][key]} not")
     return out
 
 

@@ -449,6 +449,34 @@ def test_delta_rule_scan_kernels_aot(one_chip, dt):
     assert text.count("tpu_custom_call") == 3
 
 
+@pytest.mark.parametrize("dt", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_short_conv_at_the_qwen_cells_shape_aot(one_chip, dt):
+    """`causal_conv1d` at the qwen cell's shape, the packed `[q | k | v
+    | z]` (2, 8,192, 12,288) of which it convolves the first 8,192
+    columns with 4 taps: forward and backward one Mosaic call each,
+    each leading with the float32 (1, 4, 8,192) weight; the backward's
+    temporaries are dx and nothing the size of x in float32; lowered
+    for the CPU the same call holds no kernel."""
+    from mxnet_tpu.ops.linear_attention import _k_causal_conv1d
+
+    x = jax.ShapeDtypeStruct((2, 8192, 12288), dt)
+    w = jax.ShapeDtypeStruct((8192, 4), dt)
+
+    def loss(x, w):
+        return _k_causal_conv1d(x, w).astype(jnp.float32).sum()
+
+    grad = jax.value_and_grad(loss, argnums=(0, 1))
+    compiled = jax.jit(grad, in_shardings=(one_chip,) * 2).lower(x, w) \
+        .compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert _first_operands(text) == ["f32[1,4,8192]"] * 2
+    dx = 2 * 8192 * 8192 * jnp.dtype(dt).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1.01 * dx
+    assert "custom_call" not in jax.jit(grad).lower(x, w).as_text()
+
+
 def _hybrid_step_aot(topo, monkeypatch, seq=512):
     """A 4-layer hybrid `DecoderLM` (three Gated DeltaNet layers of 4
     key / 8 value heads of 128, one gated attention layer of 16 / 2
@@ -478,9 +506,11 @@ def test_hybrid_remat_step_names_its_scopes_and_kernels_aot(topo,
     `/conv/`) in forward and backward, the grouped products keep their
     phase prefix (the PR 30 lesson: a change to how layers are traced
     can leave them bare), the one attention layer is three Mosaic
-    calls, the rule six a layer, every one under `/delta_rule/`, and
-    the rule's forward runs once a layer outside the backward pass's
-    own recomputation: its output is kept by name."""
+    calls, the rule six a layer, every one under `/delta_rule/`, the
+    convolution three a layer under `/conv/` (forward, recomputed,
+    backward) and named like no attention kernel, and the rule's
+    forward runs once a layer outside the backward pass's own
+    recomputation: its output is kept by name."""
     import re
 
     text = _hybrid_step_aot(topo, monkeypatch).as_text()
@@ -506,8 +536,21 @@ def test_hybrid_remat_step_names_its_scopes_and_kernels_aot(topo,
     # the rule's six a layer: the intra-chunk pair's forward in the pass
     # and in the recomputation and its backward, the scan's forward in
     # the pass, its states' forward and its backward
-    rule = _around(linear, False)
-    assert len(rule) == 3 * 6 and all("/delta_rule/" in n for n in rule)
+    rule = [n for n in _around(linear, False) if "/delta_rule/" in n]
+    assert len(rule) == 3 * 6
+    # the convolution's three a layer, under `/conv/`: its forward in
+    # the pass and in the recomputation, its backward
+    conv = [n for n in _around(linear, False) if "/conv/" in n]
+    assert len(rule) + len(conv) == len(_around(linear, False))
+    assert sum("transpose(" not in n for n in conv) == 3
+    assert sum("rematted_computation" in n for n in conv) == 3
+    assert sum("transpose(" in n and "rematted_computation" not in n
+               for n in conv) == 3
+    # each leads with its float32 (1, taps, channels) weight: only the
+    # attention kernels' first operand is the (b x kv, group, s, d) query
+    first = dict(zip(_mosaic_op_names(text), _first_operands(text)))
+    assert {first[n] for n in conv} == {"f32[1,4,2048]"}
+    assert _first_operands(text).count("bf16[4,8,512,256]") == 3
 
 
 def _mosaic_op_names(text, scope=""):

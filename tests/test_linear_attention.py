@@ -419,38 +419,131 @@ def test_value_heads_must_be_a_multiple_of_key_heads():
         la._k_gated_delta_rule(q, k, v, g, beta)
 
 
-@pytest.mark.parametrize("dtype,tolerance", [
-    ("float32", 1e-5), ("bfloat16", 2e-2)])
-def test_causal_conv1d_matches_explicit_shifts_and_leaks_nothing(dtype,
-                                                                 tolerance):
+def _conv_kernels(monkeypatch, interpret_pallas):
+    """Route `causal_conv1d` to the TPU's branch, the kernels
+    interpreted, in blocks of 16 rows and 128 channels (several of each
+    at a test's sizes); returns the list every kernel call appends to."""
+    from mxnet_tpu.ops.pallas import short_conv
+
+    monkeypatch.setattr(jax.lax, "platform_dependent",
+                        lambda *args, tpu, default: tpu(*args))
+    monkeypatch.setattr(short_conv, "_MAX_ROWS", 16)
+    monkeypatch.setattr(short_conv, "_MAX_BLOCK_LANES", 128)
+    calls = []
+    conv = short_conv.conv
+    monkeypatch.setattr(short_conv, "conv",
+                        lambda *xs: calls.append(1) or conv(*xs))
+    return calls
+
+
+@pytest.mark.parametrize("path,dtype,tolerance", [
+    ("xla", "float32", 1e-5), ("xla", "bfloat16", 2e-2),
+    ("kernels", "float32", 1e-5), ("kernels", "bfloat16", 2e-2)],
+    ids=["float32-1e-05", "bfloat16-0.02", "kernels-float32",
+         "kernels-bfloat16"])
+def test_causal_conv1d_matches_explicit_shifts_and_leaks_nothing(
+        request, monkeypatch, path, dtype, tolerance):
     """SiLU of four explicit shifts; in bf16 the taps are summed in
     float32 and the result rounded once (0.4 %), on inputs rounded to
-    bf16 beforehand."""
+    bf16 beforehand.  The XLA form at 6 channels, and the kernels
+    (interpreted) over three row blocks and two channel blocks of a
+    packed input whose last 128 columns are not convolved, the token
+    perturbed the last of its block: its taps reach into the next."""
     import mxnet_tpu as mx
 
+    # (batch, seq, width, channels, the token perturbed)
+    b, seq, width, channels, token = (2, 12, 6, 6, 7) if path == "xla" \
+        else (2, 48, 384, 256, 15)
+    if path == "kernels":
+        calls = _conv_kernels(monkeypatch,
+                              request.getfixturevalue("interpret_pallas"))
     rng = np.random.RandomState(3)
-    x = mx.nd.array(rng.randn(2, 12, 6)).astype(dtype)
-    w = mx.nd.array(rng.randn(6, 4)).astype(dtype)
+    x = mx.nd.array(rng.randn(b, seq, width)).astype(dtype)
+    w = rng.randn(channels, 4)
+    if path == "kernels":
+        # no tap under a quarter: the perturbation shows in every one
+        # of the 256 channels' bf16 outputs
+        w = np.sign(w) * (0.25 + np.abs(w))
+    w = mx.nd.array(w).astype(dtype)
     got = mx.nd.causal_conv1d(x, w)
-    assert got.dtype == x.dtype
+    assert got.dtype == x.dtype and got.shape == (b, seq, channels)
     got = got.astype("float32").asnumpy()
     x, w = x.astype("float32").asnumpy(), w.astype("float32").asnumpy()
-    want = np.zeros_like(x)
-    for t in range(12):
+    want = np.zeros((b, seq, channels), np.float32)
+    for t in range(seq):
         for j in range(4):          # tap j reads position t - 3 + j
             if t - 3 + j >= 0:
-                want[:, t] += w[:, j] * x[:, t - 3 + j]
+                want[:, t] += w[:, j] * x[:, t - 3 + j, :channels]
     want = want / (1 + np.exp(-want))
     np.testing.assert_allclose(got, want, rtol=tolerance, atol=tolerance)
-    # nothing from t + 1 reaches t: perturb token 7, tokens 0-6 stay
+    # nothing from t + 1 reaches t: perturb one token, those before stay
     later = x.copy()
-    later[:, 7] += 10.0
+    later[:, token] += 10.0
     moved = mx.nd.causal_conv1d(
         mx.nd.array(later).astype(dtype),
         mx.nd.array(w).astype(dtype)).astype("float32").asnumpy()
-    np.testing.assert_array_equal(moved[:, :7], got[:, :7])
-    assert np.abs(moved[:, 7:11] - got[:, 7:11]).min() > 0
-    np.testing.assert_array_equal(moved[:, 11], got[:, 11])
+    np.testing.assert_array_equal(moved[:, :token], got[:, :token])
+    assert np.abs(moved[:, token:token + 4] - got[:, token:token + 4]
+                  ).min() > 0
+    np.testing.assert_array_equal(moved[:, token + 4:], got[:, token + 4:])
+    if path == "kernels":
+        assert calls
+
+
+@pytest.mark.parametrize("dtype,tolerance,dx_tolerance", [
+    ("float32", 1e-5, 1e-5), ("bfloat16", 4e-3, 2e-2)])
+def test_conv_kernels_match_the_jnp_form_interpreted(
+        monkeypatch, interpret_pallas, dtype, tolerance, dx_tolerance):
+    """The conv kernel pair against `jax.vjp` of `_conv_xla`: the value
+    and the gradients of the packed input (zeros past the channels)
+    and of the weight for random cotangents, over four row blocks and
+    two channel blocks.  float32 agrees to round-off; in bf16 the value
+    and dw agree but for a last bit (one rounding each), dx to the
+    composition's roundings: JAX rounds each tap's contribution to bf16
+    and adds them in bf16, the kernel adds in float32 and rounds once
+    (a bf16 unit is 0.4 % of the largest).  A shape the kernels refuse
+    takes the XLA form on the TPU's branch too, and is counted so."""
+    from mxnet_tpu.ops import linear_attention as la
+
+    calls = _conv_kernels(monkeypatch, interpret_pallas)
+    rng = np.random.RandomState(8)
+    x = jnp.asarray(rng.randn(2, 64, 384), dtype)
+    w = jnp.asarray(rng.randn(256, 4) * 0.5, dtype)
+    la.reset_linear_attention_stats()
+    got, got_vjp = jax.vjp(la._k_causal_conv1d, x, w)
+    want, want_vjp = jax.vjp(la._conv_xla, x, w)
+    assert calls and got.shape == want.shape == (2, 64, 256)
+    cotangent = jnp.asarray(rng.randn(*want.shape), want.dtype)
+
+    def near(which, g, w, tolerance):
+        assert g.shape == w.shape and g.dtype == w.dtype, which
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        assert float(jnp.abs(w).max()) > 0, which
+        assert float(jnp.abs(g - w).max()) <= tolerance * float(
+            jnp.abs(w).max()), which
+
+    near("y", got, want, tolerance)
+    (dx, dw), (want_dx, want_dw) = got_vjp(cotangent), want_vjp(cotangent)
+    near("dx", dx, want_dx, dx_tolerance)
+    near("dw", dw, want_dw, tolerance)
+    assert not np.asarray(dx[:, :, 256:].astype(jnp.float32)).any()
+    stats = la.linear_attention_stats()
+    key = f"b2 s64 c256 t4 {dtype}"
+    assert stats["conv_kernel_traces"] == {key: 1}
+    assert stats["conv_xla_traces"] == {key: 0}
+    # channels that fill no lane, a sequence of no whole 16-row tiles
+    for shape, weight in (((2, 64, 96), (96, 4)), ((2, 40, 256), (256, 4))):
+        x = jnp.asarray(rng.randn(*shape), dtype)
+        w = jnp.asarray(rng.randn(*weight), dtype)
+        np.testing.assert_array_equal(
+            np.asarray(la._k_causal_conv1d(x, w).astype(jnp.float32)),
+            np.asarray(la._conv_xla(x, w).astype(jnp.float32)))
+    assert len(calls) == 1
+    stats = la.linear_attention_stats()
+    assert stats["conv_xla_traces"] == {
+        key: 0, f"b2 s64 c96 t4 {dtype}": 1, f"b2 s40 c256 t4 {dtype}": 1}
+    assert stats["conv_kernel_traces"][f"b2 s40 c256 t4 {dtype}"] == 0
+    assert "conv b2 s64 c96 t4" in "\n".join(la._stats_table(stats))
 
 
 def test_the_trainers_remat_keeps_what_the_ops_name():

@@ -368,8 +368,8 @@ SECTIONS = [
      "rules_firing step_ms step_p95_ms steps stragglers ticks", "ticks",
      lambda m: m._counters.update(ticks=m._counters["ticks"] + 1)),
     ("linearAttention", "ops.linear_attention",
-     "chunk chunks_per_sequence kernel_traces layers scan_kernel_traces "
-     "state_bytes_kept traces "
+     "chunk chunks_per_sequence conv_kernel_traces conv_xla_traces "
+     "kernel_traces layers scan_kernel_traces state_bytes_kept traces "
      "xla_traces", "layers",
      lambda m: m._traced.update({(1, 2, 128, 16, 16, "bfloat16"): 1})),
     ("moeRouting", "models.decoder_lm",
