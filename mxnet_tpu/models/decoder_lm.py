@@ -1,23 +1,31 @@
 """Decoder-only language model whose layers differ in kind: full or
-sliding-window grouped-query attention with an output gate (a head or
-an element) and partial / YaRN rotary embeddings, or a Gated DeltaNet
-(linear attention with a gated delta rule); a dense SwiGLU or a
-sparse-expert feed-forward with a shared expert (gated or not); RMS
-pre-norms, an untied head and a next-token loss (the shapes of
-poolside's Laguna family and of Qwen3-Next; nothing of either is
-hard-wired but the defaults).
+sliding-window grouped-query attention with an output gate (a head, an
+element or none) and partial / YaRN rotary embeddings (or none), a
+Gated DeltaNet (linear attention with a gated delta rule), or a Mamba-2
+mixer (the SSD state-space scan); a dense SwiGLU or a sparse-expert
+feed-forward with a shared expert (gated or not; SwiGLU or relu^2
+experts under a softmax or a sigmoid router); a layer of both parts
+or of one; RMS pre-norms, an untied head and a next-token loss (the
+shapes of poolside's Laguna family, of Qwen3-Next and of Nemotron-H;
+nothing of any is hard-wired but the defaults).
 
 Everything a layer is comes from the config's own per-layer lists, in
 HF `config.json` names: `layer_types` (`full_attention` /
-`sliding_attention` / `linear_attention`),
-`num_attention_heads_per_layer`, `mlp_layer_types` (`dense` /
-`sparse`), `rope_parameters` by layer type, `sliding_window`, the
-`linear_*` sizes of the DeltaNet; and from four switches that default
-to the plain decoder: `attention_output_gate` (`per_head` /
-`elementwise`), `qk_norm`, `norm_zero_centered`, `shared_expert_gate`
-(docs/decoder_lm.md).  The expert layers are ONE chip's share of an
-expert-parallel deployment (ops/moe.py): `num_experts` experts are held
-here, ids `first_expert ...`, under a router of `router_width` outputs.
+`sliding_attention` / `linear_attention` / `mamba`, or None: no
+mixer), `num_attention_heads_per_layer`, `mlp_layer_types` (`dense` /
+`sparse`, or None: no feed-forward), `rope_parameters` by layer type
+(none for a kind: no rotation), `sliding_window`, the `linear_*` sizes
+of the DeltaNet, the `mamba_*`, `n_groups`, `ssm_state_size`,
+`chunk_size`, `conv_kernel`, `use_conv_bias` of the Mamba-2 mixer; and
+from six switches that default to the plain decoder:
+`attention_output_gate` (`per_head` / `elementwise` / None),
+`qk_norm`, `norm_zero_centered`, `shared_expert_gate`,
+`router_scoring` (`softmax` / `sigmoid`, the latter with a
+non-trainable correction bias a layer, held by the model) and
+`expert_activation` (`swiglu` / `relu2`) (docs/decoder_lm.md).  The
+expert layers are ONE chip's share of an expert-parallel deployment
+(ops/moe.py): `num_experts` experts are held here, ids `first_expert
+...`, under a router of `router_width` outputs.
 
 `forward(ids, labels)` returns the scalar loss, so the model trains as
 BERT's pre-training block does: `DataParallelTrainer(net, lambda out,
@@ -32,15 +40,17 @@ layer's input; the flash attention kernel's output and row statistic
 in bf16), so the forward kernel runs once a layer, not twice; the
 gated delta rule's output (`linear_attention.RESIDUAL_NAMES`, 134 MB
 at 2 x 8,192 tokens, 32 heads of 128), so the rule's forward runs once
-outside its own backward pass.  The rows each held expert got in the
-newest step are in `routing_log`, a non-trainable parameter
-(`routing_rows()` reads it; the profiler section `moeRouting` reads the
-live trainers' copies).
+outside its own backward pass; the SSD scan's output
+(`ssm.RESIDUAL_NAMES`, 67 MB at 8,192 tokens, 64 heads of 64), for the
+same reason.  The rows each held expert got in the newest step are in
+`routing_log`, a non-trainable parameter (`routing_rows()` reads it;
+the profiler section `moeRouting` reads the live trainers' copies).
 
 TPU notes: attention is the registry's scaled_dot_product_attention
-(the grouped Pallas flash kernels), the delta rule the chunked XLA form
-of ops/linear_attention.py, the experts one grouped product
-(`moe_ffn`); `jax.named_scope`s name each part in the device trace.
+(the grouped Pallas flash kernels), the delta rule the kernels of
+ops/pallas/delta_rule.py, the SSD the chunked `jax.numpy` form of
+ops/ssm.py, the experts one grouped product (`moe_ffn`);
+`jax.named_scope`s name each part in the device trace.
 """
 from __future__ import annotations
 
@@ -59,8 +69,10 @@ _opened_ns = 0      # the clock when the `moeRouting` section's window opened
 
 class DecoderLayer(HybridBlock):
     """Pre-norm decoder layer: x + Mixer(norm(x)), then + FFN(norm(.)).
-    The mixer is softmax attention (full or windowed) or, for
-    `linear_attention`, a Gated DeltaNet."""
+    The mixer is softmax attention (full or windowed), for
+    `linear_attention` a Gated DeltaNet, for `mamba` a Mamba-2 mixer.
+    A layer of ONE part has a None mixer (`layer_types`) or a None
+    feed-forward (`mlp_layer_types`): x + Part(norm(x)) alone."""
 
     def __init__(self, config, index, **kwargs):
         super().__init__(**kwargs)
@@ -70,10 +82,32 @@ class DecoderLayer(HybridBlock):
         # norm(x) = x_hat * (1 + w), w from 0, where the model says so
         self._zero_centered = bool(config.get("norm_zero_centered", False))
         gain = "zeros" if self._zero_centered else "ones"
-        self._sparse = config["mlp_layer_types"][index] == "sparse"
+        self._ffn = config["mlp_layer_types"][index]
+        self._sparse = self._ffn == "sparse"
         get = self.params.get
-        self.attn_norm = get("attn_norm", shape=(h,), init=gain)
-        if self._kind == "linear_attention":
+        if self._kind is not None:
+            self.attn_norm = get("attn_norm", shape=(h,), init=gain)
+        if self._kind == "mamba":
+            heads, size = config["mamba_num_heads"], config["mamba_head_dim"]
+            groups, state = config["n_groups"], config["ssm_state_size"]
+            self._mamba_dims = heads, size, groups, state
+            self._chunk = config["chunk_size"]
+            inner, conv = heads * size, heads * size + 2 * groups * state
+            # [x | B | C | z], the convolution's channels first, and dt
+            self.in_proj_weight = get(
+                "in_proj_weight", shape=(conv + inner, h))
+            self.dt_weight = get("dt_weight", shape=(heads, h))
+            self.conv_weight = get(
+                "conv_weight", shape=(conv, config["conv_kernel"]))
+            if config.get("use_conv_bias", False):
+                self.conv_bias = get("conv_bias", shape=(conv,),
+                                     init="zeros")
+            self.a_log = get("a_log", shape=(heads,), init="zeros")
+            self.d = get("d", shape=(heads,), init="ones")
+            self.dt_bias = get("dt_bias", shape=(heads,), init="zeros")
+            self.mamba_norm = get("mamba_norm", shape=(inner,), init="ones")
+            self.out_weight = get("out_weight", shape=(h, inner))
+        elif self._kind == "linear_attention":
             hk, hv = (config["linear_num_key_heads"],
                       config["linear_num_value_heads"])
             dk, dv = (config["linear_key_head_dim"],
@@ -90,7 +124,7 @@ class DecoderLayer(HybridBlock):
             self.dt_bias = get("dt_bias", shape=(hv,), init="ones")
             self.o_norm = get("o_norm", shape=(dv,), init="ones")
             self.out_weight = get("out_weight", shape=(h, hv * dv))
-        else:
+        elif self._kind is not None:
             per_layer = config.get("num_attention_heads_per_layer")
             self._heads = per_layer[index] if per_layer \
                 else config["num_attention_heads"]
@@ -98,18 +132,22 @@ class DecoderLayer(HybridBlock):
             self._head_dim = d
             self._window = config["sliding_window"] \
                 if self._kind == "sliding_attention" else None
-            rope = dict(config["rope_parameters"][self._kind])
-            rotary_dim = int(d * rope.pop("partial_rotary_factor", 1.0))
-            self._inv_freq, self._attention_factor = rotary_frequencies(
-                rotary_dim, **rope)
+            # no rotary embedding where the kind has no rope parameters
+            rope = config.get("rope_parameters", {}).get(self._kind)
+            self._inv_freq, self._attention_factor = None, 1.0
+            if rope is not None:
+                rope = dict(rope)
+                rotary_dim = int(d * rope.pop("partial_rotary_factor", 1.0))
+                self._inv_freq, self._attention_factor = rotary_frequencies(
+                    rotary_dim, **rope)
             # the output gate: one sigmoid a head from a projection of
-            # its own (`per_head`), or one an element from the doubled
-            # q projection, [q | gate] (`elementwise`)
+            # its own (`per_head`), one an element from the doubled q
+            # projection, [q | gate] (`elementwise`), or none (None)
             self._gate = config.get("attention_output_gate", "per_head")
-            if self._gate not in ("per_head", "elementwise"):
+            if self._gate not in ("per_head", "elementwise", None):
                 raise ValueError(
                     f"attention_output_gate {self._gate!r}: per_head or "
-                    "elementwise")
+                    "elementwise (or None: no gate)")
             self._qk_norm = bool(config.get("qk_norm", False))
             n, kv = self._heads, self._kv_heads
             doubled = 2 if self._gate == "elementwise" else 1
@@ -122,7 +160,8 @@ class DecoderLayer(HybridBlock):
                 self.q_norm = get("q_norm", shape=(d,), init=gain)
                 self.k_norm = get("k_norm", shape=(d,), init=gain)
             self.out_weight = get("out_weight", shape=(h, n * d))
-        self.ffn_norm = get("ffn_norm", shape=(h,), init=gain)
+        if self._ffn is not None:
+            self.ffn_norm = get("ffn_norm", shape=(h,), init=gain)
         if self._sparse:
             width = config["moe_intermediate_size"]
             shared = config["shared_expert_intermediate_size"]
@@ -131,20 +170,24 @@ class DecoderLayer(HybridBlock):
             self._scale = config.get("moe_routed_scaling_factor", 1.0)
             self._first_expert = config.get("first_expert", 0)
             self._shared_gate = bool(config.get("shared_expert_gate", False))
+            # `swiglu` ([gate | up] packed) or `relu2` (no gate)
+            self._activation = config.get("expert_activation", "swiglu")
+            self._scoring = config.get("router_scoring", "softmax")
+            rows = 2 if self._activation == "swiglu" else 1
             self.router_weight = get(
                 "router_weight", shape=(h, config.get("router_width", held)))
             self.expert_in_weight = get(
-                "expert_in_weight", shape=(held, h, 2 * width))
+                "expert_in_weight", shape=(held, h, rows * width))
             self.expert_out_weight = get(
                 "expert_out_weight", shape=(held, width, h))
             self.shared_in_weight = get(
-                "shared_in_weight", shape=(2 * shared, h))
+                "shared_in_weight", shape=(rows * shared, h))
             self.shared_out_weight = get(
                 "shared_out_weight", shape=(h, shared))
             if self._shared_gate:
                 self.shared_gate_weight = get(
                     "shared_gate_weight", shape=(1, h))
-        else:
+        elif self._ffn is not None:
             width = config["intermediate_size"]
             self.ffn_in_weight = get("ffn_in_weight", shape=(2 * width, h))
             self.ffn_out_weight = get("ffn_out_weight", shape=(h, width))
@@ -160,6 +203,12 @@ class DecoderLayer(HybridBlock):
 
     def _swiglu_ffn(self, F, u, w_in, w_out):
         return self._linear(F, F.swiglu(self._linear(F, u, w_in)), w_out)
+
+    def _shared_ffn(self, F, u, w_in, w_out):
+        if self._activation == "swiglu":
+            return self._swiglu_ffn(F, u, w_in, w_out)
+        return self._linear(F, F.squared_relu(self._linear(F, u, w_in)),
+                            w_out)
 
     def _attention(self, F, u, p):
         n, kv, d = self._heads, self._kv_heads, self._head_dim
@@ -181,6 +230,10 @@ class DecoderLayer(HybridBlock):
             v = F.split_heads(v, heads=kv, head_dim=d)
         att = F.scaled_dot_product_attention(
             q_heads, k, v, causal=True, window=self._window)
+        if self._gate is None:
+            b, _, s, _ = att.shape
+            return self._linear(F, att.transpose((0, 2, 1, 3)).reshape(
+                (b, s, n * d)), p["out_weight"])
         if self._gate == "per_head":
             gate, column = self._linear(F, u, p["gate_weight"]), 0
         else:
@@ -225,31 +278,63 @@ class DecoderLayer(HybridBlock):
                               groups=groups)
         return self._linear(F, o, p["out_weight"])
 
-    def hybrid_forward(self, F, x, **p):
-        if self._kind == "linear_attention":
-            scope, mixer = "linear_attention", self._linear_attention
-        else:
-            scope = "attention_window" if self._window else "attention_full"
-            mixer = self._attention
-        with jax.named_scope(scope):
-            a = x + mixer(F, self._norm(F, x, p["attn_norm"]), p)
-        u = self._norm(F, a, p["ffn_norm"])
+    def _mamba(self, F, u, p):
+        """Mamba-2: [x | B | C] through a short causal convolution with
+        a bias and SiLU, the step size dt = softplus(. + dt_bias) and
+        the decay A = -exp(A_log) a head, the SSD scan with its skip D,
+        then rms_norm(y * silu(z)) over each group's heads times the
+        gain, and the output projection."""
+        heads, size, groups, state = self._mamba_dims
+        inner, conv = heads * size, heads * size + 2 * groups * state
+        b, s, _ = u.shape
+        mixed = self._linear(F, u, p["in_proj_weight"])
+        xbc = F.causal_conv1d(mixed, p["conv_weight"], p.get("conv_bias"))
+        x = xbc[:, :, :inner].reshape((b, s, heads, size))
+        bc = xbc[:, :, inner:].reshape((b, s, 2 * groups, state))
+        dt = F.softrelu(self._linear(F, u, p["dt_weight"]).astype("float32")
+                        + p["dt_bias"].astype("float32"))
+        y = F.ssd(x, dt, -F.exp(p["a_log"].astype("float32")),
+                  bc[:, :, :groups], bc[:, :, groups:],
+                  p["d"].astype("float32"), chunk=self._chunk)
+        y = F.gated_rms_norm(y.reshape((b, s, inner)),
+                             mixed[:, :, conv:conv + inner], p["mamba_norm"],
+                             eps=self._eps, group_size=inner // groups,
+                             gate_first=True)
+        return self._linear(F, y, p["out_weight"])
+
+    def hybrid_forward(self, F, x, correction_bias=None, **p):
+        if self._kind is not None:
+            if self._kind == "mamba":
+                scope, mixer = "mamba", self._mamba
+            elif self._kind == "linear_attention":
+                scope, mixer = "linear_attention", self._linear_attention
+            else:
+                scope = "attention_window" if self._window \
+                    else "attention_full"
+                mixer = self._attention
+            with jax.named_scope(scope):
+                x = x + mixer(F, self._norm(F, x, p["attn_norm"]), p)
+        if self._ffn is None:
+            return x
+        u = self._norm(F, x, p["ffn_norm"])
         if not self._sparse:
             with jax.named_scope("dense_ffn"):
-                return a + self._swiglu_ffn(F, u, p["ffn_in_weight"],
+                return x + self._swiglu_ffn(F, u, p["ffn_in_weight"],
                                             p["ffn_out_weight"])
         routed, rows = F.moe_ffn(
             u, p["router_weight"], p["expert_in_weight"],
-            p["expert_out_weight"], first_expert=self._first_expert,
-            top_k=self._top_k, scale=self._scale)
+            p["expert_out_weight"], correction_bias,
+            first_expert=self._first_expert, top_k=self._top_k,
+            scale=self._scale, scoring=self._scoring,
+            activation=self._activation)
         with jax.named_scope("shared_expert"):
-            shared = self._swiglu_ffn(F, u, p["shared_in_weight"],
+            shared = self._shared_ffn(F, u, p["shared_in_weight"],
                                       p["shared_out_weight"])
             if self._shared_gate:
                 shared = shared * F.sigmoid(self._linear(
                     F, u, p["shared_gate_weight"]).astype("float32")) \
                     .astype(shared.dtype)
-        return a + shared + routed, rows
+        return x + shared + routed, rows
 
 
 class LMHead(HybridBlock):
@@ -292,6 +377,17 @@ class DecoderLM(HybridBlock):
             self.routing_log = self.params.get(
                 "routing_log", grad_req="null", init="zeros",
                 shape=(len(self._sparse_layers), config["num_experts"] + 1))
+        # a sigmoid router's choice bias, a row an expert layer: not
+        # trained by the gradient, held HERE so that the layers hold
+        # trainable parameters alone (the trainer's `remat` recomputes
+        # only such children)
+        self._biased = self._sparse_layers and config.get(
+            "router_scoring", "softmax") == "sigmoid"
+        if self._biased:
+            self.correction_bias = self.params.get(
+                "correction_bias", grad_req="null", init="zeros",
+                shape=(len(self._sparse_layers),
+                       config.get("router_width", config["num_experts"])))
         self.embed = self.params.get(
             "embed", shape=(config["vocab_size"], config["hidden_size"]))
         self.layers = []
@@ -301,14 +397,16 @@ class DecoderLM(HybridBlock):
             self.layers.append(layer)
         self.head = LMHead(config)
 
-    def hybrid_forward(self, F, ids, labels, embed, routing_log=None):
+    def hybrid_forward(self, F, ids, labels, embed, routing_log=None,
+                       correction_bias=None):
         x = F.Embedding(ids, embed, input_dim=embed.shape[0],
                         output_dim=embed.shape[1])
         for i, layer in enumerate(self.layers):
             if i in self._sparse_layers:
-                x, rows = layer(x)
-                F.moe_routing_log(rows, routing_log,
-                                  layer=self._sparse_layers.index(i))
+                row = self._sparse_layers.index(i)
+                x, rows = layer(x, correction_bias[row]) \
+                    if self._biased else layer(x)
+                F.moe_routing_log(rows, routing_log, layer=row)
             else:
                 x = layer(x)
         return self.head(x, labels)

@@ -9,5 +9,6 @@ from . import rnn  # noqa: F401
 from . import attention  # noqa: F401
 from . import moe  # noqa: F401
 from . import linear_attention  # noqa: F401
+from . import ssm  # noqa: F401
 from . import quantization  # noqa: F401
 from .registry import get, list_ops, register  # noqa: F401

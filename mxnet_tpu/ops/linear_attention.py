@@ -162,7 +162,7 @@ def _k_l2_norm(data, *, eps=1e-6):
 register("l2_norm", _k_l2_norm, arg_names=("data",))
 
 
-def _conv_xla(data, weight):
+def _conv_xla(data, weight, bias=None):
     """The convolution as shifted multiply-adds over a padded copy,
     which JAX differentiates: the off-TPU path and the kernels'
     oracle."""
@@ -171,18 +171,21 @@ def _conv_xla(data, weight):
     w = weight.astype(jnp.float32)
     y = sum(x[:, j:j + seq].astype(jnp.float32) * w[:, j]
             for j in range(taps))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return jax.nn.silu(y).astype(data.dtype)
 
 
-def _k_causal_conv1d(data, weight):
+def _k_causal_conv1d(data, weight, bias=None):
     """SiLU of the depthwise causal convolution along the sequence of
     (batch, seq, width) over its first `channels` columns (all of them,
     or the `[q | k | v]` of a packed `[q | k | v | z]`): y_t =
-    silu(sum_j weight[:, j] * x_(t - (taps - 1) + j)), zeros before
-    the sequence's start, no bias; weight (channels, taps), the last tap
-    on x_t itself; returns (batch, seq, channels) in data's dtype.  The
-    taps are multiplied and added in float32 in tap order, SiLU in
-    float32, one rounding at the end.
+    silu(sum_j weight[:, j] * x_(t - (taps - 1) + j) + bias), zeros
+    before the sequence's start, no bias where `bias` is None; weight
+    (channels, taps), the last tap on x_t itself, bias (channels,);
+    returns (batch, seq, channels) in data's dtype.  The taps are
+    multiplied and added in float32 in tap order, the bias after them,
+    SiLU in float32, one rounding at the end.
 
     Where the computation is lowered for the TPU and the shapes allow
     it (channels of whole lanes, at most 8 taps, a sequence of whole
@@ -197,20 +200,32 @@ def _k_causal_conv1d(data, weight):
     _conv_traced[key] += 1
     with jax.named_scope("conv"):
         if not short_conv.admits(data.shape, weight.shape, data.dtype):
-            return _conv_xla(data, weight)
+            return _conv_xla(data, weight, bias)
         _conv_kernel_traced[key] += 1
+        if bias is None:
+            def kernels(data, weight):
+                from ..parallel.mesh import per_batch_shard
 
-        def kernels(data, weight):
+                return per_batch_shard(
+                    short_conv.conv, weight.astype(jnp.float32).T[None], data)
+
+            return jax.lax.platform_dependent(data, weight, tpu=kernels,
+                                              default=_conv_xla)
+
+        def biased(data, weight, bias):
             from ..parallel.mesh import per_batch_shard
 
-            return per_batch_shard(
-                short_conv.conv, weight.astype(jnp.float32).T[None], data)
+            # the bias as the weight's last row
+            rows = jnp.concatenate([weight.T, bias[None]]).astype(
+                jnp.float32)
+            return per_batch_shard(short_conv.conv_biased, rows[None], data)
 
-        return jax.lax.platform_dependent(data, weight, tpu=kernels,
+        return jax.lax.platform_dependent(data, weight, bias, tpu=biased,
                                           default=_conv_xla)
 
 
-register("causal_conv1d", _k_causal_conv1d, arg_names=("data", "weight"))
+register("causal_conv1d", _k_causal_conv1d,
+         arg_names=("data", "weight", "bias"))
 
 
 def _exact(x, y):

@@ -170,7 +170,7 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _experts_at(capacity, routing, x, expert_in_weight, expert_out_weight,
-                gates, *, top_k):
+                gates, *, top_k, activation="swiglu"):
     """The held experts' part of the layer on the first `capacity`
     sorted rows: they hold every live row (`sum(sizes) <= capacity`)."""
     order, inverse, here, sizes = routing
@@ -182,8 +182,12 @@ def _experts_at(capacity, routing, x, expert_in_weight, expert_out_weight,
         rows = _dispatch(x, plan, top_k)
     with jax.named_scope("experts"):
         hidden = jax.lax.ragged_dot(rows, expert_in_weight, sizes)
-        gate, up = jnp.split(hidden.astype(jnp.float32), 2, axis=-1)
-        act = (jax.nn.silu(gate) * up).astype(x.dtype)
+        if activation == "relu2":
+            act = jnp.square(jax.nn.relu(hidden.astype(jnp.float32))) \
+                .astype(x.dtype)
+        else:
+            gate, up = jnp.split(hidden.astype(jnp.float32), 2, axis=-1)
+            act = (jax.nn.silu(gate) * up).astype(x.dtype)
         out = jax.lax.ragged_dot(act, expert_out_weight, sizes)
     with jax.named_scope("combine"):
         by_row = gates[order[:capacity]]
@@ -201,26 +205,31 @@ def _switch(caps, routing, branch, *operands):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _experts(caps, top_k, routing, *operands):
-    """`_experts_at` the capacity the routing asks for.  Its backward
-    pass keeps the operands alone and differentiates the chosen branch
-    inside a switch of its own: a differentiated `switch` would keep
-    every branch's residuals side by side (zeros where not taken), the
-    top capacity's among them."""
-    return _switch(caps, routing, functools.partial(_experts_at, top_k=top_k),
-                   routing, *operands)
+def _experts(caps, how, routing, *operands):
+    """`_experts_at` the capacity the routing asks for; `how` is its
+    keywords, (top_k, activation).  Its backward pass keeps the
+    operands alone and differentiates the chosen branch inside a switch
+    of its own: a differentiated `switch` would keep every branch's
+    residuals side by side (zeros where not taken), the top capacity's
+    among them."""
+    return _switch(caps, routing, _at(how), routing, *operands)
 
 
-def _experts_fwd(caps, top_k, routing, *operands):
-    return _experts(caps, top_k, routing, *operands), (routing, operands)
+def _at(how):
+    top_k, activation = how
+    return functools.partial(_experts_at, top_k=top_k, activation=activation)
 
 
-def _experts_bwd(caps, top_k, res, g):
+def _experts_fwd(caps, how, routing, *operands):
+    return _experts(caps, how, routing, *operands), (routing, operands)
+
+
+def _experts_bwd(caps, how, res, g):
     routing, operands = res
 
     def back(capacity, operands, g):
-        return jax.vjp(functools.partial(_experts_at, capacity, routing,
-                                         top_k=top_k), *operands)[1](g)
+        return jax.vjp(functools.partial(_at(how), capacity, routing),
+                       *operands)[1](g)
 
     return None, *_switch(caps, routing, back, operands, g)
 
@@ -228,18 +237,29 @@ def _experts_bwd(caps, top_k, res, g):
 _experts.defvjp(_experts_fwd, _experts_bwd)
 
 
-def _k_moe_ffn(data, router_weight, expert_in_weight, expert_out_weight, *,
-               first_expert=0, top_k=8, scale=1.0):
+def _k_moe_ffn(data, router_weight, expert_in_weight, expert_out_weight,
+               correction_bias=None, *, first_expert=0, top_k=8, scale=1.0,
+               scoring="softmax", activation="swiglu"):
     """data (..., h); router_weight (h, experts of the whole layer);
-    expert_in_weight (held, h, 2 * width) packing [gate | up];
-    expert_out_weight (held, width, h).  Returns (the held experts'
+    expert_in_weight (held, h, 2 * width) packing [gate | up], or
+    (held, h, width) under `activation="relu2"`; expert_out_weight
+    (held, width, h); correction_bias (experts of the whole layer,),
+    read under `scoring="sigmoid"` alone.  Returns (the held experts'
     part of the layer's output, in data's shape; float32 (held + 1,)
     rows each held expert got, then the assignments that went to
     absent experts).
 
-    Router: logits, softmax and the top_k weights in float32, the
-    weights renormalised over the top_k and times `scale`, applied to
-    the experts' OUTPUT.  Experts: SwiGLU."""
+    Router: logits and the top_k weights in float32.  `softmax`: the
+    top_k of the softmax, renormalised over the top_k.  `sigmoid`: the
+    top_k of sigmoid(logits) + correction_bias (the bias chooses and
+    takes no gradient), their sigmoid scores renormalised over the
+    top_k (1e-20 added to the sum, as DeepSeek-V3's router).  Either
+    way times `scale`, applied to the experts' OUTPUT.  Experts:
+    SwiGLU, or relu(x W_in)^2 W_out (`relu2`, no gate)."""
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"moe_ffn: scoring {scoring!r}")
+    if activation not in ("swiglu", "relu2"):
+        raise ValueError(f"moe_ffn: activation {activation!r}")
     shape = data.shape
     x = data.reshape(-1, shape[-1])
     held = expert_in_weight.shape[0]
@@ -248,9 +268,20 @@ def _k_moe_ffn(data, router_weight, expert_in_weight, expert_out_weight, *,
             logits = jnp.dot(x.astype(jnp.float32),
                              router_weight.astype(jnp.float32),
                              precision="highest")
-            weights, experts = jax.lax.top_k(
-                jax.nn.softmax(logits, axis=-1), top_k)
-            weights = weights / jnp.sum(weights, -1, keepdims=True) * scale
+            if scoring == "softmax":
+                weights, experts = jax.lax.top_k(
+                    jax.nn.softmax(logits, axis=-1), top_k)
+                weights = weights / jnp.sum(weights, -1, keepdims=True) \
+                    * scale
+            else:
+                scores = jax.nn.sigmoid(logits)
+                choice = scores if correction_bias is None else \
+                    scores + jax.lax.stop_gradient(
+                        correction_bias.astype(jnp.float32))
+                experts = jax.lax.top_k(choice, top_k)[1]
+                weights = jnp.take_along_axis(scores, experts, -1)
+                weights = weights / (jnp.sum(weights, -1, keepdims=True)
+                                     + 1e-20) * scale
         with jax.named_scope("dispatch"):
             local = experts - first_expert
             here = (local >= 0) & (local < held)
@@ -264,7 +295,8 @@ def _k_moe_ffn(data, router_weight, expert_in_weight, expert_out_weight, *,
         # a share that holds the whole router has every row live
         caps = capacities(order.shape[0])[
             -1 if held >= router_weight.shape[-1] else 0:]
-        y = _experts(caps, top_k, (order, inverse, here, sizes[:held]), x,
+        y = _experts(caps, (top_k, activation),
+                     (order, inverse, here, sizes[:held]), x,
                      expert_in_weight, expert_out_weight,
                      jnp.where(here, weights, 0.0).reshape(-1))
     return (y.reshape(shape),
@@ -273,7 +305,7 @@ def _k_moe_ffn(data, router_weight, expert_in_weight, expert_out_weight, *,
 
 register("moe_ffn", _k_moe_ffn,
          arg_names=("data", "router_weight", "expert_in_weight",
-                    "expert_out_weight"), num_outputs=2)
+                    "expert_out_weight", "correction_bias"), num_outputs=2)
 
 
 def _k_moe_routing_log(rows, log, *, layer=0):

@@ -784,11 +784,11 @@ register("SVMOutput", _k_svm_output, arg_names=("data", "label"),
 
 
 # ---------------------------------------------------------------------------
-# decoder building blocks: RMS norm, rotary position embedding, SwiGLU
-# (each its own oracle: the XLA form below is their only form), and the
-# two ops that lay a mixer's heads out around its kernel, `split_heads`
-# and `merge_heads`, whose XLA form composes the others and is the
-# oracle of the kernels in ops/pallas/head_layout.py.
+# decoder building blocks: RMS norm, rotary position embedding, SwiGLU,
+# squared ReLU (each its own oracle: the XLA form below is their only
+# form), and the two ops that lay a mixer's heads out around its
+# kernel, `split_heads` and `merge_heads`, whose XLA form composes the
+# others and is the oracle of the kernels in ops/pallas/head_layout.py.
 
 
 def _rms_normed(data, eps):
@@ -813,11 +813,22 @@ register("rms_norm", _k_rms_norm, arg_names=("data", "gamma"),
          aliases=("RMSNorm",))
 
 
-def _k_gated_rms_norm(data, gate, gamma, *, eps=1e-6):
+def _k_gated_rms_norm(data, gate, gamma, *, eps=1e-6, group_size=0,
+                      gate_first=False):
     """rms_norm(data) * gamma * silu(gate) over the last axis, in
-    float32; `gate` has data's shape.  The result in data's dtype."""
-    return (_rms_normed(data, eps) * gamma.astype(jnp.float32)
-            * jax.nn.silu(gate.astype(jnp.float32))).astype(data.dtype)
+    float32; `gate` has data's shape.  The result in data's dtype.
+    `gate_first`: rms_norm(data * silu(gate)) * gamma instead (a Mamba-2
+    mixer's norm); `group_size`: the statistics over each group of that
+    many channels of the last axis, not over the whole of it."""
+    z = jax.nn.silu(gate.astype(jnp.float32))
+    x = data.astype(jnp.float32) * z if gate_first else data
+    shape = data.shape
+    if group_size:
+        x = x.reshape(shape[:-1] + (shape[-1] // group_size, group_size))
+    out = _rms_normed(x, eps).reshape(shape) * gamma.astype(jnp.float32)
+    if not gate_first:
+        out = out * z
+    return out.astype(data.dtype)
 
 
 register("gated_rms_norm", _k_gated_rms_norm,
@@ -916,6 +927,15 @@ def _k_swiglu(data):
 
 
 register("swiglu", _k_swiglu, arg_names=("data",))
+
+
+def _k_squared_relu(data):
+    """relu(x)^2, in float32."""
+    x = jnp.maximum(data.astype(jnp.float32), 0.0)
+    return (x * x).astype(data.dtype)
+
+
+register("squared_relu", _k_squared_relu, arg_names=("data",))
 
 
 # ---------------------------------------------------------------------------

@@ -7,11 +7,13 @@ mathematics, the roundings, the XLA form and the oracle), with a
 first `channels` columns are convolved (the `[q | k | v]` of a packed
 `[q | k | v | z]`: the BlockSpec reads them where they lie, so no
 sliced copy exists); weight is (1, taps, channels) float32, tap j on
-x_(t - (taps - 1) + j).  The grid is (batch, channel block, row block).
-A grid step reads `rows` tokens of `lanes` channels, and the
-`_HALO` rows before them through a second BlockSpec on the same
-operand (zero before the sequence's start), and works through its
-channels 128 lanes at a time: the rows go to a float32 VMEM buffer
+x_(t - (taps - 1) + j).  `conv_biased(weight, x)`: the same with a bias
+added before SiLU, the weight (1, taps + 1, channels) with the bias as
+its last row (a Mamba-2 mixer's convolution).  The grid is (batch,
+channel block, row block).  A grid step reads `rows` tokens of `lanes`
+channels, and the `_HALO` rows before them through a second BlockSpec
+on the same operand (zero before the sequence's start), and works
+through its channels 128 lanes at a time: the rows go to a float32 VMEM buffer
 behind the halo, each tap is a read of that buffer shifted by its
 offset, the taps are multiplied and added in float32 in tap order,
 SiLU in float32, one rounding to x's dtype.
@@ -23,10 +25,12 @@ more, g = dy * silu'(y) in float32, dx_t = sum_j w_j g_(t + taps - 1
 - j) summed in float32 and rounded once, and dw_j = sum of x_(t - taps
 + 1 + j) g_t in float32: (8, 128) partial sums a tap that stay in the
 output block across the row blocks (the row axis "arbitrary") and are
-added up outside over the batch and the eight rows.  Nothing the size
-of x is kept or written but dx: the residuals are the inputs.  Every
-call's FIRST operand is the float32 (1, taps, channels) weight (the
-benchmark's readers name kernels by their first operand's shape).
+added up outside over the batch and the eight rows; the bias's
+gradient, where there is one, is the sum of g, kept the same way.
+Nothing the size of x is kept or written but dx: the residuals are the
+inputs.  Every call's FIRST operand is the float32 (1, taps, channels)
+weight, (1, taps + 1, channels) with a bias (the benchmark's readers
+name kernels by their first operand's shape).
 """
 from __future__ import annotations
 
@@ -99,7 +103,8 @@ def _silu_grad(y):
     return s * (1.0 + y * (1.0 - s))
 
 
-def _forward_kernel(w_ref, x_ref, before_ref, o_ref, ext_ref, *, seq):
+def _forward_kernel(w_ref, x_ref, before_ref, o_ref, ext_ref, *, seq,
+                    biased=False):
     rows = x_ref.shape[0]
     first = pl.program_id(2) * rows
 
@@ -109,14 +114,19 @@ def _forward_kernel(w_ref, x_ref, before_ref, o_ref, ext_ref, *, seq):
         ext_ref[:_HALO] = jnp.where(
             _rows_valid(before.shape, first - _HALO, seq), before, 0.0)
         ext_ref[_HALO:] = x_ref[:, lanes].astype(jnp.float32)
-        y = _taps(ext_ref, w_ref[:, lanes], rows)
+        if biased:
+            w = w_ref[:, lanes]
+            y = _taps(ext_ref, w[:-1], rows) + w[-1:]
+        else:
+            y = _taps(ext_ref, w_ref[:, lanes], rows)
         o_ref[:, lanes] = (y * jax.nn.sigmoid(y)).astype(o_ref.dtype)
 
     jax.lax.fori_loop(0, x_ref.shape[1] // _LANES, one, None)
 
 
 def _backward_kernel(w_ref, x_ref, before_ref, after_ref, dy_ref,
-                     dy_after_ref, dx_ref, dw_ref, ext_ref, g_ref, *, seq):
+                     dy_after_ref, dx_ref, dw_ref, ext_ref, g_ref, *, seq,
+                     biased=False):
     rows = x_ref.shape[0]
     r = pl.program_id(2)
     first = r * rows
@@ -128,6 +138,8 @@ def _backward_kernel(w_ref, x_ref, before_ref, after_ref, dy_ref,
     def one(c, _):
         lanes = _lanes(c)
         w = w_ref[:, lanes]
+        if biased:
+            w, bias = w[:-1], w[-1:]
         taps = w.shape[0]
         before = before_ref[:, lanes].astype(jnp.float32)
         after = after_ref[:, lanes].astype(jnp.float32)
@@ -138,6 +150,8 @@ def _backward_kernel(w_ref, x_ref, before_ref, after_ref, dy_ref,
             _rows_valid(after.shape, first + rows, seq), after, 0.0)
         # y of the block's rows and of the `_TAIL` after them
         y = _taps(ext_ref, w, rows + _TAIL)
+        if biased:
+            y = y + bias
         d = _silu_grad(y)
         g = dy_ref[:, lanes].astype(jnp.float32) * d[:rows]
         g_ref[:rows] = g
@@ -152,11 +166,15 @@ def _backward_kernel(w_ref, x_ref, before_ref, after_ref, dy_ref,
         for j in range(taps):
             x_j = ext_ref[pl.ds(_HALO - taps + 1 + j, rows)]
             dw_ref[j, :, lanes] += (x_j * g).reshape(-1, 8, _LANES).sum(0)
+        if biased:
+            dw_ref[taps, :, lanes] += g.reshape(-1, 8, _LANES).sum(0)
 
     jax.lax.fori_loop(0, x_ref.shape[1] // _LANES, one, None)
 
 
 def _shapes(weight, x):
+    """`taps`: the weight's rows, the bias's among them where there is
+    one."""
     b, seq, _ = x.shape
     _, taps, channels = weight.shape
     rows = _largest(_MAX_ROWS, _HALO, seq)
@@ -186,11 +204,11 @@ def _weight_spec(taps, lanes):
                         memory_space=pltpu.VMEM)
 
 
-def _forward(weight, x):
+def _forward(weight, x, biased=False):
     b, seq, taps, channels, rows, lanes = _shapes(weight, x)
     block, before, _ = _specs(seq, rows, lanes)
     return pallas_call(
-        functools.partial(_forward_kernel, seq=seq),
+        functools.partial(_forward_kernel, seq=seq, biased=biased),
         grid=(b, channels // lanes, seq // rows),
         in_specs=[_weight_spec(taps, lanes), block, before],
         out_specs=block,
@@ -201,11 +219,11 @@ def _forward(weight, x):
             vmem_limit_bytes=_VMEM_LIMIT))(weight, x, x)
 
 
-def _backward(weight, x, dy):
+def _backward(weight, x, dy, biased=False):
     b, seq, taps, channels, rows, lanes = _shapes(weight, x)
     block, before, after = _specs(seq, rows, lanes)
     dx, dw = pallas_call(
-        functools.partial(_backward_kernel, seq=seq),
+        functools.partial(_backward_kernel, seq=seq, biased=biased),
         grid=(b, channels // lanes, seq // rows),
         in_specs=[_weight_spec(taps, lanes), block, before, after, block,
                   after],
@@ -237,3 +255,15 @@ def conv(weight, x):
 
 conv.defvjp(lambda weight, x: (_forward(weight, x), (weight, x)),
             lambda kept, dy: _backward(*kept, dy))
+
+
+@jax.custom_vjp
+def conv_biased(weight, x):
+    """`conv` with a bias added before SiLU: weight (1, taps + 1,
+    channels) float32, the bias its last row."""
+    return _forward(weight, x, biased=True)
+
+
+conv_biased.defvjp(
+    lambda weight, x: (_forward(weight, x, biased=True), (weight, x)),
+    lambda kept, dy: _backward(*kept, dy, biased=True))

@@ -29,6 +29,7 @@ _ops = {}
 RESIDUAL_NAMES = {
     "flash_attention": ("flash_out", "flash_lse"),
     "gated_delta_rule": ("delta_rule_out",),
+    "ssd": ("ssd_out",),
 }
 
 

@@ -136,8 +136,8 @@ _profiler.register_section(
 def _remat_saves():
     """The names `remat=True` keeps across a checkpoint: every output
     an op names for it (`ops.registry.RESIDUAL_NAMES`: the flash
-    kernels' output and row statistic, the gated delta rule's
-    output)."""
+    kernels' output and row statistic, the gated delta rule's output,
+    the SSD scan's output)."""
     from ..ops import registry
 
     return tuple(name for names in registry.RESIDUAL_NAMES.values()

@@ -477,6 +477,34 @@ def test_short_conv_at_the_qwen_cells_shape_aot(one_chip, dt):
     assert "custom_call" not in jax.jit(grad).lower(x, w).as_text()
 
 
+@pytest.mark.parametrize("dt", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_biased_short_conv_at_the_nemotron_cells_shape_aot(one_chip, dt):
+    """`causal_conv1d` with a bias at the nemotron cell's shape, the
+    packed `[x | B | C | z]` (1, 8,192, 10,240) of which it convolves
+    the first 6,144 columns with 4 taps: forward and backward one Mosaic
+    call each, each leading with the float32 (1, 5, 6,144) weight whose
+    last row is the bias; the backward's temporaries are dx and little
+    more."""
+    from mxnet_tpu.ops.linear_attention import _k_causal_conv1d
+
+    x = jax.ShapeDtypeStruct((1, 8192, 10240), dt)
+    w = jax.ShapeDtypeStruct((6144, 4), dt)
+    b = jax.ShapeDtypeStruct((6144,), dt)
+
+    def loss(x, w, b):
+        return _k_causal_conv1d(x, w, b).astype(jnp.float32).sum()
+
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    compiled = jax.jit(grad, in_shardings=(one_chip,) * 3).lower(x, w, b) \
+        .compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert _first_operands(text) == ["f32[1,5,6144]"] * 2
+    dx = 8192 * 10240 * jnp.dtype(dt).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1.01 * dx
+
+
 def _hybrid_step_aot(topo, monkeypatch, seq=512):
     """A 4-layer hybrid `DecoderLM` (three Gated DeltaNet layers of 4
     key / 8 value heads of 128, one gated attention layer of 16 / 2

@@ -207,6 +207,67 @@ def test_hybrid_model_matches_reference_loss_and_every_gradient(
     assert sorted(rows) == [0, 1, 2, 3]
 
 
+# Nemotron-H at a small size, in the PUBLISHED key names of
+# NVIDIA-Nemotron-3-Nano-30B-A3B's config.json: layers of one part
+# (Mamba-2, expert layer, NoPE attention) by `hybrid_override_pattern`
+SMALL_NEMOTRON = dict(
+    vocab_size=128, hidden_size=64, head_dim=16, num_attention_heads=4,
+    num_key_value_heads=2, mamba_num_heads=4, mamba_head_dim=16,
+    n_groups=2, ssm_state_size=16, chunk_size=32, conv_kernel=4,
+    use_conv_bias=True, num_hidden_layers=5, hybrid_override_pattern="MEM*E",
+    moe_intermediate_size=32, moe_shared_expert_intermediate_size=48,
+    n_routed_experts=4, router_width=16, first_expert=4,
+    num_experts_per_tok=3, routed_scaling_factor=2.5,
+    layer_norm_epsilon=1e-5,
+    assumed={"init_stdev": 0.05, "conv_init_stdev": 0.25,
+             "a_log_stdev": 3.0,
+             "optimizer": {"name": "adamw", "learning_rate": 1e-3,
+                           "wd": 0.01, "beta1": 0.9, "beta2": 0.999,
+                           "epsilon": 1e-8}})
+
+
+@pytest.fixture(scope="module")
+def nemotron():
+    return (_bench_module("reference", "nemotron3_nano_30b"),
+            _bench_module("configs", "nemotron3_nano_30b").decoder_config)
+
+
+def test_one_part_layers_match_reference_loss_and_every_gradient(nemotron):
+    """Layers of one part each: the chunked SSD inside the Mamba-2 mixer
+    (biased convolution, gated group norm) against the reference's
+    token scan, NoPE attention with no gate, the sigmoid-routed relu^2
+    expert layer with its shared expert; 80 tokens are two chunks and a
+    half.  float32, 2e-4 as the other models."""
+    import jax
+
+    reference, decoder_config = nemotron
+    config = copy.deepcopy(SMALL_NEMOTRON)
+    params0 = _weights(reference, config)
+    ids, labels = _batch(config, rows=2, seq=80)
+    (want_loss, want_rows), want = jax.jit(jax.value_and_grad(
+        lambda *a: reference.loss_and_routing(
+            *a, config=config, precision="float32"), has_aux=True))(
+                list(params0), ids, labels)
+
+    net, trainer = _step_matches_gradients(
+        reference, config, decoder_config(config), params0, ids, labels,
+        want_loss, want)
+    names = [name for name, _ in net._ordered_params()]
+    assert sum("in_proj" in n for n in names) == 2
+    assert sum("ffn_norm" in n for n in names) == 2
+    assert not any("gate_weight" in n or "q_norm" in n for n in names)
+    assert all(np.abs(np.asarray(g)).max() > 0 for g in want[3:])
+    np.testing.assert_array_equal(np.asarray(trainer._params[0]),
+                                  np.asarray(want_rows))
+    # the correction bias is held, not trained, and every layer is
+    # recomputed (the trainer's `remat` skips a child with a frozen leaf)
+    assert not trainer._trainable[1] and np.asarray(
+        trainer._params[1]).max() == 0
+    assert trainer._remat_count == 6
+    rows = net.routing_rows(trainer.aux_params()[net.routing_log.name])
+    assert sorted(rows) == [1, 4]
+
+
 def test_hybrid_config_keys_default_to_the_plain_decoder():
     """Every key the hybrid model added defaults to the older
     behaviour: per-head gate, plain gains from 1, no q/k norm, no gate
@@ -380,6 +441,121 @@ def test_shares_of_the_expert_layer_add_up_to_the_uncut_layer(
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
     assert sum(r[:held].sum() for r in rows) == x.shape[0] * top_k
     # no share is the whole: each leaves the others' part out
+    assert all(np.abs(p - sum(parts)).max() > 1e-3 for p in parts)
+
+
+def _dense_sigmoid_layer(x, router, w_in, w_out, top_k, scale, bias,
+                         held=None):
+    """The uncut sigmoid-routed relu^2 layer (or the part of it that the
+    experts `held` give), expert by expert with masks."""
+    import jax
+    import jax.numpy as jnp
+
+    scores = jax.nn.sigmoid(jnp.matmul(x, router, precision="highest"))
+    experts = jax.lax.top_k(scores + bias, top_k)[1]
+    weights = jnp.take_along_axis(scores, experts, -1)
+    weights = weights / weights.sum(-1, keepdims=True) * scale
+    out = jnp.zeros_like(x)
+    for e in range(router.shape[1]) if held is None else held:
+        hidden = jnp.maximum(jnp.matmul(x, w_in[e], precision="highest"), 0)
+        y = jnp.matmul(hidden * hidden, w_out[e], precision="highest")
+        out += jnp.where(experts == e, weights, 0).sum(-1, keepdims=True) * y
+    return out
+
+
+def _sigmoid_inputs(router_width=16, seed=0):
+    import jax.numpy as jnp
+
+    x, router, w_in, w_out = _moe_inputs(router_width=router_width,
+                                         seed=seed)
+    # relu^2 experts: no [gate | up] packing
+    return x, router, w_in[:, :, :32], w_out, jnp.asarray(
+        np.random.RandomState(seed + 1).rand(router_width) * 0.4,
+        jnp.float32)
+
+
+def test_sigmoid_relu2_moe_matches_the_dense_form_values_and_gradients():
+    """Sigmoid scoring, a correction bias that CHANGES the choice (and
+    takes no gradient), relu^2 experts with no gate, over held experts
+    4-7 of a 16-wide router: the op's part and its gradients against
+    the dense loop's."""
+    import jax
+
+    from mxnet_tpu.ops.moe import _k_moe_ffn
+
+    x, router, w_in, w_out, bias = _sigmoid_inputs()
+
+    def op(x, router, w_in, w_out, bias):
+        return _k_moe_ffn(x, router, w_in[4:8], w_out[4:8], bias,
+                          first_expert=4, top_k=3, scale=2.5,
+                          scoring="sigmoid", activation="relu2")
+
+    def dense(x, router, w_in, w_out, bias):
+        return _dense_sigmoid_layer(x, router, w_in, w_out, 3, 2.5, bias,
+                                    held=range(4, 8))
+
+    op, dense = jax.jit(op), jax.jit(dense)
+    y, log = op(x, router, w_in, w_out, bias)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(
+        dense(x, router, w_in, w_out, bias)), rtol=2e-5, atol=2e-6)
+    unbiased = op(x, router, w_in, w_out, 0 * bias)[1]
+    assert float(np.abs(np.asarray(log) - np.asarray(unbiased)).sum()) > 0
+    assert float(log.sum()) == x.shape[0] * 3
+
+    def grads(fn):
+        return jax.jit(jax.grad(lambda *a: (fn(*a) ** 2).sum(),
+                                argnums=(0, 1, 2, 3, 4)))(
+            x, router, w_in, w_out, bias)
+
+    got = grads(lambda *a: op(*a)[0])
+    want = grads(dense)
+    for g, w in zip(got[:4], want[:4]):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4,
+                                   atol=2e-5)
+    assert not np.asarray(got[4]).any()
+    with pytest.raises(ValueError, match="scoring"):
+        _k_moe_ffn(x, router, w_in, w_out, scoring="tanh")
+
+
+def test_sixteen_shares_of_eight_experts_add_up_to_the_uncut_layer():
+    """The deployment of the `nemotron3_nano_30b` cell at a small size:
+    a 128-wide sigmoid router, 16 shares of 8 relu^2 experts, each
+    routing over the whole router and computing its own experts' part;
+    with the shared expert, which every chip computes alike, counted
+    ONCE, the parts add up to the uncut layer."""
+    import jax
+    import jax.numpy as jnp
+
+    import mxnet_tpu as mx
+    from mxnet_tpu.ops.moe import _k_moe_ffn
+
+    x, router, w_in, w_out, bias = _sigmoid_inputs(router_width=128)
+    firsts = range(0, 128, 8)
+    shares = jax.jit(lambda x, router, w_in, w_out, bias: [_k_moe_ffn(
+        x, router, w_in[f:f + 8], w_out[f:f + 8], bias, first_expert=f,
+        top_k=6, scale=2.5, scoring="sigmoid", activation="relu2")
+        for f in firsts])
+    parts = []
+    for y, log in shares(x, router, w_in, w_out, bias):
+        parts.append(np.asarray(y))
+        assert log[:8].sum() + log[8] == x.shape[0] * 6
+    rng = np.random.RandomState(9)
+    s_in, s_out = (rng.randn(*shape).astype(np.float32) * 0.1
+                   for shape in ((48, 64), (64, 48)))
+    nd = mx.nd.array
+    fc = lambda a, w: mx.nd.FullyConnected(  # noqa: E731
+        a, nd(w), no_bias=True, flatten=False, num_hidden=w.shape[0])
+    once = fc(mx.nd.squared_relu(fc(nd(np.asarray(x)), s_in)),
+              s_out).asnumpy()
+    hidden = jnp.maximum(jnp.matmul(x, s_in.T, precision="highest"), 0)
+    want = np.asarray(_dense_sigmoid_layer(x, router, w_in, w_out, 6, 2.5,
+                                           bias)
+                      + jnp.matmul(hidden * hidden, s_out.T,
+                                   precision="highest"))
+    np.testing.assert_allclose(sum(parts) + once, want, rtol=2e-5,
+                               atol=2e-6)
+    # counted in every share it would be 16 times too much
+    assert np.abs(sum(parts) + 16 * once - want).max() > 1e-2
     assert all(np.abs(p - sum(parts)).max() > 1e-3 for p in parts)
 
 

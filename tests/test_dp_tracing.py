@@ -230,7 +230,7 @@ def test_section_says_what_remat_wrapped_and_keeps():
     stats = profiler.sections()["dataParallelStep"]
     assert stats["builds"] == 2 and stats["remat_children"] == 2
     assert stats["remat_saves"] == {
-        "flash_out": 1, "flash_lse": 1, "delta_rule_out": 1}
+        "flash_out": 1, "flash_lse": 1, "delta_rule_out": 1, "ssd_out": 1}
 
     flat = gluon.nn.Dense(4)
     flat.initialize(mx.init.Xavier())
@@ -242,7 +242,7 @@ def test_section_says_what_remat_wrapped_and_keeps():
     stats = profiler.sections()["dataParallelStep"]
     assert stats["remat_children"] == 2
     assert stats["remat_saves"] == {
-        "flash_out": 2, "flash_lse": 2, "delta_rule_out": 2}
+        "flash_out": 2, "flash_lse": 2, "delta_rule_out": 2, "ssd_out": 2}
 
     table = profiler._section_tables()
     assert f"{'remat: children checkpointed':<40}{2:>12}" in table

@@ -546,6 +546,45 @@ def test_conv_kernels_match_the_jnp_form_interpreted(
     assert "conv b2 s64 c96 t4" in "\n".join(la._stats_table(stats))
 
 
+@pytest.mark.parametrize("dtype,tolerance,dx_tolerance", [
+    ("float32", 1e-5, 1e-5), ("bfloat16", 4e-3, 2e-2)])
+def test_biased_conv_kernels_match_the_jnp_form_interpreted(
+        monkeypatch, interpret_pallas, dtype, tolerance, dx_tolerance):
+    """A Mamba-2 mixer's convolution, a bias added before SiLU: the
+    kernel pair (the bias as the weight's last row) against `jax.vjp`
+    of `_conv_xla`, the value and the gradients of the input, the
+    weight and the bias, over four row blocks and two channel blocks;
+    tolerances as the unbiased pair's."""
+    from mxnet_tpu.ops import linear_attention as la
+    from mxnet_tpu.ops.pallas import short_conv
+
+    _conv_kernels(monkeypatch, interpret_pallas)
+    calls = []
+    biased = short_conv.conv_biased
+    monkeypatch.setattr(short_conv, "conv_biased",
+                        lambda *xs: calls.append(1) or biased(*xs))
+    rng = np.random.RandomState(5)
+    x = jnp.asarray(rng.randn(2, 64, 384), dtype)
+    w = jnp.asarray(rng.randn(256, 4) * 0.5, dtype)
+    bias = jnp.asarray(rng.randn(256), dtype)
+    got, got_vjp = jax.vjp(la._k_causal_conv1d, x, w, bias)
+    want, want_vjp = jax.vjp(la._conv_xla, x, w, bias)
+    assert calls and got.shape == want.shape == (2, 64, 256)
+    # the bias moves the value: it is not dropped
+    assert float(jnp.abs(la._conv_xla(x, w).astype(jnp.float32)
+                         - want.astype(jnp.float32)).max()) > 0.1
+    cotangent = jnp.asarray(rng.randn(*want.shape), want.dtype)
+    for which, g, v, tol in zip(
+            ("y", "dx", "dw", "dbias"),
+            (got,) + tuple(got_vjp(cotangent)),
+            (want,) + tuple(want_vjp(cotangent)),
+            (tolerance, dx_tolerance, tolerance, tolerance)):
+        assert g.shape == v.shape and g.dtype == v.dtype, which
+        g, v = g.astype(jnp.float32), v.astype(jnp.float32)
+        assert float(jnp.abs(g - v).max()) <= tol * float(
+            jnp.abs(v).max()), which
+
+
 def test_the_trainers_remat_keeps_what_the_ops_name():
     """One list of the names a `jax.checkpoint` policy keeps
     (`ops.registry.RESIDUAL_NAMES`): each op names its outputs from it,
@@ -557,11 +596,13 @@ def test_the_trainers_remat_keeps_what_the_ops_name():
     from mxnet_tpu.parallel import data_parallel
 
     assert la.RESIDUAL_NAMES == ("delta_rule_out",)
+    from mxnet_tpu.ops import ssm
+
     assert registry.RESIDUAL_NAMES == {
         "flash_attention": flash_attention.RESIDUAL_NAMES,
-        "gated_delta_rule": la.RESIDUAL_NAMES}
+        "gated_delta_rule": la.RESIDUAL_NAMES, "ssd": ssm.RESIDUAL_NAMES}
     assert set(data_parallel._remat_saves()) == {
-        "flash_out", "flash_lse", "delta_rule_out"}
+        "flash_out", "flash_lse", "delta_rule_out", "ssd_out"}
     jaxpr = jax.make_jaxpr(la._k_gated_delta_rule)(*_inputs(seq=64))
     assert "name=delta_rule_out" in str(jaxpr)
 
@@ -585,6 +626,14 @@ def test_l2_norm_and_the_norms_new_forms():
         mx.nd.gated_rms_norm(mx.nd.array(x), mx.nd.array(gate),
                              mx.nd.array(gamma), eps=1e-6).asnumpy(),
         x_hat * gamma * gate / (1 + np.exp(-gate)), rtol=1e-5, atol=1e-6)
+    # a Mamba-2 mixer's: the gate before the norm, over groups of 4
+    gated = (x * gate / (1 + np.exp(-gate))).reshape(3, 5, 4, 4)
+    grouped = gated / np.sqrt((gated * gated).mean(-1, keepdims=True) + 1e-6)
+    np.testing.assert_allclose(
+        mx.nd.gated_rms_norm(mx.nd.array(x), mx.nd.array(gate),
+                             mx.nd.array(gamma), eps=1e-6, group_size=4,
+                             gate_first=True).asnumpy(),
+        grouped.reshape(3, 5, 16) * gamma, rtol=1e-5, atol=1e-6)
 
 
 def test_linear_attention_section_is_on_metrics():
