@@ -28,20 +28,31 @@ throughout.  A length that is no multiple of the chunk is padded with
 tokens of dt 0, which leave the state as it is and whose outputs are
 dropped.
 
-Memory.  The scan is one `jax.checkpoint`: its backward pass recomputes
-its forward, and keeps its inputs alone in between.  The output is
-NAMED (`RESIDUAL_NAMES`): a `jax.checkpoint` whose policy saves it
-(`DataParallelTrainer(remat=True)`) recomputes what follows the scan
-without running the scan again, so a step runs the scan's forward
-twice (the pass, and inside its own backward), not three times.  The
-backward holds the float32 states at the chunks' starts
-(`state_bytes_kept`: chunks x batch x heads x p x n x 4 bytes, 134 MB
-for 8,192 tokens and 64 heads of 64 x 128) and the decays inside each
-chunk (batch x heads x seq x chunk x 4, 268 MB there).
+Where the computation is lowered for the TPU (`lax.platform_dependent`)
+and the shapes allow it (heads of 64, two to a 128-lane unit, or of a
+multiple of 128; a group's heads on whole lanes; a state size of whole
+lanes; chunks of 128; bf16 or float32: `ssd.admits`), the scan is the
+kernel pair of ops/pallas/ssd.py, with its own `jax.custom_vjp`: a
+group's float32 states stay in VMEM across the chunks, and the decays
+and C B^T of a chunk are made in VMEM and never written to HBM.
+Everywhere else, and on every other backend, it is `_ssd_chunked`
+below, the oracle, in `jax.numpy`.
 
-This is the `jax.numpy` form, the only one: no Pallas kernel takes it
-yet.  Every scan traced is counted (the profiler section
-`stateSpace`).
+Memory.  Both forms keep their inputs alone from the pass to the
+backward.  The `jax.numpy` form is one `jax.checkpoint`: its backward
+recomputes its forward and holds the float32 states at the chunks'
+starts (`state_bytes_kept`: chunks x batch x heads x p x n x 4 bytes,
+134 MB for 8,192 tokens and 64 heads of 64 x 128) and the decays inside
+each chunk (batch x heads x seq x chunk x 4, 268 MB there).  The
+kernels' backward runs a forward that writes the same states, and
+nothing of chunk x chunk.  The output is NAMED (`RESIDUAL_NAMES`): a
+`jax.checkpoint` whose policy saves it (`DataParallelTrainer(remat=
+True)`) recomputes what follows the scan without running the scan
+again, so a step runs the scan's forward twice (the pass, and inside
+its own backward), not three times.
+
+Every scan traced is counted (the profiler section `stateSpace`), and
+those of shapes the kernels take.
 """
 from __future__ import annotations
 
@@ -60,8 +71,9 @@ from .registry import register
 RESIDUAL_NAMES = registry.RESIDUAL_NAMES["ssd"]
 
 # every scan traced: (batch, heads, seq, head size, groups, state size,
-# chunk, dtype) -> traces
+# chunk, dtype) -> traces; and those of shapes the kernels take
 _traced = collections.Counter()
+_kernel_traced = collections.Counter()
 
 
 def _dot(dtype):
@@ -126,6 +138,34 @@ def _ssd_chunked(x, dt, a, b, c, d, chunk):
     return y.reshape(batch, seq, heads, p).astype(dtype)
 
 
+def _scan(x, dt, a, b, c, d, chunk):
+    """The chunked scan over whole chunks: the kernel pair of
+    ops/pallas/ssd.py where the computation is lowered for the TPU and
+    it takes the shapes, `_ssd_chunked` (one `jax.checkpoint`)
+    everywhere else."""
+    from .pallas import ssd
+
+    def oracle(x, dt, a, b, c, d):
+        return jax.checkpoint(_ssd_chunked, static_argnums=(6,))(
+            x, dt, a, b, c, d, chunk)
+
+    if not ssd.admits(x, b, chunk):
+        return oracle(x, dt, a, b, c, d)
+
+    def kernels(x, dt, a, b, c, d):
+        from ..parallel.mesh import per_batch_shard
+
+        # a step that is partitioned over the batch runs them a shard
+        # of it each; a and d go as rows, shared by the shards
+        return per_batch_shard(
+            lambda x, dt, a, b, c, d: ssd.scan(x, dt, a[0], b, c, d[0],
+                                               chunk),
+            x, dt, a[None], b, c, d[None])
+
+    return jax.lax.platform_dependent(x, dt, a, b, c, d, tpu=kernels,
+                                      default=oracle)
+
+
 def _k_ssd(x, dt, a, b, c, d, *, chunk=128):
     """The Mamba-2 state-space scan (module docstring) in its chunked
     form.
@@ -140,8 +180,10 @@ def _k_ssd(x, dt, a, b, c, d, *, chunk=128):
     groups, n = b.shape[2], b.shape[3]
     if heads % groups:
         raise ValueError(f"ssd: {heads} heads over {groups} groups")
-    _traced[(batch, heads, seq, p, groups, n, chunk,
-             jnp.dtype(x.dtype).name)] += 1
+    from .pallas import ssd
+
+    key = (batch, heads, seq, p, groups, n, chunk, jnp.dtype(x.dtype).name)
+    _traced[key] += 1
     with jax.named_scope("ssd"):
         pad = -seq % chunk
         if pad:
@@ -149,8 +191,8 @@ def _k_ssd(x, dt, a, b, c, d, *, chunk=128):
             x, b, c = (jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
                        for v in (x, b, c))
             dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        y = jax.checkpoint(_ssd_chunked, static_argnums=(6,))(
-            x, dt, a, b, c, d, chunk)
+        _kernel_traced[key] += ssd.admits(x, b, chunk)
+        y = _scan(x, dt, a, b, c, d, chunk)
         return checkpoint_name(y[:, :seq], RESIDUAL_NAMES[0])
 
 
@@ -160,19 +202,22 @@ register("ssd", _k_ssd, arg_names=("x", "dt", "a", "b", "c", "d"))
 def state_space_stats():
     """The `stateSpace` profiler section: the scans traced since the
     last reset (a trace a layer's pass, as `linearAttention` counts).
-    `layers`: the distinct shapes; by shape, `traces` and `xla_traces`
-    (all of them: the scan has no kernel yet), `chunk`,
-    `chunks_per_sequence`, `groups` and `state_bytes_kept` (the float32
-    states at the chunks' starts that the scan's backward holds)."""
-    out = {"layers": len(_traced), "traces": {}, "xla_traces": {},
-           "chunk": {}, "chunks_per_sequence": {}, "groups": {},
-           "state_bytes_kept": {}}
+    `layers`: the distinct shapes; by shape, `traces`, `kernel_traces`
+    (of shapes the kernels take: on the TPU they run there),
+    `xla_traces` (of the others, the `jax.numpy` form everywhere),
+    `chunk`, `chunks_per_sequence`, `groups` and `state_bytes_kept` (the
+    float32 states at the chunks' starts that the scan's backward
+    holds)."""
+    out = {"layers": len(_traced), "traces": {}, "kernel_traces": {},
+           "xla_traces": {}, "chunk": {}, "chunks_per_sequence": {},
+           "groups": {}, "state_bytes_kept": {}}
     for shape, count in _traced.items():
         b, h, seq, p, g, n, chunk, dtype = shape
         key = f"b{b} h{h} s{seq} p{p} g{g} n{n} {dtype}"
         chunks = -(-seq // chunk)
         out["traces"][key] = count
-        out["xla_traces"][key] = count
+        out["kernel_traces"][key] = _kernel_traced[shape]
+        out["xla_traces"][key] = count - _kernel_traced[shape]
         out["chunk"][key] = chunk
         out["chunks_per_sequence"][key] = chunks
         out["groups"][key] = g
@@ -182,13 +227,15 @@ def state_space_stats():
 
 def reset_state_space_stats():
     _traced.clear()
+    _kernel_traced.clear()
 
 
 def _stats_table(stats):
     out = ["State Space (SSD scans traced):"]
     for key in sorted(stats["traces"]):
         out.append(f"  {key}: x{stats['traces'][key]} "
-                   f"({stats['xla_traces'][key]} in jax.numpy), "
+                   f"({stats['kernel_traces'][key]} of shapes the kernels "
+                   f"take, {stats['xla_traces'][key]} in jax.numpy), "
                    f"{stats['chunks_per_sequence'][key]} chunks of "
                    f"{stats['chunk'][key]}, {stats['groups'][key]} groups, "
                    f"keeps {stats['state_bytes_kept'][key]} bytes of states")

@@ -505,6 +505,77 @@ def test_biased_short_conv_at_the_nemotron_cells_shape_aot(one_chip, dt):
     assert compiled.memory_analysis().temp_size_in_bytes <= 1.01 * dx
 
 
+@pytest.mark.parametrize("dt", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_ssd_at_the_nemotron_cells_shape_aot(one_chip, dt):
+    """`ssd` at the nemotron cell's shape, one sequence of 8,192 tokens,
+    64 heads of 64 over 8 groups of state size 128, forward and
+    backward: three Mosaic calls under `/ssd/` (the forward, the
+    forward that writes the states, the reverse kernel), each leading
+    with float32 G by head (1, 1, 64, 8,192), never the attention
+    kernels' `bf16[b x kv, group, s, d]` query; no float32 (.., 128, 128) decay
+    array of the scan is left in the text, and the largest float32
+    array is the states at the chunks' starts (64 chunks x 8 groups x
+    128 x 512); lowered for the CPU the same call holds no kernel."""
+    import re
+
+    from mxnet_tpu.ops.ssm import _k_ssd
+
+    x = jax.ShapeDtypeStruct((1, 8192, 64, 64), dt)
+    step = jax.ShapeDtypeStruct((1, 8192, 64), jnp.float32)
+    head = jax.ShapeDtypeStruct((64,), jnp.float32)
+    bc = jax.ShapeDtypeStruct((1, 8192, 8, 128), dt)
+
+    def loss(x, dt, a, b, c, d):
+        # inside another scope, as in the trainer's step
+        with jax.named_scope("forward"):
+            return _k_ssd(x, dt, a, b, c, d).astype(jnp.float32).sum()
+
+    grad = jax.value_and_grad(loss, argnums=tuple(range(6)))
+    text = jax.jit(grad, in_shardings=(one_chip,) * 6) \
+        .lower(x, step, head, bc, bc, head).compile().as_text()
+    kernels = _mosaic_op_names(text)
+    assert len(kernels) == text.count("tpu_custom_call") == 3
+    assert all("/ssd/" in name for name in kernels), kernels
+    assert sum("transpose(" in name for name in kernels) == 2
+    assert _first_operands(text) == ["f32[1,1,64,8192]"] * 3
+    assert not re.search(r"f32\[[0-9,]*,128,128\]", text)
+    states = 64 * 8 * 128 * 512
+    f32 = [int(np.prod([int(n) for n in dims.split(",")]))
+           for dims in re.findall(r"\bf32\[([0-9,]+)\]", text)]
+    assert max(f32) == states
+    assert "custom_call" not in jax.jit(grad).lower(
+        x, step, head, bc, bc, head).as_text()
+
+
+def test_mamba_remat_step_runs_the_ssd_kernels_aot(topo, monkeypatch):
+    """The remat step of a decoder of two Mamba-2 layers (8 heads of 64
+    over 2 groups of state size 128, chunks of 128) at a small width:
+    the scan is three Mosaic calls a layer under `/mamba/.../ssd/`,
+    the forward in the pass and, in the backward, the forward that
+    writes the states and the reverse kernel (the recomputation's
+    forward is dead: `ssd_out` is kept), each leading with float32 G by
+    head;
+    the biased convolution three a layer under `/conv/`."""
+    config = dict(
+        vocab_size=1024, hidden_size=256, head_dim=128,
+        num_attention_heads=2, num_key_value_heads=2, num_hidden_layers=2,
+        layer_types=["mamba"] * 2, mlp_layer_types=[None] * 2,
+        mamba_num_heads=8, mamba_head_dim=64, n_groups=2,
+        ssm_state_size=128, chunk_size=128, conv_kernel=4,
+        use_conv_bias=True, rope_parameters={})
+    text = _step_aot(topo, monkeypatch, config, 512, 1).as_text()
+    first = dict(zip(_mosaic_op_names(text), _first_operands(text)))
+    scan = _mosaic_op_names(text, "/ssd/")
+    assert len(scan) == 2 * 3
+    assert all("/mamba/" in name for name in scan)
+    assert sum("transpose(" in name for name in scan) == 2 * 2
+    assert not [name for name in scan if "rematted_computation" in name]
+    assert {first[name] for name in scan} == {"f32[2,1,8,512]"}
+    assert len(_mosaic_op_names(text, "/conv/")) == 2 * 3
+    assert text.count("tpu_custom_call") == 2 * 6
+
+
 def _hybrid_step_aot(topo, monkeypatch, seq=512):
     """A 4-layer hybrid `DecoderLM` (three Gated DeltaNet layers of 4
     key / 8 value heads of 128, one gated attention layer of 16 / 2
